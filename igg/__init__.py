@@ -61,10 +61,6 @@ fence-the-suspect-device re-tile — all chaos-provable with
 `igg.chaos.silent_corruption`/`poison_checkpoint`).
 """
 
-from ._compat import install as _compat_install
-
-_compat_install()
-
 from .shared import (
     AXIS_NAMES,
     NDIMS,

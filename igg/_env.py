@@ -210,3 +210,22 @@ def flag(name: str, default: bool = False) -> bool:
         return False
     raise GridError(f"{name}={val!r} is not a boolean "
                     f"(use 1/0, true/false, yes/no, on/off).")
+
+
+def place_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a stable home, for entry
+    scripts (`chip_smoke.py`, `bench.py`, `benchmarks/common.py`) to call
+    at start-up; never called on `import igg` or under the tests.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this sets
+    nothing; otherwise the cache goes to ``<checkout>/.jax_cache`` — a
+    fixed path, since the path is part of the cache key.  Returns the
+    directory in use."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -293,8 +293,9 @@ def _build_probe(pk: _Packing, watch, ndims: Dict[str, int],
     unwatched field."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
     from jax.sharding import PartitionSpec as P
+
+    from .parallel import psum_grid
 
     fields = list(probe_fields) if probe_fields is not None else list(watch)
 
@@ -309,7 +310,7 @@ def _build_probe(pk: _Packing, watch, ndims: Dict[str, int],
             else:
                 c = jnp.zeros((a.shape[0],), jnp.float32)
             if pk.name == "grid":
-                c = lax.psum(c, AXIS_NAMES)
+                c = psum_grid(c)
             counts.append(c)
         rows = list(counts)
         if invariants:
@@ -318,7 +319,13 @@ def _build_probe(pk: _Packing, watch, ndims: Dict[str, int],
             grid = shared.global_grid()
             rows.extend(_integrity.member_invariant_rows(
                 invariants, by_field, pk.name, grid))
-        return jnp.stack(rows)
+        out = jnp.stack(rows)
+        if pk.name == "batch":
+            # The batch mesh's grid axes have size 1: this psum is the
+            # identity, and it types the rows grid-invariant, which the
+            # P(None, "member") out_specs requires under check_vma.
+            out = psum_grid(out)
+        return out
 
     in_specs = tuple(pk.spec(ndims[k]) for k in fields)
     out_specs = P(None, "member") if pk.name == "batch" else P()

@@ -359,7 +359,7 @@ def member_invariant_rows(invariants, arrays_by_field, pk_name: str, grid):
     local blocks (leading member axis), psum'd over grid axes under
     grid packing (batch packing's member shards need no collective —
     the count-probe contract)."""
-    from jax import lax
+    from .parallel import psum_grid
 
     rows = []
     for inv in invariants:
@@ -370,8 +370,8 @@ def member_invariant_rows(invariants, arrays_by_field, pk_name: str, grid):
             sca = sca + _owned_reduce(a, inv.moment, grid, lead=1,
                                       absolute=True)
         if pk_name == "grid":
-            val = lax.psum(val, AXIS_NAMES)
-            sca = lax.psum(sca, AXIS_NAMES)
+            val = psum_grid(val)
+            sca = psum_grid(sca)
         rows.append(val)
         rows.append(sca)
     return rows
@@ -418,7 +418,7 @@ def _build_probe(watch: Sequence[str], extra: Sequence[str],
     across an elastic re-tile."""
     from jax.sharding import PartitionSpec
 
-    from .parallel import sharded
+    from .parallel import psum_grid, sharded
 
     watch = tuple(watch)
     extra = tuple(extra)
@@ -442,20 +442,19 @@ def _build_probe(watch: Sequence[str], extra: Sequence[str],
             a = cur[name]
             fm = moms.get(name, ())
             if not jnp.issubdtype(a.dtype, jnp.inexact):
-                counts.append(lax.psum(jnp.zeros((), jnp.float32),
-                                       AXIS_NAMES))
+                counts.append(psum_grid(jnp.zeros((), jnp.float32)))
                 continue
             nf = (~jnp.isfinite(a)).astype(jnp.float32)
             if fm:
                 # The packed pass: count + first moment in one reduce.
                 z = jnp.sum(lax.complex(nf,
                                         _masked_moment(a, fm[0], grid)))
-                counts.append(lax.psum(z.real, AXIS_NAMES))
+                counts.append(psum_grid(z.real))
                 vals[(name, fm[0])] = z.imag
                 for m in fm[1:]:
                     vals[(name, m)] = jnp.sum(_masked_moment(a, m, grid))
             else:
-                counts.append(lax.psum(jnp.sum(nf), AXIS_NAMES))
+                counts.append(psum_grid(jnp.sum(nf)))
         for name in extra:
             a = cur[name]
             for m in moms.get(name, ()):

@@ -14,15 +14,13 @@ to BASELINE config 4's model family on *every* rank of a decomposed run —
 the per-rank property of the reference's native tier — not just the
 single-device configuration.
 
-Measured on v5e at 256^3 f32 (median-of-3, 100-step dispatches, self-wrap
-grid): **0.64 ms/step vs 2.92 for the XLA composition — 4.6x** — the
-largest native-tier gain of the three model kernels: the nonlinear
-per-step `(phi/phi0)^n` permeabilities and two coupled interior updates
-cost the XLA path many extra HBM passes that all fuse here.  Matches the
-XLA path to float32 rounding; `benchmarks/results/overlap_study.jsonl`.
-On self-wrap grids the time loop goes further still: `fused_hm3d_steps`
-routes it through the two-field K-step mega-kernel at **0.48 ms/step —
-6.1x** (`igg/ops/hm3d_mega.py`).
+The nonlinear per-step `(phi/phi0)^n` permeabilities and two coupled
+interior updates cost the XLA path many extra HBM passes that all fuse
+here.  Matches the XLA path to float32 rounding
+(`tests/test_mega_tpu.py::test_hm3d_kernel_compiled_matches_xla`, on the
+chip).  On self-wrap grids `fused_hm3d_steps` routes the time loop
+through the two-field K-step mega-kernel (`igg/ops/hm3d_mega.py`).  Speed
+against the XLA composition is not measured on today's code.
 
 Structure (the two-field radius-1 instance of the `diffusion_pallas`
 recipe; see that module's docstring for the design rationale):

@@ -30,6 +30,21 @@ def local_coords() -> Tuple:
     return tuple(lax.axis_index(a) for a in AXIS_NAMES)
 
 
+def psum_grid(x):
+    """`lax.psum` of `x` over the grid axes, inside SPMD code.  Under
+    JAX's varying-manual-axes check a value that varies over only some
+    grid axes (a 2-D field on the 3-axis mesh, a member shard on batch
+    packing's size-1 grid axes) is already replicated over the others,
+    and a psum over those is refused, so it sums over the axes it varies
+    over.  A value with no tracked variation (replicated, or traced with
+    `check_vma=False`) sums over every grid axis."""
+    from jax import lax
+
+    vma = getattr(getattr(x, "aval", None), "vma", None) or ()
+    return lax.psum(x, tuple(a for a in AXIS_NAMES if a in vma)
+                    or AXIS_NAMES)
+
+
 def _is_grid_leaf(x, grid) -> bool:
     """Whether a pytree leaf is a grid array (shardable over the mesh):
     every one of its leading <=3 dims is divisible by the mesh dims."""

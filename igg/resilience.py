@@ -89,7 +89,7 @@ import numpy as np
 
 from . import shared
 from . import telemetry as _telemetry
-from .shared import AXIS_NAMES, GridError
+from .shared import GridError
 
 __all__ = ["run_resilient", "RunResult", "Event", "ResilienceError",
            "request_preemption", "preemption_requested", "clear_preemption"]
@@ -271,12 +271,11 @@ def _make_probe():
     width."""
     from jax.sharding import PartitionSpec
 
-    from .parallel import sharded
+    from .parallel import psum_grid, sharded
 
     @sharded(out_specs=PartitionSpec())
     def probe(*arrays):
         import jax.numpy as jnp
-        from jax import lax
 
         counts = []
         for a in arrays:
@@ -284,7 +283,7 @@ def _make_probe():
                 c = jnp.sum((~jnp.isfinite(a)).astype(jnp.float32))
             else:
                 c = jnp.zeros((), jnp.float32)
-            counts.append(lax.psum(c, AXIS_NAMES))
+            counts.append(psum_grid(c))
         return jnp.stack(counts)
 
     return probe

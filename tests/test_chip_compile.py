@@ -1,0 +1,186 @@
+"""Chipless compiles of the main path's kernels for the TPU v5e.
+
+The TPU compiler is installed next to the CPU backend, and it compiles for
+a chip that is described (`topologies.get_topology_desc`) but not
+attached.  Each case lowers one program that `chip_smoke.py` (or its
+Stokes sibling) runs, at its real size, and asserts the Mosaic kernel
+survived into the compiled program (`tpu_custom_call`): a refused slice,
+a VMEM limit over the chip's or a kernel that cannot be partitioned fails
+here, at no chip time.  Nothing runs, so nothing here is a timing.
+
+The topology is described inside a module fixture, never at import or
+collection: only one process may load libtpu at a time, and every xdist
+worker imports this file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import igg
+
+N_INNER = 100    # chip_smoke.py's steps per dispatch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(local, shapes, out_specs):
+    """`jit(shard_map(local))` on the live grid's mesh, compiled from
+    shapes alone; returns the compiled program."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    grid = igg.get_global_grid()
+    specs = tuple(igg.spec_for(len(s)) for s in shapes)
+    args = [jax.ShapeDtypeStruct(s, np.float32,
+                                 sharding=NamedSharding(grid.mesh, sp))
+            for s, sp in zip(shapes, specs)]
+    sm = jax.shard_map(local, mesh=grid.mesh, in_specs=specs,
+                       out_specs=out_specs)
+    return jax.jit(sm, donate_argnums=(0,)).lower(*args).compile()
+
+
+def _grid(topo, n, dims, periodic, **kw):
+    p = int(periodic)
+    ndev = dims[0] * dims[1] * dims[2]
+    igg.init_global_grid(*n, dimx=dims[0], dimy=dims[1], dimz=dims[2],
+                         periodx=p, periody=p, periodz=p,
+                         devices=list(topo.devices[:ndev]), quiet=True,
+                         **kw)
+    grid = igg.get_global_grid()
+    return tuple(d * s for d, s in zip(grid.dims, grid.nxyz))
+
+
+def _diffusion_steps(n_inner):
+    """The diffusion3d Mosaic tier's local program (`make_multi_step`'s
+    `build_pallas_steps`), with the bx it picks."""
+    from igg.models import diffusion3d as d3
+    from igg.ops import fused_diffusion_steps
+
+    p = d3.Params()
+    dx, dy, dz = p.spacing()
+    bx = d3._best_bx(igg.get_global_grid().nxyz[0])
+    return lambda T, Cp: fused_diffusion_steps(
+        T, Cp, n_inner=n_inner, dx=dx, dy=dy, dz=dz, dt=p.timestep(),
+        lam=p.lam, bx=bx)
+
+
+def mega_512_open(topo):
+    """Phase A: the streamed-coefficient frozen-edge mega kernel."""
+    from igg.ops.diffusion_mega import mega_supported
+
+    g = _grid(topo, (512,) * 3, (1, 1, 1), periodic=False)
+    assert mega_supported(g, 8, N_INNER, False, dtype=np.float32)
+    return _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+
+
+def mega_256_periodic(topo):
+    """Phase B: the resident self-wrapping mega kernel."""
+    from igg.ops.diffusion_mega import mega_supported
+
+    g = _grid(topo, (256,) * 3, (1, 1, 1), periodic=True)
+    assert mega_supported(g, 8, N_INNER, False, dtype=np.float32)
+    return _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+
+
+def perstep_with_halo_writer(topo):
+    """The per-step fused kernel, then a standalone halo update through
+    the in-place Pallas writers (phase B's `igg.update_halo`)."""
+    from igg.models import diffusion3d as d3
+    from igg.ops import fused_diffusion_step
+
+    g = _grid(topo, (256,) * 3, (1, 1, 1), periodic=True)
+    p = d3.Params()
+    dx, dy, dz = p.spacing()
+
+    def local(T, Cp):
+        T = fused_diffusion_step(T, Cp, dx=dx, dy=dy, dz=dz,
+                                 dt=p.timestep(), lam=p.lam, bx=8)
+        return igg.update_halo_local(T, assembly="pallas")
+
+    return _compile(local, [g, g], igg.spec_for(3))
+
+
+def diffusion_trapezoid_2x2(topo):
+    """`--chips 4`: the K-step trapezoid chunks with slab ppermutes."""
+    from igg.ops.diffusion_trapezoid import trapezoid_supported
+
+    g = _grid(topo, (256,) * 3, (2, 2, 1), periodic=True)
+    assert trapezoid_supported(igg.get_global_grid(), (256,) * 3, 8,
+                               N_INNER - 1, np.float32, allow_open=True)
+    return _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+
+
+def stokes_trapezoid_4x1(topo):
+    """The Stokes K-iteration chunk tier (warm-up iteration, chunks,
+    per-iteration remainder) over the same four chips at 128^3: its
+    VMEM budget admits the chunk only there and only on an (N,1,1)
+    ring (`fit_stokes_K` is 0 on (2,2,1) at every size)."""
+    from jax import lax
+
+    from igg.models import stokes3d
+    from igg.ops import fused_stokes_iteration
+    from igg.ops.stokes_trapezoid import (fit_stokes_K,
+                                          fused_stokes_trapezoid_iters)
+
+    n, n_inner = 128, 9
+    g = _grid(topo, (n,) * 3, (4, 1, 1), periodic=True, overlapx=3,
+              overlapy=3, overlapz=3)
+    K = fit_stokes_K(igg.get_global_grid(), (n,) * 3, n_inner - 1,
+                     np.float32)
+    assert K, "the Stokes chunk tier does not engage"
+    kw = stokes3d._pseudo_steps(stokes3d.Params())
+
+    def local(P, Vx, Vy, Vz, Rho):
+        S = fused_stokes_iteration(P, Vx, Vy, Vz, Rho, **kw)
+        *S, done = fused_stokes_trapezoid_iters(*S, Rho, n_inner=n_inner - 1,
+                                                K=K, **kw)
+        return tuple(lax.fori_loop(
+            0, n_inner - 1 - done,
+            lambda _, T: fused_stokes_iteration(*T, Rho, **kw), tuple(S)))
+
+    dims = igg.get_global_grid().dims
+    stag = [tuple(dims[d] * (n + (d == i)) for d in range(3))
+            for i in range(3)]
+    return _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
+
+
+@pytest.mark.parametrize("case", [
+    mega_512_open, mega_256_periodic, perstep_with_halo_writer,
+    diffusion_trapezoid_2x2, stokes_trapezoid_4x1],
+    ids=lambda f: f.__name__)
+def test_compiles_for_v5e(topo, case):
+    import jax
+
+    with jax.enable_x64(False):
+        try:
+            compiled = case(topo)
+        finally:
+            if igg.grid_is_initialized():
+                igg.finalize_global_grid()
+    assert "tpu_custom_call" in compiled.as_text()
