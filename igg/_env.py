@@ -124,9 +124,6 @@ _KNOWN: Dict[str, str] = {
     "IGG_STATUSD_PUBLISH_EVERY":
         "seconds between the non-zero-rank statusd snapshot files that "
         "rank 0's endpoint merges (default 5)",
-    "IGG_TELEMETRY_DEVICE":
-        "0 disables mirroring trace spans onto the device timeline "
-        "(jax.profiler.TraceAnnotation)",
     "IGG_TELEMETRY_DIR":
         "default igg.telemetry session directory (setting it attaches "
         "telemetry to every run loop)",

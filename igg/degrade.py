@@ -56,10 +56,11 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 import warnings
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from . import _env
+from . import _env, profiling
 from . import telemetry as _telemetry
 from .shared import GridError
 
@@ -614,21 +615,41 @@ class Ladder:
         except Exception:   # pragma: no cover - advisory path
             pass
 
-    def _record_active(self, tier_name: str) -> None:
+    def _record_active(self, tier_name: str) -> int:
         global _DISPATCHES
         with _lock:
             _DISPATCHES += 1
+            stamp = _DISPATCHES
             _ACTIVE[self.family] = tier_name
-            _ACTIVE_STAMP[self.family] = _DISPATCHES
+            _ACTIVE_STAMP[self.family] = stamp
         _telemetry.counter("igg_tier_dispatch_total", family=self.family,
                            tier=tier_name).inc()
+        return stamp
 
     def dispatch(self, *args):
-        for t in self.tiers:
+        """Serve one dispatch: the `igg.dispatch` profiler span (family,
+        serving tier, dispatch stamp) around the walk; its host seconds
+        go to `igg_dispatch_host_seconds{family}` unless it compiled, in
+        which case the compile stages went to `igg_compile_seconds`."""
+        t0 = time.perf_counter()
+        with profiling.annotate("igg.dispatch", family=self.family) as span, \
+                profiling.program_frame() as frame:
+            out, tier, stamp = self._serve(args)
+            span.set_metadata(tier=tier, stamp=stamp)
+        if not frame.compiled:
+            _telemetry.histogram("igg_dispatch_host_seconds",
+                                 family=self.family).observe(
+                                     time.perf_counter() - t0)
+        return out
+
+    def _admit(self, i: int, args: tuple) -> int:
+        """Index of the first tier from `i` on that is neither quarantined
+        nor refused by its admission gate (the truth rung always admits);
+        a required tier that is either raises."""
+        for i in range(i, len(self.tiers)):
+            t = self.tiers[i]
             if t.truth:
-                out = self._fn(t)(*args)
-                self._record_active(t.name)
-                return out
+                return i
             if is_quarantined(t.name):
                 if t.required:
                     q = _QUARANTINE[t.name]
@@ -646,6 +667,22 @@ class Ladder:
                     raise GridError(t.requirement
                                     or f"tier {t.name}: {reason}")
                 continue
+            return i
+        raise GridError(   # unreachable: the last rung is the truth rung
+            f"degrade: no tier of {self.family} could serve the dispatch.")
+
+    def _serve(self, args: tuple):
+        """(outputs, serving tier, dispatch stamp) of the first admitted
+        tier that survives the compile-failure capture and verification."""
+        i = 0
+        while True:
+            with profiling.annotate("igg.admit"):
+                i = self._admit(i, args)
+            t = self.tiers[i]
+            i += 1
+            if t.truth:
+                out = self._fn(t)(*args)
+                return out, t.name, self._record_active(t.name)
             try:
                 fn = self._fn(t)
                 if self._verify_enabled():
@@ -680,7 +717,4 @@ class Ladder:
                         f"{type(e).__name__}: {e}") from e
                 continue
             _SERVED.add(t.name)
-            self._record_active(t.name)
-            return out
-        raise GridError(   # unreachable: the truth rung always serves
-            f"degrade: no tier of {self.family} could serve the dispatch.")
+            return out, t.name, self._record_active(t.name)

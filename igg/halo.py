@@ -88,7 +88,7 @@ from __future__ import annotations
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from . import shared
+from . import profiling, shared
 from .fields import spec_for
 from .shared import AXIS_NAMES, NDIMS, GridError
 
@@ -364,6 +364,7 @@ def exchange_all_dims(A, send: Dict, dims_active, grid,
     return recvs[0]
 
 
+@profiling.scoped(profiling.HALO)
 def exchange_all_dims_grouped(shapes, sends, dims_actives, grid,
                               stales=None, wraps=None,
                               blocks=None) -> List[Dict]:
@@ -1027,6 +1028,7 @@ def _writer_dims(A, dims, grid, all_ext: bool = False):
     return wraps, use_writer
 
 
+@profiling.scoped(profiling.HALO)
 def _update_halo_impl(fields: List, grid, assembly=None) -> Tuple:
     """Halo update of all fields' local blocks: pack squeezed send planes
     (inner plane `ol-1` / `s-ol`, `/root/reference/src/update_halo.jl:

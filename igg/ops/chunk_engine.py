@@ -67,6 +67,7 @@ from __future__ import annotations
 
 from functools import partial
 
+from .. import profiling
 from ._vmem import chunk_budget, fit_chunk_K  # noqa: F401  (re-exported)
 
 
@@ -194,6 +195,7 @@ def freeze_open_dim(U, F, d, mode, lo, hi, grid):
 # The grouped K-deep slab extension (moved from stokes_trapezoid)
 # ---------------------------------------------------------------------------
 
+@profiling.scoped(profiling.HALO)
 def extend_dim_grouped(arrs, ols, E, grid, d, mode="ext"):
     """The `size + 2E` contiguous global window along dim `d` for a GROUP
     of fields with per-field staggered overlaps: E extension rows beyond
@@ -647,6 +649,7 @@ def whole_window_chunk_call(exts, *, K, E, modes, grid, ols, shapes,
 
     out = pl.pallas_call(
         kern,
+        name="chunk_whole_window",
         grid=(K,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(exts)
         + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(flag_ops)
@@ -984,6 +987,7 @@ def resident_chunk_call(exts, const_exts, *, K, bx, modes, grid, ols,
     fr_scratch = [pltpu.VMEM(p.shape, p.dtype) for p in fr_planes]
     out = pl.pallas_call(
         kern,
+        name="chunk_resident",
         grid=(K, nb),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(fields_all)
         + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(flag_ops)
@@ -1454,6 +1458,7 @@ def streaming_chunk_call(exts, const_exts, *, K, B, modes, grid, ols,
         for f, F in enumerate(fields_all)]
     out = pl.pallas_call(
         kern,
+        name="chunk_streaming",
         grid=(K, nb),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(fields_all)
         + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(flag_ops)

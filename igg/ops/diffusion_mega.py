@@ -409,6 +409,7 @@ def fused_diffusion_megasteps(T, A, *, n_inner: int, bx: int,
         ]
     out, _, _ = pl.pallas_call(
         kern,
+        name="diffusion3d_mega",
         grid=(n_inner, nb),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],

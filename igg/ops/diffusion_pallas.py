@@ -336,6 +336,7 @@ def _call_kernel(T, A, recv, scal, bx, interpret, wrap_yz):
         out_specs += [pl.BlockSpec((bx, 3, S1), lambda i: (i, 0, 0))] * 2
     return pl.pallas_call(
         kern,
+        name="diffusion3d_step",
         out_shape=tuple(out_shape),
         grid=(nb,),
         in_specs=in_specs,

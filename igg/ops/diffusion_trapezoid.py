@@ -532,6 +532,7 @@ def _chunk_call(Text, A_ext, out_shape3, *, K, bx, modes, grid,
         fr_scratch.append(pltpu.SemaphoreType.DMA((len(fr_planes),)))
     out, _, _ = pl.pallas_call(
         kern,
+        name="diffusion3d_trapezoid_chunk",
         grid=(K, nbe),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)]

@@ -275,6 +275,7 @@ def _inplace_call(kernel, A, *, grid, in_specs, out_spec, alias, args,
                  else jax.ShapeDtypeStruct(A.shape, A.dtype))
     return pl.pallas_call(
         kernel,
+        name="halo_writer",
         grid=grid,
         in_specs=list(in_specs),
         out_specs=out_spec,

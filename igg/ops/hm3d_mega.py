@@ -248,6 +248,7 @@ def fused_hm3d_megasteps(Pe, phi, *, n_inner: int, bx: int, **kw_core):
 
     pe_out, phi_out, *_ = pl.pallas_call(
         kern,
+        name="hm3d_mega",
         grid=(n_inner, nb),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 6,

@@ -365,6 +365,7 @@ def _call_kernel(Pe, phi, recvs, kw_core, bx, interpret, wrap_yz,
         out_specs += [pl.BlockSpec((bx, 3, S1), lambda i: (i, 0, 0))] * 4
     return pl.pallas_call(
         kern,
+        name="hm3d_step",
         out_shape=tuple(out_shape),
         grid=(nb,),
         in_specs=in_specs,

@@ -76,6 +76,7 @@ def pack_planes(A, reqs: Sequence[Tuple[int, int]]) -> List:
     ]
     return list(pl.pallas_call(
         kernel,
+        name="halo_pack_planes",
         grid=(nb,),
         in_specs=[pl.BlockSpec((bx, s1, s2), lambda i: (i, 0, 0))],
         out_specs=out_specs,

@@ -445,6 +445,7 @@ def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
     Pn, Vxn, Vyn, Vzn = pl.pallas_call(
         partial(_kernel, bx=bx, nb=nb, shapes=shapes[:4], scal=scal,
                 wrap_y=wy, wrap_z=wz),
+        name="stokes3d_iteration",
         grid=(nb,),
         in_specs=in_specs,
         out_specs=out_specs,

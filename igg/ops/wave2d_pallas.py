@@ -143,6 +143,7 @@ def _call_step_kernel(P, Vx, Vy, scal, interpret):
                 _whole_block_vmem([a.shape for a in operands])))
     return pl.pallas_call(
         partial(_step_kernel, scal=scal),
+        name="wave2d_step",
         out_shape=tuple(shp(a) for a in operands),
         interpret=interpret,
         **kwargs,

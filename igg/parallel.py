@@ -16,7 +16,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import shared
+from . import profiling, shared, telemetry
 from .fields import spec_for
 from .shared import AXIS_NAMES, NDIMS, GridError
 
@@ -221,6 +221,121 @@ def free_sharded_cache() -> None:
     _compiled.clear()
 
 
+def _aval(x):
+    """What a cached program was called with, minus the data: arrays as
+    `ShapeDtypeStruct`s with their sharding, anything else as itself."""
+    import jax
+
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding,
+                                    weak_type=x.weak_type)
+    return x
+
+
+def compiled_texts() -> list:
+    """The compiled HLO text of each program in the cache that has run
+    (for :func:`igg.profiling.op_scopes`), lowered again from the avals
+    kept at its cache miss — a hit in JAX's in-memory caches."""
+    out = []
+    for entry in list(_compiled.values()):
+        if entry.avals is not None:
+            if entry.text is None:
+                entry.text = entry.jfn.lower(*entry.avals).compile().as_text()
+            out.append(entry.text)
+    return out
+
+
+class _Program:
+    """A cached program: the jitted callable, the argument avals of its
+    first call and, once asked for, its compiled text."""
+
+    __slots__ = ("jfn", "avals", "text")
+
+    def __init__(self, jfn):
+        self.jfn, self.avals, self.text = jfn, None, None
+
+
+def _build(f, args, grid, *, donate_argnums, out_specs, check_vma):
+    """The jitted `shard_map` program of :func:`sharded` for `f` at these
+    arguments, its whole body traced under the :data:`igg.profiling.STENCIL`
+    scope."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    in_specs = jax.tree.map(lambda x: _leaf_spec(x, grid), args)
+    if out_specs is None:
+        # Infer the output specs by abstract tracing with the mesh
+        # axes bound (so collectives/axis_index trace), combining
+        # two facts per output leaf:
+        #   - does its local shape look like a grid block
+        #     (stagger/flux margin of the local grid size)?
+        #   - can it hold *different values on different devices*
+        #     (taint analysis, `_device_varying_outvars`)?
+        # Device-varying grid-shaped outputs are grid fields
+        # (replication is not even meaningful for them);
+        # device-invariant non-grid outputs are replicated
+        # (provably correct).  The two mixed cases are genuinely
+        # ambiguous and raise, demanding explicit `out_specs` —
+        # never a silent wrong answer (a replicated diagnostic
+        # that happens to be (nx,ny,nz)-shaped must not be
+        # concatenated into a fake "global" array).
+        local_avals = jax.tree.map(lambda x: _local_aval(x, grid), args)
+        axis_env = [(a, grid.dims[d])
+                    for d, a in enumerate(AXIS_NAMES)]
+        jaxpr, out_aval = jax.make_jaxpr(
+            f, axis_env=axis_env, return_shape=True)(*local_avals)
+        varying = _device_varying_outvars(
+            jaxpr.jaxpr,
+            [_is_grid_leaf(x, grid) for x in jax.tree.leaves(args)],
+            all_axes=[a for d, a in enumerate(AXIS_NAMES)
+                      if grid.dims[d] > 1])
+        out_leaves, out_tree = jax.tree.flatten(out_aval)
+        if grid.nprocs == 1:
+            # One device: sharding and replication coincide;
+            # keep the historical (shard-grid-shaped) behavior.
+            o_specs = out_tree.unflatten([
+                spec_for(len(a.shape))
+                if _is_grid_local_shape(a.shape, grid) else P()
+                for a in out_leaves])
+        else:
+            specs_flat = []
+            for i, (a, var) in enumerate(zip(out_leaves, varying)):
+                gridlike = _is_grid_local_shape(a.shape, grid)
+                if gridlike and var:
+                    specs_flat.append(spec_for(len(a.shape)))
+                elif not gridlike and not var:
+                    specs_flat.append(P())
+                elif gridlike:
+                    raise GridError(
+                        f"igg.sharded: output leaf {i} has the "
+                        f"local shape {tuple(a.shape)} of a grid "
+                        f"block but is provably identical on "
+                        f"every device — ambiguous between a "
+                        f"constant grid field and a replicated "
+                        f"diagnostic.  Pass out_specs= (e.g. "
+                        f"igg.spec_for({len(a.shape)}) to stack "
+                        f"it as a grid field, or "
+                        f"jax.sharding.PartitionSpec() to keep "
+                        f"one copy).")
+                else:
+                    raise GridError(
+                        f"igg.sharded: output leaf {i} with "
+                        f"local shape {tuple(a.shape)} can "
+                        f"differ per device but is not "
+                        f"grid-block shaped — ambiguous (a "
+                        f"per-device diagnostic?).  Reduce it "
+                        f"with a full-mesh collective (jax.lax."
+                        f"psum/pmax/pmin over igg.AXIS_NAMES) "
+                        f"or pass explicit out_specs=.")
+            o_specs = out_tree.unflatten(specs_flat)
+    else:
+        o_specs = out_specs
+    sm = jax.shard_map(f, mesh=grid.mesh, in_specs=tuple(in_specs),
+                       out_specs=o_specs, check_vma=check_vma)
+    return jax.jit(profiling.scoped(profiling.STENCIL)(sm),
+                   donate_argnums=tuple(donate_argnums))
+
+
 def sharded(fn=None, *, donate_argnums: Sequence[int] = (),
             out_specs=None, check_vma: bool = True):
     """Compile `fn`, written over per-device *local* arrays (the reference's
@@ -243,94 +358,34 @@ def sharded(fn=None, *, donate_argnums: Sequence[int] = (),
         def wrapper(*args):
             import jax
 
-            shared.check_initialized()
-            grid = shared.global_grid()
-            leaves, treedef = jax.tree.flatten(args)
-            fk = _fn_key(f)
-            key = (shared.grid_epoch(), fk, treedef,
-                   tuple(donate_argnums), repr(out_specs), check_vma,
-                   tuple((getattr(x, "shape", ()),
-                          str(getattr(x, "dtype", type(x)))) for x in leaves))
-            jfn = _cache_get(key)
-            if jfn is None:
-                if fk is f:
-                    _log_identity_miss(f)
-                from jax.sharding import PartitionSpec as P
-
-                in_specs = jax.tree.map(lambda x: _leaf_spec(x, grid), args)
-                if out_specs is None:
-                    # Infer the output specs by abstract tracing with the mesh
-                    # axes bound (so collectives/axis_index trace), combining
-                    # two facts per output leaf:
-                    #   - does its local shape look like a grid block
-                    #     (stagger/flux margin of the local grid size)?
-                    #   - can it hold *different values on different devices*
-                    #     (taint analysis, `_device_varying_outvars`)?
-                    # Device-varying grid-shaped outputs are grid fields
-                    # (replication is not even meaningful for them);
-                    # device-invariant non-grid outputs are replicated
-                    # (provably correct).  The two mixed cases are genuinely
-                    # ambiguous and raise, demanding explicit `out_specs` —
-                    # never a silent wrong answer (a replicated diagnostic
-                    # that happens to be (nx,ny,nz)-shaped must not be
-                    # concatenated into a fake "global" array).
-                    local_avals = jax.tree.map(lambda x: _local_aval(x, grid), args)
-                    axis_env = [(a, grid.dims[d])
-                                for d, a in enumerate(AXIS_NAMES)]
-                    jaxpr, out_aval = jax.make_jaxpr(
-                        f, axis_env=axis_env, return_shape=True)(*local_avals)
-                    varying = _device_varying_outvars(
-                        jaxpr.jaxpr,
-                        [_is_grid_leaf(x, grid) for x in leaves],
-                        all_axes=[a for d, a in enumerate(AXIS_NAMES)
-                                  if grid.dims[d] > 1])
-                    out_leaves, out_tree = jax.tree.flatten(out_aval)
-                    if grid.nprocs == 1:
-                        # One device: sharding and replication coincide;
-                        # keep the historical (shard-grid-shaped) behavior.
-                        o_specs = out_tree.unflatten([
-                            spec_for(len(a.shape))
-                            if _is_grid_local_shape(a.shape, grid) else P()
-                            for a in out_leaves])
-                    else:
-                        specs_flat = []
-                        for i, (a, var) in enumerate(zip(out_leaves, varying)):
-                            gridlike = _is_grid_local_shape(a.shape, grid)
-                            if gridlike and var:
-                                specs_flat.append(spec_for(len(a.shape)))
-                            elif not gridlike and not var:
-                                specs_flat.append(P())
-                            elif gridlike:
-                                raise GridError(
-                                    f"igg.sharded: output leaf {i} has the "
-                                    f"local shape {tuple(a.shape)} of a grid "
-                                    f"block but is provably identical on "
-                                    f"every device — ambiguous between a "
-                                    f"constant grid field and a replicated "
-                                    f"diagnostic.  Pass out_specs= (e.g. "
-                                    f"igg.spec_for({len(a.shape)}) to stack "
-                                    f"it as a grid field, or "
-                                    f"jax.sharding.PartitionSpec() to keep "
-                                    f"one copy).")
-                            else:
-                                raise GridError(
-                                    f"igg.sharded: output leaf {i} with "
-                                    f"local shape {tuple(a.shape)} can "
-                                    f"differ per device but is not "
-                                    f"grid-block shaped — ambiguous (a "
-                                    f"per-device diagnostic?).  Reduce it "
-                                    f"with a full-mesh collective (jax.lax."
-                                    f"psum/pmax/pmin over igg.AXIS_NAMES) "
-                                    f"or pass explicit out_specs=.")
-                        o_specs = out_tree.unflatten(specs_flat)
+            with profiling.annotate("igg.sharded"), \
+                    profiling.program_frame():
+                shared.check_initialized()
+                grid = shared.global_grid()
+                leaves, treedef = jax.tree.flatten(args)
+                fk = _fn_key(f)
+                key = (shared.grid_epoch(), fk, treedef,
+                       tuple(donate_argnums), repr(out_specs), check_vma,
+                       tuple((getattr(x, "shape", ()),
+                              str(getattr(x, "dtype", type(x))))
+                             for x in leaves))
+                prog = _cache_get(key)
+                if prog is None:
+                    with profiling.annotate("igg.compile"):
+                        if fk is f:
+                            _log_identity_miss(f)
+                        telemetry.counter(
+                            "igg_sharded_compiles_total",
+                            fn=getattr(f, "__qualname__", repr(f))).inc()
+                        prog = _Program(_build(
+                            f, args, grid, donate_argnums=donate_argnums,
+                            out_specs=out_specs, check_vma=check_vma))
+                        _cache_put(key, prog)
+                        avals = jax.tree.map(_aval, args)
+                        out = prog.jfn(*args)
+                        prog.avals = avals
                 else:
-                    o_specs = out_specs
-                sm = jax.shard_map(f, mesh=grid.mesh,
-                                   in_specs=tuple(in_specs),
-                                   out_specs=o_specs, check_vma=check_vma)
-                jfn = jax.jit(sm, donate_argnums=tuple(donate_argnums))
-                _cache_put(key, jfn)
-            out = jfn(*args)
+                    out = prog.jfn(*args)
             if grid.needs_cpu_sync:
                 jax.block_until_ready(out)
             return out

@@ -233,6 +233,7 @@ def fused_spec_step(spec: StencilSpec, coeffs: Dict, fields,
                 whole_block_vmem([a.shape for a in operands])))
     news = pl.pallas_call(
         partial(_step_kernel, spec=spec, coeffs=coeffs),
+        name="stencil_spec_step",
         out_shape=tuple(shp(a) for a in operands),
         interpret=interpret,
         **kwargs,

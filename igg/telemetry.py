@@ -59,8 +59,9 @@ diagnostics-without-host-sync as a first-class design axis):
 - **Trace spans.**  :func:`span` records a named host-side region
   (checkpoint write/drain, rollback, halo compile, verify-first-use,
   fleet job lifecycle) as a `span` record and mirrors it onto the
-  device timeline via `jax.profiler.TraceAnnotation` (so spans line up
-  with the XLA profiler trace of :func:`igg.profiling.trace`);
+  profiler timeline via :func:`igg.profiling.annotate` (so spans line up
+  with the XLA profiler trace of :func:`igg.profiling.trace`; the hot
+  path's per-dispatch spans are profiler-only, `igg.profiling`);
   :func:`export_chrome_trace` renders spans as Chrome-trace/Perfetto
   JSON (a session writes `trace_r<rank>.json` at detach).
 
@@ -90,6 +91,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from . import profiling
 from .shared import GridError
 
 __all__ = [
@@ -382,6 +384,15 @@ _BUILTIN_HELP: Dict[str, str] = {
                                "the degradation ladder.",
     "igg_tier_quarantined_total": "Kernel tiers quarantined by the "
                                   "degradation ladder.",
+    "igg_dispatch_host_seconds": "Host seconds of a ladder dispatch that "
+                                 "compiled nothing, from entry until the "
+                                 "served executable was enqueued.",
+    "igg_compile_seconds": "Seconds of JAX tracing, lowering to MLIR "
+                           "(Pallas/Mosaic included) and backend compile "
+                           "or cache fetch inside igg's own dispatches, "
+                           "by stage.",
+    "igg_sharded_compiles_total": "igg.sharded compiled-program cache "
+                                  "misses, by step function.",
     "igg_member_quarantined_total": "Ensemble members quarantined after "
                                     "retry-budget exhaustion.",
     "igg_fleet_queue_depth": "Fleet jobs not yet terminal this drain.",
@@ -637,49 +648,22 @@ def prometheus_text() -> str:
 # Trace spans
 # ---------------------------------------------------------------------------
 
-_device_annotation_ok = True   # flipped off permanently on first failure
-
-
-def _device_annotation(name: str):
-    """A `jax.profiler.TraceAnnotation` for mirroring a host span onto the
-    device timeline — None when disabled (`IGG_TELEMETRY_DEVICE=0`) or
-    unavailable (disabled permanently on first failure, so a broken
-    profiler backend costs one try, not one per span)."""
-    global _device_annotation_ok
-    if not _device_annotation_ok:
-        return None
-    try:
-        if not _env().flag("IGG_TELEMETRY_DEVICE", True):
-            return None   # knob is off NOW — it may be turned back on
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except GridError:
-        raise
-    except Exception:
-        _device_annotation_ok = False
-        return None
-
-
 @contextlib.contextmanager
 def span(name: str, step: Optional[int] = None, **attrs):
     """Record the enclosed block as a named trace span: one `span` record
     on the bus (payload: name, dur_s, start timestamps, thread id, attrs)
-    and a mirrored `jax.profiler.TraceAnnotation` on the device timeline.
-    `IGG_TELEMETRY_SPANS=0` turns capture off (the block still runs)."""
+    and its mirror on the profiler timeline (:func:`igg.profiling.annotate`).
+    `IGG_TELEMETRY_SPANS=0` turns capture off (the block still runs).  For
+    rare events: a per-dispatch region belongs on the profiler alone."""
     if not _env().flag("IGG_TELEMETRY_SPANS", True):
         yield
         return
-    dev = _device_annotation(name)
     t0 = time.monotonic()
     w0 = time.time()
-    if dev is not None:
-        dev.__enter__()
     try:
-        yield
+        with profiling.annotate(name):
+            yield
     finally:
-        if dev is not None:
-            dev.__exit__(None, None, None)
         dur = time.monotonic() - t0
         emit("span", step=step, name=name, dur_s=dur, t0=t0, wall0=w0,
              tid=threading.get_ident(), **attrs)

@@ -50,19 +50,22 @@ def _no_persistent_cache():
 
 
 def _compile(local, shapes, out_specs):
-    """`jit(shard_map(local))` on the live grid's mesh, compiled from
-    shapes alone; returns the compiled program."""
+    """The program `igg.sharded` builds for `local` on the live grid's
+    mesh (`jit(shard_map(local))` under the `igg.stencil` scope), compiled
+    from shapes alone; returns the compiled program."""
     import jax
     from jax.sharding import NamedSharding
 
+    from igg import parallel
+
     grid = igg.get_global_grid()
-    specs = tuple(igg.spec_for(len(s)) for s in shapes)
     args = [jax.ShapeDtypeStruct(s, np.float32,
-                                 sharding=NamedSharding(grid.mesh, sp))
-            for s, sp in zip(shapes, specs)]
-    sm = jax.shard_map(local, mesh=grid.mesh, in_specs=specs,
-                       out_specs=out_specs)
-    return jax.jit(sm, donate_argnums=(0,)).lower(*args).compile()
+                                 sharding=NamedSharding(
+                                     grid.mesh, igg.spec_for(len(s))))
+            for s in shapes]
+    jfn = parallel._build(local, args, grid, donate_argnums=(0,),
+                          out_specs=out_specs, check_vma=True)
+    return jfn.lower(*args).compile()
 
 
 def _grid(topo, n, dims, periodic, **kw):

@@ -196,8 +196,7 @@ def test_telemetry_env_knobs_registered():
     from igg import _env
 
     for name in ("IGG_TELEMETRY_DIR", "IGG_TELEMETRY_FLIGHT_RECORDER",
-                 "IGG_TELEMETRY_METRICS_EVERY", "IGG_TELEMETRY_SPANS",
-                 "IGG_TELEMETRY_DEVICE"):
+                 "IGG_TELEMETRY_METRICS_EVERY", "IGG_TELEMETRY_SPANS"):
         assert name in _env._KNOWN, name
 
 
