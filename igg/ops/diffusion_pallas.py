@@ -57,7 +57,8 @@ this is the fused analog of running the XLA path with `overlap=True`.
 
 **Path selection in** :func:`fused_diffusion_steps` (fastest applicable
 wins): the K-step mega-kernel (`diffusion_mega`, 1-device grids, every
-dim self-wrap or frozen, 0.24 ms/step at 256^3) > K-step trapezoidal
+dim self-wrap or frozen, 0.24 ms/step at 256^3; with x open, a
+wavefront of D steps per HBM sweep, 0.93 ms/step at 512^3) > K-step trapezoidal
 chunks (`diffusion_trapezoid`, exchanged rings/tori of ANY per-dim
 periodicity — periodic dims self-wrapped or extended, open dims extended
 with per-device edge freezing (the reference-default boundary condition)
@@ -491,8 +492,9 @@ def fused_diffusion_steps(T, Cp, *, n_inner, dx, dy, dz, dt, lam,
 
         # Fastest: the whole inner loop as ONE pallas_call, coefficient
         # VMEM-resident when it fits and slab-streamed otherwise; open
-        # dims run the frozen-edge mode (see `diffusion_mega` — this is
-        # the reference's published 510^3 open-boundary headline path).
+        # dims run the frozen-edge mode, and an open x the D-step
+        # wavefront (see `diffusion_mega` — this is the reference's
+        # published 510^3 open-boundary headline path).
         if mega_supported(T.shape, bx, n_inner, interpret, dtype=T.dtype):
             return fused_diffusion_megasteps(T, A, n_inner=n_inner, bx=bx,
                                              **scal, modes=modes)

@@ -93,13 +93,39 @@ def _diffusion_steps(n_inner):
         lam=p.lam, bx=bx)
 
 
+def _kernels(compiled):
+    from igg import profiling
+
+    return {i.kernel for i in profiling.instructions(compiled.as_text())
+            if i.kernel}
+
+
 def mega_512_open(topo):
-    """Phase A: the streamed-coefficient frozen-edge mega kernel."""
-    from igg.ops.diffusion_mega import mega_supported
+    """Phase A: the streamed-coefficient frozen-edge mega kernel, as the
+    open-x wavefront (D steps per HBM sweep)."""
+    from igg.ops.diffusion_mega import mega_supported, wavefront_plan
 
     g = _grid(topo, (512,) * 3, (1, 1, 1), periodic=False)
     assert mega_supported(g, 8, N_INNER, False, dtype=np.float32)
-    return _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+    assert wavefront_plan(g, N_INNER, ("frozen",) * 3, np.float32)
+    compiled = _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+    assert _kernels(compiled) == {"diffusion3d_mega_wavefront"}
+    return compiled
+
+
+def mega_512_periodic(topo):
+    """The streamed-coefficient mega kernel with wrap x, one step per
+    sweep (the wavefront serves open x only)."""
+    from igg.ops.diffusion_mega import (mega_supported, resident_a_fits,
+                                        wavefront_plan)
+
+    g = _grid(topo, (512,) * 3, (1, 1, 1), periodic=True)
+    assert mega_supported(g, 8, N_INNER, False, dtype=np.float32)
+    assert not resident_a_fits(g, 8, np.float32)
+    assert not wavefront_plan(g, N_INNER, ("wrap",) * 3, np.float32)
+    compiled = _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+    assert _kernels(compiled) == {"diffusion3d_mega"}
+    return compiled
 
 
 def mega_256_periodic(topo):
@@ -174,7 +200,8 @@ def stokes_trapezoid_4x1(topo):
 
 
 @pytest.mark.parametrize("case", [
-    mega_512_open, mega_256_periodic, perstep_with_halo_writer,
+    mega_512_open, mega_512_periodic, mega_256_periodic,
+    perstep_with_halo_writer,
     diffusion_trapezoid_2x2, stokes_trapezoid_4x1],
     ids=lambda f: f.__name__)
 def test_compiles_for_v5e(topo, case):

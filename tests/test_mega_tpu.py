@@ -554,6 +554,44 @@ def test_mega_frozen_modes_match_per_step_kernel(periods, streamed):
     igg.finalize_global_grid()
 
 
+@pytest.mark.parametrize("shape, periods, n_inner, streamed", [
+    ((64, 64, 128), (0, 0, 0), 10, False),
+    ((64, 64, 128), (0, 0, 0), 11, True),
+    ((64, 64, 128), (0, 1, 1), 10, True),
+    ((64, 64, 128), (0, 1, 1), 11, False),
+    ((64, 64, 128), (0, 1, 0), 10, False),
+    ((64, 64, 128), (0, 0, 1), 11, True),
+    ((12, 64, 128), (0, 1, 0), 7, True),
+    ((512, 512, 512), (0, 0, 0), 10, False),
+], ids=["open_2x5", "open_8+3_streamed", "wrapyz_2x5_streamed",
+        "wrapyz_8+3", "wrapy_2x5", "wrapz_8+3_streamed",
+        "three_slabs_7_streamed", "512_open_2x5"])
+def test_mega_wavefront_matches_per_step_sweep(shape, periods, n_inner,
+                                               streamed):
+    """The open-x wavefront (D steps per HBM sweep) against the kernel
+    that sweeps HBM once per step, bit for bit: identical arithmetic per
+    cell, only the order of the rows changes.  n_inner 10 runs two sweeps
+    of 5, 11 one of 8 plus a 3-step remainder sweep; 512^3 streams its
+    coefficient whatever `streamed` says."""
+    import jax.numpy as jnp
+
+    from igg.ops.diffusion_mega import (fused_diffusion_megasteps,
+                                        wavefront_plan)
+
+    modes = tuple("wrap" if p else "frozen" for p in periods)
+    assert wavefront_plan(shape, n_inner, modes, np.float32)
+    rng = np.random.default_rng(n_inner)
+    T = jnp.asarray(rng.standard_normal(shape, dtype=np.float32))
+    A = jnp.asarray((0.05 + 0.1 * rng.random(shape, dtype=np.float32)))
+    kw = dict(n_inner=n_inner, bx=8 if shape[0] >= 16 else 4, rdx2=1.0,
+              rdy2=1.1, rdz2=0.9, modes=modes, force_streamed=streamed)
+    out = np.asarray(fused_diffusion_megasteps(jnp.array(T), A, **kw))
+    ref = np.asarray(fused_diffusion_megasteps(jnp.array(T), A, **kw,
+                                               _per_step_sweep=True))
+    assert np.array_equal(out, ref), np.abs(out - ref).max()
+    assert not np.array_equal(out, np.asarray(T))
+
+
 def test_f64_rank4_halo_oracle_on_chip():
     """Rank-4 component-stacked Float64 fields on real hardware: the halo
     dims (0,1,2) miss the lane (component) axis, so this exercises the

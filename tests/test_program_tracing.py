@@ -43,7 +43,7 @@ def _compiled(topo, case):
 
 
 @pytest.mark.parametrize("case, kernels", [
-    (mega_512_open, {"diffusion3d_mega"}),
+    (mega_512_open, {"diffusion3d_mega_wavefront"}),
     (diffusion_trapezoid_2x2, {"diffusion3d_trapezoid_chunk",
                                "diffusion3d_step"})],
     ids=["mega_512_open", "diffusion_trapezoid_2x2"])
