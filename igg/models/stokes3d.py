@@ -56,6 +56,13 @@ def init_fields(params: Params = Params(), dtype=np.float32):
     return P, Vx, Vy, Vz, Rho
 
 
+def divergence(Vx, Vy, Vz, *, dx, dy, dz):
+    """The velocity divergence at cell centers (full pressure shape)."""
+    return ((Vx[1:, :, :] - Vx[:-1, :, :]) / dx
+            + (Vy[:, 1:, :] - Vy[:, :-1, :]) / dy
+            + (Vz[:, :, 1:] - Vz[:, :, :-1]) / dz)
+
+
 def iteration_core(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
                    buoy_axis: int = 2):
     """The raw coupled arithmetic shared VERBATIM by the XLA path and the
@@ -70,10 +77,7 @@ def iteration_core(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
     y<->z swap of axes, fields, and spacings, which the fused kernel's
     transposed z-window send-plane computation exploits with
     `buoy_axis=1`."""
-    # Divergence at cell centers
-    divV = ((Vx[1:, :, :] - Vx[:-1, :, :]) / dx
-            + (Vy[:, 1:, :] - Vy[:, :-1, :]) / dy
-            + (Vz[:, :, 1:] - Vz[:, :, :-1]) / dz)
+    divV = divergence(Vx, Vy, Vz, dx=dx, dy=dy, dz=dz)
     P = P - dtP * divV
 
     # Deviatoric normal stresses at centers
@@ -259,7 +263,10 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
     window, HBM ping-pong; the ladder rung below the resident
     trapezoid): "auto" (default) engages it only where the resident
     tier's `fit_stokes_K` refuses (the VMEM K-bound at headline
-    shapes), True requires it, False pins the resident tiers.  `band`
+    shapes) and the per-iteration kernel cannot keep its full slab
+    height within the VMEM cap (`stokes_pallas.full_slab_fits`; at 256^3
+    the per-iteration kernel serves), True requires it, False pins the
+    resident tiers.  `band`
     overrides the auto-fitted band depth B (`fit_stokes_band`)."""
     from jax import lax
 
@@ -429,6 +436,7 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
     def admit_banded(args):
         from igg.degrade import Admission
         from igg.ops import stokes_pallas_supported
+        from igg.ops.stokes_pallas import full_slab_fits
 
         from ._dispatch import pallas_applicable
 
@@ -460,6 +468,15 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
                 return Admission.no(
                     "the resident chunk tier serves this shape (the "
                     "banded rung engages where fit_stokes_K refuses)")
+            # Where the per-iteration kernel keeps its full slab height,
+            # the streaming chunk's recomputed 2K margins cost more than
+            # the HBM traffic they save (256^3 periodic on one TPU v5e:
+            # 2.64 ms per iteration banded, 2.27 per-iteration).
+            if full_slab_fits(lshape[1], lshape[2]):
+                return Admission.no(
+                    "the per-iteration kernel fits VMEM at its full slab "
+                    "height here (the banded rung engages where it "
+                    "does not)")
         if not _fit_band(grid, lshape, P.dtype):
             return Admission.no(
                 "no banded config (K, B) admissible "
@@ -527,6 +544,50 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
         donate_argnums=donate_argnums,
         family="stokes3d", verify=verify,
         extra_tiers=(trap_tier, banded_tier))
+
+
+def residual_norms(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu):
+    """The four max-norms of the state's residuals over this device's
+    block, reduced over the whole grid (`pmax`): the continuity residual
+    `divergence` at cell centers and the momentum residuals of
+    `iteration_core` (with `dtP=0`, so they read the pressure as given)
+    on the faces the iteration updates.  Call inside SPMD code; every op
+    runs under the `igg.residual` scope."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from igg import profiling
+
+    with jax.named_scope(profiling.RESIDUAL):
+        div = divergence(Vx, Vy, Vz, dx=dx, dy=dy, dz=dz)
+        _, rx, ry, rz = iteration_core(P, Vx, Vy, Vz, Rho, dx=dx, dy=dy,
+                                       dz=dz, mu=mu, dtP=0.0, dtV=1.0)
+        norms = jnp.stack([jnp.max(jnp.abs(r)) for r in (div, rx, ry, rz)])
+        return lax.pmax(norms, igg.AXIS_NAMES)
+
+
+def residual(P, Vx, Vy, Vz, Rho, params: Params = Params()):
+    """(max|div V|, max|Rx|, max|Ry|, max|Rz|) of the state, as host
+    floats: the check the published pseudo-transient solver makes every
+    `nout` iterations to decide whether to stop.  One `igg.sharded`
+    program (the norms and their global `pmax`) and one read-back to the
+    host, which waits for every dispatch queued before it.  Counts each
+    check in `igg_residual_checks_total{family="stokes3d"}`."""
+    import jax
+
+    from igg import telemetry
+
+    kw = _pseudo_steps(params)
+    dx, dy, dz, mu = kw["dx"], kw["dy"], kw["dz"], kw["mu"]
+
+    def norms(P, Vx, Vy, Vz, Rho):
+        return residual_norms(P, Vx, Vy, Vz, Rho, dx=dx, dy=dy, dz=dz,
+                              mu=mu)
+
+    telemetry.counter("igg_residual_checks_total", family="stokes3d").inc()
+    got = jax.device_get(igg.sharded(norms)(P, Vx, Vy, Vz, Rho))
+    return tuple(float(v) for v in got)
 
 
 def run(n_iters: int, params: Params = Params(), dtype=np.float32,

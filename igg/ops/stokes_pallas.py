@@ -3,12 +3,10 @@
 One `pallas_call` performs a full pseudo-transient Stokes iteration —
 pressure update, six stresses, three momentum residuals, velocity updates,
 AND the grouped halo update of P/Vx/Vy/Vz — reading each field once and
-writing each updated field once.  The XLA composition
-(`stokes3d.local_iteration`: compute + `update_halo_local(P,Vx,Vy,Vz)`)
-costs ~0.27 ms/iter at 128^3 on v5e, of which the 4-field halo phase alone
-is ~0.26 ms measured in isolation (each field pays its own read+write
-assembly pass, plus XLA's multi-field layout copies); the fused kernel's
-traffic is the ideal 5 reads + 4 writes.
+writing each updated field once: the ideal 5 reads + 4 writes, where the
+XLA composition (`stokes3d.local_iteration`: compute +
+`update_halo_local(P,Vx,Vy,Vz)`) pays a read and write pass per field for
+the halo phase on top.
 
 This is the TPU re-expression of the reference's native-kernel performance
 tier (">10x faster" than the array-broadcast form,
@@ -16,19 +14,14 @@ tier (">10x faster" than the array-broadcast form,
 *every* rank of a decomposed run — the per-rank property of the
 reference's native tier — not just the single-device configuration.
 
-Measured on v5e at 128^3 f32 (median-of-3, 100-iteration dispatches,
-self-wrap grid): **0.143 ms/iter** vs 0.224 for the XLA composition
-(1.57x; round-5 artifact refresh — the round-5 ext-plane writer gate
-also sped the composition itself up from 0.278);
-matches the XLA path to ~1e-7 relative on the chip (identical
-`iteration_core` arithmetic).  The DMA floor of this structure measured
-with a no-op core is 0.108 ms (~790 GB/s on ~85 MB/iter of traffic,
-including the 2x lane padding of Vz's (S,S,S+1) shape), so the remaining
-gap to ideal is non-overlapped VPU time.  `docs/stokes_roofline.md`
-carries the full traffic accounting: the structure is jointly DMA- and
-VPU-bound and its ceiling is ~2.1x over the round-5 composition — no
-per-iteration kernel of this solver reaches 3x at f32 128^3; only
-temporal blocking or bf16 break the bound.
+Measured on one TPU v5e at 256^3 f32, periodic (one run of the
+benchmark's `st3-256-periodic.ptcheck` cell: 100-iteration dispatches,
+a residual check after each, host clock over a 10 s window): 2.27 ms per
+iteration, against 2.64 for the streaming banded chunk tier at the same
+shape.  The kernel moves at least 0.61 GB an iteration (5 reads + 4
+writes), 0.74 ms at the HBM peak, so at this size HBM bandwidth is not
+what bounds it.
+`docs/stokes_roofline.md` carries the traffic accounting.
 
 Structure (the radius-2 staggered four-field instance of the
 `diffusion_pallas` recipe):
@@ -84,17 +77,15 @@ from functools import partial
 from .diffusion_pallas import _wrap_dims, _wrap_set
 
 # Deliberately TIGHT when the working set allows: the scoped-vmem budget
-# steers Mosaic's scheduling, and a small budget produces far better
-# DMA/compute interleaving for this kernel.  Swept on v5e at 128^3
-# (median-of-3, ms/iter): 20MB 0.138, 26MB 0.137, 32MB 0.136, 44MB 0.139,
-# 56MB 0.157, 64MB 0.175, 100MB 0.224, 128MB 0.40.  At 128^3 the working
-# set sits below 20MB and the floor budget applies; larger y*z areas
-# (256^3-class) NEED more than the floor — the per-call limit grows with
-# `_vmem_need` up to the hard cap (round 5: 256^3 OOM'd at Mosaic compile
-# under the fixed 32MB budget; with the grown 82MB budget it runs
-# bx=8 at 2.19 ms/iter vs 7.42 XLA — 3.4x — the shipped-and-measured
-# configuration).
-from ._vmem import fit_bx, vmem_limit
+# steers Mosaic's scheduling, and a small budget gave better DMA/compute
+# interleaving for this kernel in an early sweep at 128^3.  Larger y*z
+# areas (256^3-class) NEED more than the floor, so the per-call limit
+# grows with `_vmem_need` up to the hard cap (a fixed 32 MB budget failed
+# Mosaic's compile at 256^3).  At 256^3 the kernel runs bx=8 at 2.27 ms
+# per iteration on one TPU v5e (see the module docstring).
+from ._vmem import fit_bx, vmem_cap, vmem_limit
+
+_BX_FULL = 8     # the slab height the kernel starts its fit from
 
 
 def _vmem_limit(bx: int, S1: int, S2: int) -> int:
@@ -119,6 +110,14 @@ def _fit_bx(bx: int, S0: int, S1: int, S2: int,
                   check_vmem=check_vmem)
 
 
+def full_slab_fits(S1: int, S2: int) -> bool:
+    """Whether the fused iteration's windows fit the VMEM cap at the full
+    slab height for a local y*z area `S1 x S2` (the same model in compiled
+    and interpret mode, so interpret meshes see the compiled ladder's
+    choice)."""
+    return _vmem_need(_BX_FULL, S1, S2) <= vmem_cap()
+
+
 def stokes_pallas_supported(grid, P, interpret: bool = False):
     """Whether the fused iteration applies: overlap-3 grid (any device
     count and any periodicity — the exchange engine handles open boundaries
@@ -141,7 +140,7 @@ def stokes_pallas_supported(grid, P, interpret: bool = False):
     if not (s[0] % 8 == 0 and s[0] >= 16 and s[1] >= 8 and s[2] >= 8):
         return Admission.no(f"local block {s} too small to slab "
                             f"(needs x % 8 == 0, x >= 16, y >= 8, z >= 8)")
-    if _fit_bx(8, s[0], s[1], s[2], check_vmem=not interpret) < 4:
+    if _fit_bx(_BX_FULL, s[0], s[1], s[2], check_vmem=not interpret) < 4:
         return Admission.no(f"no slab height bx >= 4 fits the VMEM budget "
                             f"for local y*z area {s[1]}x{s[2]}")
     return Admission.yes()
@@ -341,7 +340,7 @@ def _kernel(*refs, bx, nb, shapes, scal, wrap_y, wrap_z):
 
 
 def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
-                           bx: int = 8, interpret: bool = False):
+                           bx: int = _BX_FULL, interpret: bool = False):
     """One fused Stokes pseudo-transient iteration
     `(P, Vx, Vy, Vz, Rho) -> (P', Vx', Vy', Vz')` with halo maintenance
     included, on any mesh (see module docstring).  Call inside SPMD code

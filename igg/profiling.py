@@ -12,7 +12,9 @@ The program names its own work at three layer boundaries:
 
 - **Device scopes.**  Every op of a program :func:`igg.sharded` compiles
   runs under the `jax.named_scope` :data:`STENCIL`; the halo engine's
-  slab and plane cutting, sending and stitching under :data:`HALO`.  Each
+  slab and plane cutting, sending and stitching under :data:`HALO`; a
+  solver's convergence check (its residual norms and their global
+  reduction) under :data:`RESIDUAL`.  Each
   Mosaic kernel carries a stable `pallas_call` name.  :func:`op_scopes`
   maps each compiled instruction, as the profiler names its device op, to
   its innermost `igg.*` scope and kernel name.
@@ -40,7 +42,8 @@ from .shared import GridError
 
 STENCIL = "igg.stencil"     # every op of a program igg.sharded compiles
 HALO = "igg.halo"           # the halo engine's cut, send and stitch
-SCOPES = (STENCIL, HALO)
+RESIDUAL = "igg.residual"   # a solver's residual norms and their reduction
+SCOPES = (STENCIL, HALO, RESIDUAL)
 
 # Re-entrancy guard: `jax.profiler.start_trace` raises mid-flight when a
 # trace is already active, which used to surface as an opaque runtime error
@@ -235,7 +238,7 @@ class Instruction(NamedTuple):
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%(\S+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%(\S+) = (.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_SCOPE = re.compile(r"igg\.(?:stencil|halo)\b")
+_SCOPE = re.compile(r"igg\.(?:stencil|halo|residual)\b")
 _NOT_RUN = re.compile(r"\b(?:calls|to_apply)=%([\w.\-]+)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 

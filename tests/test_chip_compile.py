@@ -199,10 +199,37 @@ def stokes_trapezoid_4x1(topo):
     return _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
 
 
+def stokes_mosaic_256_periodic(topo):
+    """The rung `stokes3d.make_iteration`'s ladder serves for the Stokes
+    cell's 256^3 periodic one-chip block: the per-iteration fused kernel
+    (the resident chunk tier's K-bound refuses the shape, and the banded
+    rung stays off while this kernel keeps its full slab height).  The
+    local program is the ladder's own."""
+    import jax
+
+    from igg.models import stokes3d
+
+    n = 256
+    g = _grid(topo, (n,) * 3, (1, 1, 1), periodic=True, overlapx=3,
+              overlapy=3, overlapz=3)
+    ladder = stokes3d.make_iteration(n_inner=N_INNER).ladder
+    P = (jax.ShapeDtypeStruct(g, np.float32),)
+    tiers = {t.name: t for t in ladder.tiers}
+    assert not tiers["stokes3d.trapezoid"].admit(P)
+    assert not tiers["stokes3d.banded"].admit(P)
+    assert tiers["stokes3d.mosaic"].admit(P)
+    local = tiers["stokes3d.mosaic"].build().__wrapped__
+    stag = [tuple(n + (d == i) for d in range(3)) for i in range(3)]
+    compiled = _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
+    assert _kernels(compiled) == {"stokes3d_iteration"}
+    return compiled
+
+
 @pytest.mark.parametrize("case", [
     mega_512_open, mega_512_periodic, mega_256_periodic,
     perstep_with_halo_writer,
-    diffusion_trapezoid_2x2, stokes_trapezoid_4x1],
+    diffusion_trapezoid_2x2, stokes_trapezoid_4x1,
+    stokes_mosaic_256_periodic],
     ids=lambda f: f.__name__)
 def test_compiles_for_v5e(topo, case):
     import jax
