@@ -615,7 +615,10 @@ class Ladder:
         except Exception:   # pragma: no cover - advisory path
             pass
 
-    def _record_active(self, tier_name: str) -> int:
+    def _record_active(self, tier_name: str, fn: Callable) -> int:
+        """Stamp the dispatch `tier_name` served with `fn`, and count it
+        with the counts each dispatch of `fn` performs
+        (`igg.parallel.count_per_dispatch`)."""
         global _DISPATCHES
         with _lock:
             _DISPATCHES += 1
@@ -624,6 +627,9 @@ class Ladder:
             _ACTIVE_STAMP[self.family] = stamp
         _telemetry.counter("igg_tier_dispatch_total", family=self.family,
                            tier=tier_name).inc()
+        for name, n in getattr(fn, "per_dispatch", {}).items():
+            _telemetry.counter(name, family=self.family,
+                               tier=tier_name).inc(n)
         return stamp
 
     def dispatch(self, *args):
@@ -681,8 +687,9 @@ class Ladder:
             t = self.tiers[i]
             i += 1
             if t.truth:
-                out = self._fn(t)(*args)
-                return out, t.name, self._record_active(t.name)
+                fn = self._fn(t)
+                out = fn(*args)
+                return out, t.name, self._record_active(t.name, fn)
             try:
                 fn = self._fn(t)
                 if self._verify_enabled():
@@ -717,4 +724,4 @@ class Ladder:
                         f"{type(e).__name__}: {e}") from e
                 continue
             _SERVED.add(t.name)
-            return out, t.name, self._record_active(t.name)
+            return out, t.name, self._record_active(t.name, fn)

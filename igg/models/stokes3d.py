@@ -401,6 +401,7 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
 
     def build_trapezoid():
         from igg.ops import fused_stokes_iteration
+        from igg.ops.stokes_pallas import stokes_iterations
         from igg.ops.stokes_trapezoid import fused_stokes_trapezoid_iters
 
         def trap_it(P, Vx, Vy, Vz, Rho):
@@ -421,14 +422,9 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
             *state, done = fused_stokes_trapezoid_iters(
                 *state, Rho, n_inner=n_inner - 1, K=Kf, **kw_it,
                 interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:    # remainder through the per-iteration kernel
-                state = lax.fori_loop(
-                    0, n,
-                    lambda _, S: fused_stokes_iteration(
-                        *S, Rho, **kw_it, interpret=pallas_interpret),
-                    tuple(state))
-            return tuple(state)
+            # The remainder through the per-iteration kernel.
+            return stokes_iterations(state, Rho, n_inner - 1 - done,
+                                     **kw_it, interpret=pallas_interpret)
 
         return igg.sharded(trap_it, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
@@ -471,7 +467,7 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
             # Where the per-iteration kernel keeps its full slab height,
             # the streaming chunk's recomputed 2K margins cost more than
             # the HBM traffic they save (256^3 periodic on one TPU v5e:
-            # 2.64 ms per iteration banded, 2.27 per-iteration).
+            # 2.64 ms per iteration banded, 1.38 per-iteration).
             if full_slab_fits(lshape[1], lshape[2]):
                 return Admission.no(
                     "the per-iteration kernel fits VMEM at its full slab "
@@ -485,6 +481,7 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
 
     def build_banded():
         from igg.ops import fused_stokes_iteration
+        from igg.ops.stokes_pallas import stokes_iterations
         from igg.ops.stokes_trapezoid import fused_stokes_banded_iters
 
         def banded_it(P, Vx, Vy, Vz, Rho):
@@ -501,28 +498,20 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
             *state, done = fused_stokes_banded_iters(
                 *state, Rho, n_inner=n_inner - 1, K=Kf, B=Bf, **kw_it,
                 interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:    # remainder through the per-iteration kernel
-                state = lax.fori_loop(
-                    0, n,
-                    lambda _, S: fused_stokes_iteration(
-                        *S, Rho, **kw_it, interpret=pallas_interpret),
-                    tuple(state))
-            return tuple(state)
+            # The remainder through the per-iteration kernel.
+            return stokes_iterations(state, Rho, n_inner - 1 - done,
+                                     **kw_it, interpret=pallas_interpret)
 
         return igg.sharded(banded_it, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
 
     def build_pallas_steps():
-        from igg.ops import fused_stokes_iteration
+        from igg.ops.stokes_pallas import stokes_iterations
 
         def pallas_it(P, Vx, Vy, Vz, Rho):
             kw_it = dict(dx=dx, dy=dy, dz=dz, mu=mu, dtP=dtP, dtV=dtV)
-            return lax.fori_loop(
-                0, n_inner,
-                lambda _, S: fused_stokes_iteration(
-                    *S, Rho, **kw_it, interpret=pallas_interpret),
-                (P, Vx, Vy, Vz))
+            return stokes_iterations((P, Vx, Vy, Vz), Rho, n_inner, **kw_it,
+                                     interpret=pallas_interpret)
 
         return pallas_it
 

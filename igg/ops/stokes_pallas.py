@@ -14,11 +14,13 @@ tier (">10x faster" than the array-broadcast form,
 *every* rank of a decomposed run — the per-rank property of the
 reference's native tier — not just the single-device configuration.
 
-Measured on one TPU v5e at 256^3 f32, periodic (one run of the
-benchmark's `st3-256-periodic.ptcheck` cell: 100-iteration dispatches,
-a residual check after each, host clock over a 10 s window): 2.27 ms per
-iteration, against 2.64 for the streaming banded chunk tier at the same
-shape.  The kernel moves at least 0.61 GB an iteration (5 reads + 4
+Measured on one TPU v5e at 256^3 f32, periodic (the benchmark's
+`st3-256-periodic.ptcheck` cell: 100-iteration dispatches, a residual
+check after each, host clock over a 10 s window): 1.38 ms per iteration
+through :func:`stokes_iterations`, of which the kernel takes 1.14; a plain
+`fori_loop` of the kernel ran 2.27, as XLA copied every iteration's four
+fresh outputs back into the loop carry, and the streaming banded chunk
+tier 2.64.  The kernel moves at least 0.61 GB an iteration (5 reads + 4
 writes), 0.74 ms at the HBM peak, so at this size HBM bandwidth is not
 what bounds it.
 `docs/stokes_roofline.md` carries the traffic accounting.
@@ -81,7 +83,7 @@ from .diffusion_pallas import _wrap_dims, _wrap_set
 # interleaving for this kernel in an early sweep at 128^3.  Larger y*z
 # areas (256^3-class) NEED more than the floor, so the per-call limit
 # grows with `_vmem_need` up to the hard cap (a fixed 32 MB budget failed
-# Mosaic's compile at 256^3).  At 256^3 the kernel runs bx=8 at 2.27 ms
+# Mosaic's compile at 256^3).  At 256^3 the kernel runs bx=8 at 1.14 ms
 # per iteration on one TPU v5e (see the module docstring).
 from ._vmem import fit_bx, vmem_cap, vmem_limit
 
@@ -247,7 +249,7 @@ def _sends_and_stales(P, Vx, Vy, Vz, Rho, scal, wrap_yz):
     return sends, stales
 
 
-def _kernel(*refs, bx, nb, shapes, scal, wrap_y, wrap_z):
+def _kernel(*refs, bx, nb, shapes, scal, wrap_y, wrap_z, into):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -275,6 +277,7 @@ def _kernel(*refs, bx, nb, shapes, scal, wrap_y, wrap_z):
     pl_, vyl, vzl = take(3)            # x last planes (Vx's is post-kernel)
     y_in = take(0 if wrap_y else 8)    # (P f,l, Vx f,l, Vy f,l, Vz f,l)
     z_in = take(0 if wrap_z else 8)
+    take(4 if into else 0)             # the outputs' buffers, never read
     oP, oVx, oVy, oVz = take(4)
 
     Pn, dVx, dVy, dVz = iteration_core(eP, eVx, eVy, eVz, eRho, **scal)
@@ -340,14 +343,22 @@ def _kernel(*refs, bx, nb, shapes, scal, wrap_y, wrap_z):
 
 
 def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
-                           bx: int = _BX_FULL, interpret: bool = False):
+                           bx: int = _BX_FULL, interpret: bool = False,
+                           into=None):
     """One fused Stokes pseudo-transient iteration
     `(P, Vx, Vy, Vz, Rho) -> (P', Vx', Vy', Vz')` with halo maintenance
     included, on any mesh (see module docstring).  Call inside SPMD code
     (`igg.sharded` / shard_map); on a 1-device grid the exchange
     degenerates to local copies and the function also works under plain
     `jax.jit`.  Matches `stokes3d.local_iteration(..., overlap=True)` to
-    Mosaic-vs-XLA rounding (overlap semantics are built in)."""
+    Mosaic-vs-XLA rounding (overlap semantics are built in).
+
+    `into`: a dead `(P, Vx, Vy, Vz)`-shaped buffer set the outputs are
+    written into (aliased, never read), for a loop that alternates two
+    state sets (:func:`stokes_iterations`).  The outputs cannot alias the
+    inputs themselves: each slab program reads its neighbours' margin rows
+    (program 0 the last rows, through the modular index maps), which an
+    in-place write of the slab before it would already have replaced."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -420,6 +431,11 @@ def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
                 operands.append(pln)
                 in_specs.append(pl.BlockSpec((bx, pln.shape[1]),
                                              lambda i: (i, 0)))
+    aliases = {}
+    if into is not None:
+        aliases = {len(operands) + k: k for k in range(4)}
+        operands.extend(into)
+        in_specs.extend([pl.BlockSpec(memory_space=pl.ANY)] * 4)
 
     vmas = [getattr(getattr(x, "aval", None), "vma", None) for x in operands]
     vma = frozenset().union(*[v for v in vmas if v])
@@ -443,12 +459,13 @@ def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
 
     Pn, Vxn, Vyn, Vzn = pl.pallas_call(
         partial(_kernel, bx=bx, nb=nb, shapes=shapes[:4], scal=scal,
-                wrap_y=wy, wrap_z=wz),
+                wrap_y=wy, wrap_z=wz, into=into is not None),
         name="stokes3d_iteration",
         grid=(nb,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
         **kwargs,
     )(*operands)
@@ -471,6 +488,40 @@ def fused_stokes_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
 
     Vxn = lax.dynamic_update_slice_in_dim(Vxn, vx_out[None], S0, axis=0)
     return Pn, Vxn, Vyn, Vzn
+
+
+def stokes_iterations(state, Rho, n: int, **kw):
+    """`n` fused iterations of the state `(P, Vx, Vy, Vz)` as an HBM
+    ping-pong, so that no iteration's result is copied back into a loop
+    carry (a `while` result must sit in its parameter's buffer).  The
+    first iteration writes fresh buffers; the state it read is dead from
+    then on and becomes the partner set, and every later iteration writes
+    into the set the one before it read (`into=`).  Two state sets are
+    live, as in a plain loop.  `kw` goes to
+    :func:`fused_stokes_iteration`.
+
+    Each dispatch of the program being traced counts its `n - 1`
+    ping-pong iterations in `igg_pingpong_iterations_total`
+    (`igg.parallel.count_per_dispatch`)."""
+    from jax import lax
+
+    from ..parallel import count_per_dispatch
+
+    A = tuple(state)
+    if n <= 0:
+        return A
+    B, A = A, fused_stokes_iteration(*A, Rho, **kw)
+
+    def two(_, AB):
+        A, B = AB
+        B = fused_stokes_iteration(*A, Rho, **kw, into=B)
+        return fused_stokes_iteration(*B, Rho, **kw, into=A), B
+
+    A, B = lax.fori_loop(0, (n - 1) // 2, two, (A, B))
+    if (n - 1) % 2:
+        A = fused_stokes_iteration(*A, Rho, **kw, into=B)
+    count_per_dispatch("igg_pingpong_iterations_total", n - 1)
+    return A
 
 
 def _wrap_row(v, axis, size, ol):

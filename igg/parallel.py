@@ -247,12 +247,29 @@ def compiled_texts() -> list:
 
 class _Program:
     """A cached program: the jitted callable, the argument avals of its
-    first call and, once asked for, its compiled text."""
+    first call, once asked for its compiled text, and the counts each of
+    its dispatches performs (:func:`count_per_dispatch`)."""
 
-    __slots__ = ("jfn", "avals", "text")
+    __slots__ = ("jfn", "avals", "text", "per_dispatch")
 
     def __init__(self, jfn):
         self.jfn, self.avals, self.text = jfn, None, None
+        self.per_dispatch = {}
+
+
+# The per-dispatch counts of the program whose first call is tracing, if
+# any (innermost last).
+_tracing: list = []
+
+
+def count_per_dispatch(name: str, n: int) -> None:
+    """Add `n` to the count `name` that every dispatch of the
+    :func:`sharded` program now being traced performs: fixed once, at its
+    trace, and kept on the cached program, whose callers read it after
+    each call as `fn.per_dispatch` (the tier ladder adds it to the counter
+    `name`).  Outside such a trace it does nothing."""
+    if _tracing:
+        _tracing[-1][name] = _tracing[-1].get(name, 0) + n
 
 
 def _build(f, args, grid, *, donate_argnums, out_specs, check_vma):
@@ -382,13 +399,19 @@ def sharded(fn=None, *, donate_argnums: Sequence[int] = (),
                             out_specs=out_specs, check_vma=check_vma))
                         _cache_put(key, prog)
                         avals = jax.tree.map(_aval, args)
-                        out = prog.jfn(*args)
+                        _tracing.append(prog.per_dispatch)
+                        try:
+                            out = prog.jfn(*args)
+                        finally:
+                            _tracing.pop()
                         prog.avals = avals
                 else:
                     out = prog.jfn(*args)
+                wrapper.per_dispatch = prog.per_dispatch
             if grid.needs_cpu_sync:
                 jax.block_until_ready(out)
             return out
+        wrapper.per_dispatch = {}
         return wrapper
 
     return deco(fn) if fn is not None else deco

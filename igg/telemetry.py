@@ -382,6 +382,12 @@ _BUILTIN_HELP: Dict[str, str] = {
                                            "schedules traced.",
     "igg_tier_dispatch_total": "Dispatches served per (family, tier) by "
                                "the degradation ladder.",
+    "igg_pingpong_iterations_total": "Iterations of the ladder's "
+                                     "dispatches, per (family, tier), "
+                                     "whose outputs went into the dead "
+                                     "partner state set (an HBM "
+                                     "ping-pong) instead of fresh "
+                                     "buffers.",
     "igg_tier_quarantined_total": "Kernel tiers quarantined by the "
                                   "degradation ladder.",
     "igg_dispatch_host_seconds": "Host seconds of a ladder dispatch that "

@@ -100,6 +100,36 @@ def _kernels(compiled):
             if i.kernel}
 
 
+def _loop_field_copies(compiled, shapes):
+    """The whole-field copies (`copy`, `copy-start`) of an operand of one
+    of `shapes` inside the bodies of the program's `while` loops: each runs
+    once a trip, as when a loop result must be moved back into its
+    parameter's buffer."""
+    import re
+
+    from igg import profiling
+
+    comps, comp = {}, None
+    for line in compiled.as_text().splitlines():
+        m = profiling._INSTRUCTION.match(line)
+        if m and comp is not None:
+            comps[comp].append(profiling._split_instruction(m.group(2)))
+            continue
+        c = profiling._COMPUTATION.match(line)
+        if c:
+            comp = c.group(1)
+            comps[comp] = []
+    fields = {"f32[" + ",".join(map(str, s)) + "]" for s in shapes}
+    bodies = {b for rows in comps.values() for _, op, _, attrs in rows
+              if op == "while"
+              for b in re.findall(r"\bbody=%([\w.\-]+)", attrs)}
+    assert bodies, "the program has no while loop"
+    return [(b, op, shape) for b in sorted(bodies)
+            for shape, op, _, _ in comps[b]
+            if op in ("copy", "copy-start")
+            and any(f in shape for f in fields)]
+
+
 def mega_512_open(topo):
     """Phase A: the streamed-coefficient frozen-edge mega kernel, as the
     open-x wavefront (D steps per HBM sweep)."""
@@ -169,34 +199,30 @@ def stokes_trapezoid_4x1(topo):
     """The Stokes K-iteration chunk tier (warm-up iteration, chunks,
     per-iteration remainder) over the same four chips at 128^3: its
     VMEM budget admits the chunk only there and only on an (N,1,1)
-    ring (`fit_stokes_K` is 0 on (2,2,1) at every size)."""
-    from jax import lax
-
+    ring (`fit_stokes_K` is 0 on (2,2,1) at every size).  The local
+    program is the ladder's own; at 16 iterations K 8 leaves a remainder
+    of 7, whose loop must move no field (the HBM ping-pong)."""
     from igg.models import stokes3d
-    from igg.ops import fused_stokes_iteration
-    from igg.ops.stokes_trapezoid import (fit_stokes_K,
-                                          fused_stokes_trapezoid_iters)
+    from igg.ops.stokes_trapezoid import fit_stokes_K
 
-    n, n_inner = 128, 9
+    n, n_inner = 128, 16
     g = _grid(topo, (n,) * 3, (4, 1, 1), periodic=True, overlapx=3,
               overlapy=3, overlapz=3)
-    K = fit_stokes_K(igg.get_global_grid(), (n,) * 3, n_inner - 1,
-                     np.float32)
-    assert K, "the Stokes chunk tier does not engage"
-    kw = stokes3d._pseudo_steps(stokes3d.Params())
-
-    def local(P, Vx, Vy, Vz, Rho):
-        S = fused_stokes_iteration(P, Vx, Vy, Vz, Rho, **kw)
-        *S, done = fused_stokes_trapezoid_iters(*S, Rho, n_inner=n_inner - 1,
-                                                K=K, **kw)
-        return tuple(lax.fori_loop(
-            0, n_inner - 1 - done,
-            lambda _, T: fused_stokes_iteration(*T, Rho, **kw), tuple(S)))
-
+    assert fit_stokes_K(igg.get_global_grid(), (n,) * 3, n_inner - 1,
+                        np.float32) == 8, "the Stokes chunk tier's K moved"
+    tiers = {t.name: t for t in
+             stokes3d.make_iteration(n_inner=n_inner).ladder.tiers}
+    local = tiers["stokes3d.trapezoid"].build().__wrapped__
     dims = igg.get_global_grid().dims
     stag = [tuple(dims[d] * (n + (d == i)) for d in range(3))
             for i in range(3)]
-    return _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
+    compiled = _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
+    assert _kernels(compiled) == {"stokes3d_iteration",
+                                  "chunk_resident"}
+    local_shapes = [(n,) * 3] + [tuple(n + (d == i) for d in range(3))
+                                 for i in range(3)]
+    assert _loop_field_copies(compiled, local_shapes) == []
+    return compiled
 
 
 def stokes_mosaic_256_periodic(topo):
@@ -222,6 +248,7 @@ def stokes_mosaic_256_periodic(topo):
     stag = [tuple(n + (d == i) for d in range(3)) for i in range(3)]
     compiled = _compile(local, [g, *stag, g], (igg.spec_for(3),) * 4)
     assert _kernels(compiled) == {"stokes3d_iteration"}
+    assert _loop_field_copies(compiled, [g, *stag]) == []
     return compiled
 
 

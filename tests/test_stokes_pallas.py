@@ -204,3 +204,76 @@ def test_decomposition_invariance_open_boundaries():
         assert a.shape == b.shape, (name, a.shape, b.shape)
         scale = max(np.abs(b).max(), 1e-30)
         assert np.abs(a - b).max() <= 1e-5 * scale, name
+
+
+_PINGPONG_GRIDS = {
+    "selfwrap": dict(dimx=1, dimy=1, dimz=1, periodx=1, periody=1,
+                     periodz=1),
+    "openx211": dict(dimx=2, dimy=1, dimz=1, periody=1, periodz=1),
+}
+
+
+@pytest.mark.parametrize("grid, n, entry", [
+    *[(g, n, "stokes_iterations") for g in sorted(_PINGPONG_GRIDS)
+      for n in (0, 1, 2, 3, 4, 7)],
+    *[(g, 5, "make_iteration") for g in sorted(_PINGPONG_GRIDS)]])
+def test_pingpong_matches_sequential_bitwise(grid, n, entry):
+    """The HBM ping-pong (`stokes_iterations`: fresh buffers first, then
+    each iteration into the state set the one before it read) equals `n`
+    sequential `fused_stokes_iteration` dispatches bit for bit, on the
+    self-wrap grid and a (2,1,1) mesh with open x, and so does the
+    factory's program with donated inputs.  (Where y or z is open too, the
+    edge lines of those planes come from XLA-compiled windows that XLA:CPU
+    compiles 1 ulp apart in different programs: a plain `fori_loop` of the
+    kernel already differs from sequential dispatches there.)"""
+    import jax
+
+    from igg.ops import fused_stokes_iteration
+    from igg.ops.stokes_pallas import stokes_iterations
+
+    kw_grid = _PINGPONG_GRIDS[grid]
+    igg.init_global_grid(16, 8, 8, overlapx=3, overlapy=3, overlapz=3,
+                         quiet=True, devices=jax.devices()[:kw_grid["dimx"]],
+                         **kw_grid)
+    params = stokes3d.Params()
+    kw = dict(stokes3d._pseudo_steps(params), interpret=True)
+    *S, Rho = _fields()
+    one = igg.sharded(lambda P, Vx, Vy, Vz, Rho: fused_stokes_iteration(
+        P, Vx, Vy, Vz, Rho, **kw), check_vma=False)
+    want = tuple(S)
+    for _ in range(n):
+        want = one(*want, Rho)
+    if entry == "make_iteration":
+        it = stokes3d.make_iteration(params, n_inner=n, donate=True,
+                                     use_pallas=True, pallas_interpret=True,
+                                     trapezoid=False, banded=False)
+        got = it(*S, Rho)
+        assert it.ladder.tiers[0].name == "stokes3d.trapezoid"
+        assert igg.degrade.active()["stokes3d"] == "stokes3d.mosaic"
+    else:
+        got = igg.sharded(
+            lambda P, Vx, Vy, Vz, Rho: stokes_iterations(
+                (P, Vx, Vy, Vz), Rho, n, **kw), check_vma=False)(*S, Rho)
+    for name, a, b in zip(("P", "Vx", "Vy", "Vz"), got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+
+def test_pingpong_counter_per_dispatch(selfwrap_grid):
+    """`igg_pingpong_iterations_total` grows by `n_inner - 1` a dispatch
+    of the Mosaic rung, the iterations that wrote into the partner set,
+    beside `igg_tier_dispatch_total`'s one."""
+    from igg import telemetry
+
+    n_inner = 5
+    it = stokes3d.make_iteration(n_inner=n_inner, donate=False,
+                                 use_pallas=True, pallas_interpret=True,
+                                 trapezoid=False, banded=False)
+    labels = dict(family="stokes3d", tier="stokes3d.mosaic")
+    pingpong = telemetry.counter("igg_pingpong_iterations_total", **labels)
+    dispatches = telemetry.counter("igg_tier_dispatch_total", **labels)
+    p0, d0 = pingpong.value, dispatches.value
+    *S, Rho = _fields()
+    for k in (1, 2):
+        S = it(*S, Rho)
+        assert dispatches.value - d0 == k
+        assert pingpong.value - p0 == k * (n_inner - 1)
