@@ -345,35 +345,7 @@ def main():
     })
     igg.finalize_global_grid()
 
-    # The STREAMING banded rung (this round): diffusion's banded chunk
-    # realization vs the XLA composition, a CONTRACT row on EVERY
-    # platform (the rolling-window/ping-pong structure the compiled
-    # Mosaic kernel streams; interpret shares admission and schedule).
-    igg.init_global_grid(16, 16, 128, periodx=1, periody=1, periodz=1,
-                         quiet=True)
     n5 = 5   # warm-up + one K=4 chunk
-    dref = d3.make_multi_step(n5, params, donate=False, use_pallas=False,
-                              tune=False)
-    dband = d3.make_multi_step(n5, params, donate=False, banded=True,
-                               K=4, band=8, pallas_interpret=True,
-                               tune=False)
-    dT, dCp = d3.init_fields(params, dtype=np.float32)
-    dr = dref(dT, dCp)
-    db = dband(dT, dCp)
-    drel = float(abs(np.asarray(dr, np.float64)
-                     - np.asarray(db, np.float64)).max()
-                 / (abs(np.asarray(dr, np.float64)).max() + 1e-30))
-    _, sec = time_steps(lambda T, Cp: (dband(T, Cp), Cp), (dT, dCp),
-                        n1=2, n2=4)
-    emit({
-        "metric": "pallas_sweep_ms_per_step",
-        "config": "diffusion_banded_interpret_K4", "local": 16,
-        "value": round(sec / n5 * 1e3, 4), "unit": "ms",
-        "platform": platform, "rel_vs_composition": drel,
-        "pass": bool(drel < 1e-4),
-    })
-    igg.finalize_global_grid()
-
     from igg.models import wave2d as _w2
 
     igg.init_global_grid(16, 16, 1, periodx=1, periody=1, quiet=True)
@@ -432,27 +404,6 @@ def main():
         "value": round(sec / n5 * 1e3, 4), "unit": "ms",
         "platform": platform, "rel_vs_hand_composition": srel,
         "pass": bool(srel < 1e-4),
-    })
-
-    # The spec-lowered STREAMING banded rung (this round): same oracle
-    # (the hand-written module's composition), `banded=True` pinning the
-    # `wave2d.banded` tier through the generated ladder.
-    sbstep = _st.compile(_st.wave2d_spec(), coeffs=_st.wave2d_coeffs(wp),
-                         donate=False, n_inner=n5, use_pallas=True,
-                         pallas_interpret=True, banded=True, K=4, band=8)
-    sbo = sbstep(wP, wVx, wVy)
-    sbrel = max(
-        float(abs(np.asarray(a, np.float64) - np.asarray(b, np.float64))
-              .max() / (abs(np.asarray(a, np.float64)).max() + 1e-30))
-        for a, b in zip(wref, sbo))
-    _, sec = time_steps(lambda P, Vx, Vy: sbstep(P, Vx, Vy),
-                        (wP, wVx, wVy), n1=2, n2=4)
-    emit({
-        "metric": "pallas_sweep_ms_per_step",
-        "config": "stencil_wave2d_banded_interpret_K4", "local": 16,
-        "value": round(sec / n5 * 1e3, 4), "unit": "ms",
-        "platform": platform, "rel_vs_hand_composition": sbrel,
-        "pass": bool(sbrel < 1e-4),
     })
 
     sp = _sw.Params()

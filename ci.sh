@@ -54,14 +54,12 @@ fi
 # chunk tier gated against the HAND-WRITTEN module's composition (the
 # frontend's bit-exactness contract) and the shallow-water family —
 # zero hand-written kernel code — against its own generated XLA truth.
-# Round 18 adds the STREAMING banded rung (diffusion + the spec-lowered
-# ladder) and unpins the hm3d row to automatic dims (K=8 — the depth the
+# Round 18 unpins the hm3d row to automatic dims (K=8 — the depth the
 # (2,2,2) mesh's sublane-tile gate admits, now a structured Admission
 # refusal at K=4 instead of a Mosaic crash).
 for cfg in hm3d_trapezoid_open_interpret_K8 wave2d_mosaic_interpret \
         wave2d_chunk_interpret_K4 stencil_wave2d_chunk_interpret_K4 \
-        shallow_water_mosaic_interpret shallow_water_chunk_interpret_K4 \
-        diffusion_banded_interpret_K4 stencil_wave2d_banded_interpret_K4; do
+        shallow_water_mosaic_interpret shallow_water_chunk_interpret_K4; do
     if grep "\"config\": \"$cfg\"" \
             benchmarks/results_smoke/pallas_sweep.jsonl \
             | grep -q '"pass": true'; then
@@ -300,13 +298,13 @@ else
 fi
 rm -rf "$IGG_COMM_GATE_TMP"
 
-# Round 18: the banded-rung contract goldens must BITE too — flip every
-# pass flag in the committed pallas_sweep contract-only goldens and the
-# gate has to go red (the run_all --compare above proves the green path
-# for the new diffusion_banded/stencil_wave2d_banded rows; this proves
-# a silently-failing banded tier cannot slip through).
-echo "=== pallas_sweep golden-gate proof (flipped banded contract pass"
-echo "    flags must fail igg.perf compare) ==="
+# The chunk-engine and spec-generated contract goldens must BITE too —
+# flip every pass flag in the committed pallas_sweep contract-only
+# goldens and the gate has to go red (the run_all --compare above proves
+# the green path for the hm3d/wave2d/stencil/shallow-water rows; this
+# proves a silently-failing chunk or generated tier cannot slip through).
+echo "=== pallas_sweep golden-gate proof (flipped contract pass flags"
+echo "    must fail igg.perf compare) ==="
 IGG_SWEEP_GATE_TMP=$(mktemp -d)
 sed 's/"pass": true/"pass": false/' benchmarks/goldens/pallas_sweep.jsonl \
     > "$IGG_SWEEP_GATE_TMP/new.jsonl"
@@ -507,21 +505,6 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     IGG_TUNE_CACHE="$IGG_TUNE_TMP/tune.json" \
     IGG_PERF_LEDGER="$IGG_TUNE_TMP/ledger.json" \
     python examples/tuned_run.py warm
-
-# Round 18: the streaming banded rung is a FIRST-CLASS ledger tier —
-# the cold search above measured its candidates, so the per-tier view
-# of the ledger must list it (`python -m igg.perf show --tier` is the
-# filter the tuning work reads).
-echo "=== banded rung is a first-class perf-ledger tier (igg.perf show"
-echo "    --tier diffusion3d.banded lists the searched candidates) ==="
-if python -m igg.perf show "$IGG_TUNE_TMP/ledger.json" \
-        --tier diffusion3d.banded | grep -q "diffusion3d.banded"; then
-    echo "    diffusion3d.banded rung PRESENT in the ledger's tier view"
-else
-    echo "    diffusion3d.banded rung MISSING from igg.perf show --tier"
-    rm -rf "$IGG_TUNE_TMP"
-    exit 1
-fi
 rm -rf "$IGG_TUNE_TMP"
 
 # Round 16 (overlap serving): the weak-scaling artifact must carry the

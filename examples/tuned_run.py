@@ -5,8 +5,7 @@ dispatches — the `ci.sh` acceptance proof for `igg.autotune`.
 Phase "cold" (first process):
   1. The perf ledger starts empty (no prior) and the tuning cache is a
      miss for the diffusion signature.
-  2. `make_multi_step(..., tune=True)` runs the (tier, K, bx, band)
-     search — the streaming banded rung's candidates included — on
+  2. `make_multi_step(..., tune=True)` runs the (tier, K, bx) search on
      warm scratch-copy dispatches — the ledger gains autotune-sourced
      samples for every candidate, and the winner persists to
      `IGG_TUNE_CACHE` (format igg-tune-cache-v1, atomic merge-on-write).
@@ -75,14 +74,11 @@ if phase == "cold":
     assert w is not None, "the winner must be cached"
     # Round 16: the overlap axis is part of every persisted winner — the
     # warm process must be able to serve the full
-    # (tier, K, bx, vmem, overlap, band) configuration from the cache
-    # alone.  Round 18: ditto the band axis (the streaming banded rung's
-    # band depth; None whenever a non-banded tier won).
+    # (tier, K, bx, vmem, overlap) configuration from the cache alone.
     assert isinstance(w.get("overlap"), bool), w
-    assert "band" in w, w
     print(f"cold: searched with {n_search} timed dispatches -> winner "
           f"tier={w['tier']} K={w['K']} bx={w['bx']} "
-          f"band={w['band']} overlap={w['overlap']} ms={w['ms']:.4f}")
+          f"overlap={w['overlap']} ms={w['ms']:.4f}")
 
     # The winner beats-or-equals the hand-picked bx=8 config (searched
     # samples carry per-candidate labels on the bus).
@@ -130,13 +126,9 @@ else:
     from igg.overlap import resolve_overlap
     assert resolve_overlap("auto", family="diffusion3d",
                            tuned=w) == w["overlap"], w
-    # Round 18: the band axis round-trips too — a banded winner serves
-    # its cached band depth, a non-banded winner serves band=None; the
-    # cache entry always carries the key.
-    assert "band" in w, w
     print(f"warm: served {served} with cached config "
-          f"K={w['K']} bx={w['bx']} band={w['band']} "
-          f"overlap={w['overlap']} after 0 search dispatches")
+          f"K={w['K']} bx={w['bx']} overlap={w['overlap']} "
+          f"after 0 search dispatches")
 
     # The CLI renders the cache next to its ledger prior.
     out = subprocess.run(

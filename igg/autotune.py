@@ -246,8 +246,7 @@ def record_winner(family: str, winner: Dict, *, local_shape=None,
          "dims": list(k[3]) if k[3] else None, "backend": k[4],
          "device_kind": k[5],
          "tier": winner.get("tier"), "K": winner.get("K"),
-         "bx": winner.get("bx"), "band": winner.get("band"),
-         "vmem_mb": winner.get("vmem_mb"),
+         "bx": winner.get("bx"), "vmem_mb": winner.get("vmem_mb"),
          "overlap": bool(winner.get("overlap", False)),
          "ms": winner.get("ms"), "source": source,
          "updated_wall": time.time()}
@@ -455,18 +454,8 @@ def candidates_for(family: str, *, n_inner: int = 8,
                 if supported(grid, shape, K, n_inner - 1, dtype,
                              interpret=interpret)]
 
-    def banded_cands(supported, tier, ks=(4, 8), bands=(8, 16)):
-        # The streaming banded tier joins the search space on its own
-        # (K, band) axes — admission-gated host-side so a refused config
-        # never costs a search dispatch.
-        return [{"tier": tier, "K": K, "bx": None, "band": B,
-                 "vmem_mb": None}
-                for K in ks for B in bands
-                if supported(grid, shape, K, n_inner - 1, dtype, B=B,
-                             interpret=interpret)]
-
     if family == "diffusion3d":
-        from .ops import diffusion_banded_supported, pallas_supported
+        from .ops import pallas_supported
 
         if pallas_supported(grid, type("S", (), {
                 "ndim": 3, "shape": shape, "dtype": dtype})()):
@@ -474,11 +463,8 @@ def candidates_for(family: str, *, n_inner: int = 8,
                 if shape[0] % bx == 0:
                     out.append({"tier": "diffusion3d.mosaic", "K": bx,
                                 "bx": bx, "vmem_mb": None})
-        out.extend(banded_cands(diffusion_banded_supported,
-                                "diffusion3d.banded"))
     elif family == "stokes3d":
-        from .ops import (stokes_banded_supported,
-                          stokes_trapezoid_supported)
+        from .ops import stokes_trapezoid_supported
 
         for v in vmems:
             out.append({"tier": "stokes3d.mosaic", "K": None, "bx": None,
@@ -486,11 +472,8 @@ def candidates_for(family: str, *, n_inner: int = 8,
         for K in chunk_ks(stokes_trapezoid_supported):
             out.append({"tier": "stokes3d.trapezoid", "K": K, "bx": None,
                         "vmem_mb": None})
-        out.extend(banded_cands(stokes_banded_supported,
-                                "stokes3d.banded"))
     elif family == "hm3d":
-        from .ops.hm3d_trapezoid import (hm3d_banded_supported,
-                                         hm3d_trapezoid_supported)
+        from .ops.hm3d_trapezoid import hm3d_trapezoid_supported
 
         for v in vmems:
             out.append({"tier": "hm3d.mosaic", "K": None, "bx": None,
@@ -498,17 +481,14 @@ def candidates_for(family: str, *, n_inner: int = 8,
         for K in chunk_ks(hm3d_trapezoid_supported):
             out.append({"tier": "hm3d.trapezoid", "K": K, "bx": None,
                         "vmem_mb": None})
-        out.extend(banded_cands(hm3d_banded_supported, "hm3d.banded"))
     elif family == "wave2d":
-        from .ops.wave2d_pallas import (wave2d_banded_supported,
-                                        wave2d_chunk_supported)
+        from .ops.wave2d_pallas import wave2d_chunk_supported
 
         out.append({"tier": "wave2d.mosaic", "K": None, "bx": None,
                     "vmem_mb": None})
         for K in chunk_ks(wave2d_chunk_supported):
             out.append({"tier": "wave2d.chunk", "K": K, "bx": None,
                         "vmem_mb": None})
-        out.extend(banded_cands(wave2d_banded_supported, "wave2d.banded"))
     else:
         raise GridError(
             f"igg.autotune: unknown family {family!r} (built-ins: "
@@ -541,7 +521,6 @@ def _build_candidate(family: str, cand: Dict, n_inner: int, params,
     tier = cand["tier"]
     fast = not tier.endswith(".xla")
     ov = bool(cand.get("overlap"))
-    bdd = tier.endswith(".banded")
     if family == "diffusion3d":
         from .models import diffusion3d as m
 
@@ -550,10 +529,7 @@ def _build_candidate(family: str, cand: Dict, n_inner: int, params,
         step = m.make_multi_step(
             n_inner, p, donate=False, overlap=ov,
             use_pallas=(True if fast else False),
-            pallas_interpret=interpret, bx=cand.get("bx"),
-            banded=(True if bdd else False),
-            K=cand.get("K") if bdd else None, band=cand.get("band"),
-            tune=False)
+            pallas_interpret=interpret, bx=cand.get("bx"), tune=False)
         return (lambda T, Cp: (step(T, Cp), Cp)), (T, Cp)
     if family == "stokes3d":
         from .models import stokes3d as m
@@ -564,7 +540,6 @@ def _build_candidate(family: str, cand: Dict, n_inner: int, params,
             p, donate=False, n_inner=n_inner, overlap=ov,
             use_pallas=(True if fast else False), pallas_interpret=interpret,
             trapezoid=(tier.endswith(".trapezoid")), K=cand.get("K"),
-            banded=(True if bdd else False), band=cand.get("band"),
             tune=False)
         return (lambda P, Vx, Vy, Vz, Rho:
                 it(P, Vx, Vy, Vz, Rho) + (Rho,)), tuple(fields)
@@ -577,7 +552,6 @@ def _build_candidate(family: str, cand: Dict, n_inner: int, params,
             p, donate=False, n_inner=n_inner, overlap=ov,
             use_pallas=(True if fast else False), pallas_interpret=interpret,
             trapezoid=(tier.endswith(".trapezoid")), K=cand.get("K"),
-            banded=(True if bdd else False), band=cand.get("band"),
             tune=False)
         return (lambda Pe, phi: step(Pe, phi)), tuple(fields)
     if family == "wave2d":
@@ -589,7 +563,6 @@ def _build_candidate(family: str, cand: Dict, n_inner: int, params,
             p, donate=False, n_inner=n_inner, overlap=ov,
             use_pallas=(True if fast else False), pallas_interpret=interpret,
             chunk=(tier == "wave2d.chunk"), K=cand.get("K"),
-            banded=(True if bdd else False), band=cand.get("band"),
             tune=False)
         return (lambda P, Vx, Vy: step(P, Vx, Vy)), tuple(fields)
     raise GridError(f"igg.autotune: unknown family {family!r}.")
@@ -603,8 +576,6 @@ def _cand_label(cand: Dict) -> str:
         bits.append(f"K={cand['K']}")
     if cand.get("bx"):
         bits.append(f"bx={cand['bx']}")
-    if cand.get("band"):
-        bits.append(f"band={cand['band']}")
     if cand.get("vmem_mb"):
         bits.append(f"vmem={cand['vmem_mb']}MB")
     return "[" + ",".join(bits) + "]"

@@ -252,57 +252,43 @@ def auto_dispatch(*, use_pallas, interpret, supported_fn, requirement,
 
 
 def apply_tuned(family, tune, *, n_inner, interpret, K, chunk_knob,
-                use_pallas, band=None, banded_knob="auto"):
+                use_pallas, tiers):
     """The chunk-tier families' shared tuned-config application (one
     implementation of the precedence rules — hm3d/wave2d/stokes3d used
     to carry private copies):
 
+    - a winner whose tier is not one of the family's ladder `tiers`
+      (suffixes; a cache written before a tier was removed may still
+      name it) is no winner: the factory serves its default ladder;
     - a cached winner's `K` fills an unset caller `K` — and is marked
       cache-sourced, so the family's `_fit_K` can FALL BACK to auto-fit
       when that K is inapplicable at this factory's `n_inner` (the cache
       key has no n_inner axis; only a CALLER-pinned K hard-refuses);
-      a cached winner's `band` fills an unset caller `band` the same way
-      (absent in pre-band cache entries — `.get`, never a KeyError);
-    - a `<family>.mosaic` winner turns `chunk_knob` "auto" off, and a
-      `<family>.banded` winner turns it off too (the streaming tier
-      outranked the resident one on this machine);
-    - a `<family>.mosaic` winner turns `banded_knob` "auto" off; a
-      `<family>.banded` winner marks it "cached" — the family's banded
-      admission then serves the tier WITHOUT requiring the resident
-      tiers to refuse first (and without hard-raising if the cached
-      config no longer fits this shape, unlike an explicit True);
+    - a `<family>.mosaic` winner turns `chunk_knob` "auto" off;
     - a `<family>.xla` winner pins `use_pallas` off ONLY when the caller
-      left ALL the knobs on auto — an explicit chunk/trapezoid/banded
-      =True always outranks a cached winner.
+      left ALL the knobs on auto — an explicit chunk/trapezoid=True
+      always outranks a cached winner.
 
-    Returns `(K, K_from_cache, band, band_from_cache, chunk_knob,
-    banded_knob, use_pallas, tuned)` — `tuned` is the raw winner entry
-    (or None), so the factory can resolve its remaining auto knobs (the
-    overlap axis, `igg.overlap.resolve_overlap`) from the same lookup."""
+    Returns `(K, K_from_cache, chunk_knob, use_pallas, tuned)` — `tuned`
+    is the raw winner entry (or None), so the factory can resolve its
+    remaining auto knobs (the overlap axis,
+    `igg.overlap.resolve_overlap`) from the same lookup."""
     from igg import autotune
 
     tuned = autotune.applied(family, tune, n_inner=n_inner,
                              interpret=interpret)
+    if tuned and tuned.get("tier") not in {f"{family}.{t}" for t in tiers}:
+        tuned = None
     K_from_cache = False
     if K is None and tuned and tuned.get("K"):
         K, K_from_cache = int(tuned["K"]), True
-    band_from_cache = False
-    if band is None and tuned and tuned.get("band"):
-        band, band_from_cache = int(tuned["band"]), True
     winner = tuned.get("tier") if tuned else None
-    if chunk_knob == "auto" and winner in (f"{family}.mosaic",
-                                           f"{family}.banded"):
+    if chunk_knob == "auto" and winner == f"{family}.mosaic":
         chunk_knob = False
-    if banded_knob == "auto":
-        if winner == f"{family}.banded":
-            banded_knob = "cached"
-        elif winner == f"{family}.mosaic":
-            banded_knob = False
     if use_pallas == "auto" and chunk_knob == "auto" and \
-            banded_knob == "auto" and winner == f"{family}.xla":
+            winner == f"{family}.xla":
         use_pallas = False
-    return (K, K_from_cache, band, band_from_cache, chunk_knob,
-            banded_knob, use_pallas, tuned)
+    return K, K_from_cache, chunk_knob, use_pallas, tuned
 
 
 def resolve_chunk_K(K, K_from_cache, supported, fit):
@@ -318,25 +304,3 @@ def resolve_chunk_K(K, K_from_cache, supported, fit):
             return 0
     return fit()
 
-
-def resolve_band(K, band, from_cache, supported, fit, bands=(8, 16)):
-    """The banded-tier `(K, B)` resolution shared by the chunk families
-    (the `resolve_chunk_K` rules lifted to the two-axis search space):
-    an explicit/cached `(K, band)` pair serves iff admissible; caller
-    pins hard-refuse (return None) on mismatch while cache-sourced
-    values fall back to the auto-fit (`_vmem.fit_banded` through the
-    family's `fit_*_band`).  `supported(K, B)` is the family admission
-    gate; `fit(bands)` runs the family fit over a band tuple.  Returns
-    `(K, B)` or None."""
-    cand = (int(band),) if band is not None else tuple(bands)
-    if K is not None:
-        for b in cand:
-            if supported(int(K), b):
-                return (int(K), b)
-        if not from_cache:
-            return None
-        return fit(tuple(bands))
-    got = fit(cand)
-    if got is None and band is not None and from_cache:
-        got = fit(tuple(bands))
-    return got

@@ -172,18 +172,6 @@ def _best_bx(S0: int) -> int:
     return 1
 
 
-_BANDED_REQ = (
-    "the streaming banded diffusion chunk tier requires the fused "
-    "per-step kernel's prerequisites (TPU devices or "
-    "pallas_interpret=True, overlap-2 grid, f32 field) plus: "
-    "n_inner >= K+1, banded geometry (band B >= 8, B % 8 == 0, extended "
-    "x span divisible into >= 2 bands), K-deep send slabs inside every "
-    "split dimension's block, and a rolling band window set within the "
-    "VMEM budget (igg.ops.diffusion_trapezoid."
-    "diffusion_banded_supported); use banded='auto' or the resident "
-    "paths otherwise.")
-
-
 def make_step(params: Params = Params(), *, donate: bool = True,
               use_pallas="auto", overlap="auto",
               pallas_interpret: bool = False, verify=None, tune=None):
@@ -212,8 +200,7 @@ def make_step(params: Params = Params(), *, donate: bool = True,
 def make_multi_step(n_inner: int, params: Params = Params(), *,
                     donate: bool = True, use_pallas="auto",
                     overlap="auto", pallas_interpret: bool = False,
-                    bx: int = None, banded="auto", K: int = None,
-                    band: int = None, verify=None, tune=None):
+                    bx: int = None, verify=None, tune=None):
     """Compiled `(T, Cp) -> T` advancing `n_inner` steps in ONE XLA program
     (`lax.fori_loop` around the step, halo ppermutes included).  This is the
     TPU-idiomatic time loop: host dispatch overhead amortizes to zero, and
@@ -228,31 +215,21 @@ def make_multi_step(n_inner: int, params: Params = Params(), *,
     ("auto"/True/False, default the `IGG_TUNE` knob; `igg.autotune`):
     a hit supplies the slab/chunk depth `bx` and may pin the tier when
     the caller left the defaults — K is then searched, not fixed, and
-    the winner's persisted overlap axis resolves `overlap="auto"`.
-
-    `banded` admits the STREAMING banded chunk tier
-    (`igg.ops.diffusion_trapezoid.fused_diffusion_banded_steps` —
-    rolling VMEM window of band depth B, HBM ping-pong): "auto"
-    (default) engages it only where the resident fused realizations
-    (the mega kernel and the resident trapezoid chunk) both refuse —
-    the VMEM K-bound at headline shapes; True requires it; False pins
-    the resident paths.  `K`/`band` override the auto-fitted chunk
-    depth and band depth (`fit_diffusion_band`)."""
+    the winner's persisted overlap axis resolves `overlap="auto"`."""
     from jax import lax
 
     from igg.overlap import resolve_overlap
 
     from ._dispatch import apply_tuned
 
-    (K, K_from_cache, band, band_from_cache, _, banded,
-     use_pallas, tuned) = apply_tuned(
+    _, _, _, use_pallas, tuned = apply_tuned(
         "diffusion3d", tune, n_inner=n_inner, interpret=pallas_interpret,
-        K=K, chunk_knob="auto", use_pallas=use_pallas, band=band,
-        banded_knob=banded)
+        K=None, chunk_knob="auto", use_pallas=use_pallas,
+        tiers=("mosaic", "xla"))
     if bx is None and tuned and tuned.get("bx"):
         bx = int(tuned["bx"])
     overlap = resolve_overlap(overlap, family="diffusion3d", tuned=tuned,
-                              radius=1, chunk_active=banded is True)
+                              radius=1)
 
     dx, dy, dz = params.spacing()
     dt = params.timestep()
@@ -303,131 +280,16 @@ def make_multi_step(n_inner: int, params: Params = Params(), *,
 
         return pallas_steps
 
-    if banded is True and use_pallas is False:
-        raise igg.GridError(_BANDED_REQ)
-    if banded is True:
-        use_pallas = True    # the streaming tier rides the fused kernel
-
-    def _fit_band(grid, lshape, dtype):
-        """The `(K, B)` config the streaming banded tier will run (None
-        when none applies) — shared by the tier's admission gate and its
-        traced body so the two can never disagree."""
-        from igg.ops.diffusion_trapezoid import (
-            diffusion_banded_supported, fit_diffusion_band)
-
-        from ._dispatch import resolve_band
-
-        if banded is False or n_inner < 3:
-            return None
-        return resolve_band(
-            K, band, K_from_cache or band_from_cache,
-            lambda k, b: diffusion_banded_supported(
-                grid, tuple(lshape), k, n_inner - 1, dtype, B=b,
-                interpret=pallas_interpret),
-            lambda bands: fit_diffusion_band(
-                grid, tuple(lshape), n_inner - 1, dtype,
-                interpret=pallas_interpret, bands=bands))
-
-    def admit_banded(args):
-        from igg.degrade import Admission
-        from igg.ops import pallas_supported
-
-        from ._dispatch import pallas_applicable
-
-        if use_pallas is False:
-            return Admission.no("use_pallas=False pins the XLA path")
-        if banded is False:
-            return Admission.no("banded=False pins the resident paths")
-        base = pallas_applicable("auto", args[0],
-                                 supported_fn=pallas_supported,
-                                 requirement=_PALLAS_REQ,
-                                 interpret=pallas_interpret)
-        if not base:
-            return Admission.no(f"fused per-step kernel (the banded "
-                                f"tier's carrier) inadmissible: "
-                                f"{getattr(base, 'reason', '')}")
-        if n_inner < 3:
-            return Admission.no(f"n_inner={n_inner} < 3: no warm-up plus "
-                                f"full chunk fits")
-        grid = igg.get_global_grid()
-        T = args[0]
-        lshape = grid.local_shape_any(T)
-        bx_ = bx or _best_bx(grid.nxyz[0])
-        if banded == "auto":
-            from igg.ops.diffusion_mega import mega_supported
-            from igg.ops.diffusion_pallas import _single_device_modes
-            from igg.ops.diffusion_trapezoid import trapezoid_supported
-
-            if _single_device_modes(grid) is not None and mega_supported(
-                    tuple(lshape), bx_, n_inner, pallas_interpret,
-                    dtype=T.dtype):
-                return Admission.no(
-                    "the resident mega kernel serves this shape (the "
-                    "banded rung engages where the resident fused "
-                    "realizations refuse)")
-            if trapezoid_supported(grid, tuple(lshape), bx_, n_inner - 1,
-                                   T.dtype, allow_open=True):
-                return Admission.no(
-                    "the resident trapezoid chunk serves this shape "
-                    "(the banded rung engages where the resident fused "
-                    "realizations refuse)")
-        if not _fit_band(grid, lshape, T.dtype):
-            return Admission.no(
-                "no banded config (K, B) admissible "
-                "(igg.ops.diffusion_trapezoid.diffusion_banded_supported)")
-        return Admission.yes()
-
-    def build_banded():
-        from igg.ops import fused_diffusion_step
-        from igg.ops.diffusion_trapezoid import fused_diffusion_banded_steps
-
-        def banded_steps(T, Cp):
-            grid = igg.get_global_grid()
-            kb = _fit_band(grid, T.shape, T.dtype)
-            if not kb:    # admission gate and trace share _fit_band
-                raise igg.GridError(_BANDED_REQ)
-            Kf, Bf = kb
-            bx_ = bx or _best_bx(grid.nxyz[0])
-            A = dt_lam / Cp    # loop-invariant coefficient
-            # Warm-up per-step kernel: the exchange-fresh entry state the
-            # chunk validity argument requires (the trapezoid contract).
-            T = fused_diffusion_step(T, Cp, dx=dx, dy=dy, dz=dz, dt=dt,
-                                     lam=lam, bx=bx_,
-                                     interpret=pallas_interpret)
-            T, done = fused_diffusion_banded_steps(
-                T, A, n_inner=n_inner - 1, K=Kf, B=Bf, grid=grid,
-                rdx2=rdx2, rdy2=rdy2, rdz2=rdz2,
-                interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:    # remainder through the per-step kernel
-                T = lax.fori_loop(
-                    0, n,
-                    lambda _, T: fused_diffusion_step(
-                        T, Cp, dx=dx, dy=dy, dz=dz, dt=dt, lam=lam,
-                        bx=bx_, interpret=pallas_interpret),
-                    T)
-            return T
-
-        return igg.sharded(banded_steps,
-                           donate_argnums=(0,) if donate else (),
-                           check_vma=not pallas_interpret)
-
-    from igg.degrade import Tier
     from igg.ops import pallas_supported
 
     from ._dispatch import auto_dispatch
 
-    banded_tier = Tier(name="diffusion3d.banded", rung=0,
-                       build=build_banded, admit=admit_banded,
-                       required=banded is True,
-                       requirement=_BANDED_REQ)
     return auto_dispatch(
         use_pallas=use_pallas, interpret=pallas_interpret,
         supported_fn=pallas_supported, requirement=_PALLAS_REQ,
         xla_path=xla_path, build_pallas_steps=build_pallas_steps,
         donate_argnums=(0,) if donate else (),
-        family="diffusion3d", verify=verify,
-        extra_tiers=(banded_tier,))
+        family="diffusion3d", verify=verify)
 
 
 # Numeric-integrity declaration (igg.integrity, round 19): under fully

@@ -155,22 +155,10 @@ _TRAPEZOID_REQ = (
     "; use trapezoid='auto' or the per-step kernel otherwise.")
 
 
-_BANDED_REQ = (
-    "the streaming banded HM3D chunk tier requires the fused per-step "
-    "kernel's prerequisites (TPU devices or pallas_interpret=True, "
-    "overlap-2 grid, f32 fields) plus: n_inner >= K+1, banded geometry "
-    "(band B >= 8, B % 8 == 0, extended x span divisible into >= 2 "
-    "bands), K-deep send slabs inside every split dimension's block, and "
-    "a rolling band window set within the VMEM budget "
-    "(igg.ops.hm3d_trapezoid.hm3d_banded_supported); use banded='auto' "
-    "or the resident tiers otherwise.")
-
-
 def make_step(params: Params = Params(), *, donate: bool = True,
               overlap="auto", n_inner: int = 1,
               use_pallas="auto", pallas_interpret: bool = False,
-              trapezoid="auto", K: int = None, banded="auto",
-              band: int = None, verify=None, tune=None):
+              trapezoid="auto", K: int = None, verify=None, tune=None):
     """Compiled `(Pe, phi) -> (Pe, phi)` advancing `n_inner` steps in one
     SPMD program.  `use_pallas`: "auto" (default) uses the fused kernel
     (`igg.ops.fused_hm3d_steps`, with boundary-slab carry) when it applies —
@@ -195,15 +183,7 @@ def make_step(params: Params = Params(), *, donate: bool = True,
     the chunk tier and raises `GridError` when inapplicable.  `K`
     overrides the auto-fitted chunk depth (`fit_hm3d_K`).  `tune`
     consults the autotuner's cached winner for this signature
-    ("auto"/True/False; `igg.autotune`).
-
-    `banded` admits the STREAMING banded chunk tier
-    (`igg.ops.hm3d_trapezoid.fused_hm3d_banded_steps` — rolling VMEM
-    window, HBM ping-pong; the ladder rung below the resident
-    trapezoid): "auto" (default) engages it only where the resident
-    tier's `fit_hm3d_K` refuses (the VMEM K-bound at headline shapes),
-    True requires it, False pins the resident tiers.  `band` overrides
-    the auto-fitted band depth B (`fit_hm3d_band`)."""
+    ("auto"/True/False; `igg.autotune`)."""
     from jax import lax
 
     dx, dy, dz = params.spacing()
@@ -216,15 +196,12 @@ def make_step(params: Params = Params(), *, donate: bool = True,
 
     from ._dispatch import apply_tuned
 
-    (K, K_from_cache, band, band_from_cache, trapezoid, banded,
-     use_pallas, tuned) = apply_tuned(
+    K, K_from_cache, trapezoid, use_pallas, tuned = apply_tuned(
         "hm3d", tune, n_inner=n_inner, interpret=pallas_interpret, K=K,
-        chunk_knob=trapezoid, use_pallas=use_pallas, band=band,
-        banded_knob=banded)
+        chunk_knob=trapezoid, use_pallas=use_pallas,
+        tiers=("trapezoid", "mosaic", "xla"))
     overlap = resolve_overlap(overlap, family="hm3d", tuned=tuned,
-                              radius=1,
-                              chunk_active=(trapezoid is True
-                                            or banded is True))
+                              radius=1, chunk_active=trapezoid is True)
 
     def build_xla(assembly):
         def xla_steps(Pe, phi):
@@ -256,10 +233,8 @@ def make_step(params: Params = Params(), *, donate: bool = True,
 
     if trapezoid is True and use_pallas is False:
         raise igg.GridError(_TRAPEZOID_REQ)
-    if banded is True and use_pallas is False:
-        raise igg.GridError(_BANDED_REQ)
-    if trapezoid is True or banded is True:
-        use_pallas = True    # the chunk tiers ride the fused kernel
+    if trapezoid is True:
+        use_pallas = True    # the chunk tier rides the fused kernel
 
     donate_argnums = (0, 1) if donate else ()
 
@@ -282,26 +257,6 @@ def make_step(params: Params = Params(), *, donate: bool = True,
             lambda: fit_hm3d_K(grid, tuple(lshape), n_inner - 1, dtype,
                                interpret=pallas_interpret))
 
-    def _fit_band(grid, lshape, dtype):
-        """The `(K, B)` config the streaming banded tier will run (None
-        when none applies) — shared by the tier's admission gate and its
-        traced body so the two can never disagree."""
-        from igg.ops.hm3d_trapezoid import (fit_hm3d_band,
-                                            hm3d_banded_supported)
-
-        from ._dispatch import resolve_band
-
-        if banded is False or n_inner < 3:
-            return None
-        return resolve_band(
-            K, band, K_from_cache or band_from_cache,
-            lambda k, b: hm3d_banded_supported(
-                grid, tuple(lshape), k, n_inner - 1, dtype, B=b,
-                interpret=pallas_interpret),
-            lambda bands: fit_hm3d_band(grid, tuple(lshape), n_inner - 1,
-                                        dtype, interpret=pallas_interpret,
-                                        bands=bands))
-
     def admit_trapezoid(args):
         from igg.degrade import Admission
         from igg.ops import hm3d_pallas_supported
@@ -313,9 +268,6 @@ def make_step(params: Params = Params(), *, donate: bool = True,
         if trapezoid is False:
             return Admission.no("trapezoid=False pins the per-step "
                                 "kernel")
-        if banded is True:
-            return Admission.no("banded=True pins the streaming banded "
-                                "tier")
         base = pallas_applicable("auto", args[0],
                                  supported_fn=hm3d_pallas_supported,
                                  requirement=_PALLAS_REQ,
@@ -367,76 +319,6 @@ def make_step(params: Params = Params(), *, donate: bool = True,
         return igg.sharded(trap_steps, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
 
-    def admit_banded(args):
-        from igg.degrade import Admission
-        from igg.ops import hm3d_pallas_supported
-
-        from ._dispatch import pallas_applicable
-
-        if use_pallas is False:
-            return Admission.no("use_pallas=False pins the XLA path")
-        if banded is False:
-            return Admission.no("banded=False pins the resident tiers")
-        base = pallas_applicable("auto", args[0],
-                                 supported_fn=hm3d_pallas_supported,
-                                 requirement=_PALLAS_REQ,
-                                 interpret=pallas_interpret)
-        if not base:
-            return Admission.no(f"fused per-step kernel (the banded "
-                                f"tier's carrier) inadmissible: "
-                                f"{getattr(base, 'reason', '')}")
-        if n_inner < 3:
-            return Admission.no(f"n_inner={n_inner} < 3: no warm-up plus "
-                                f"full chunk fits")
-        grid = igg.get_global_grid()
-        Pe = args[0]
-        lshape = grid.local_shape_any(Pe)
-        if banded == "auto":
-            if trapezoid is False:
-                return Admission.no("trapezoid=False pins the per-step "
-                                    "kernel (pass banded=True to require "
-                                    "the streaming tier)")
-            if _fit_K(grid, lshape, Pe.dtype):
-                return Admission.no(
-                    "the resident chunk tier serves this shape (the "
-                    "banded rung engages where fit_hm3d_K refuses)")
-        if not _fit_band(grid, lshape, Pe.dtype):
-            return Admission.no(
-                "no banded config (K, B) admissible "
-                "(igg.ops.hm3d_trapezoid.hm3d_banded_supported)")
-        return Admission.yes()
-
-    def build_banded():
-        from igg.ops import fused_hm3d_step
-        from igg.ops.hm3d_trapezoid import fused_hm3d_banded_steps
-
-        def banded_steps(Pe, phi):
-            kw_it = dict(dx=dx, dy=dy, dz=dz, dt=dt, phi0=phi0,
-                         npow=npow, eta=eta)
-            grid = igg.get_global_grid()
-            kb = _fit_band(grid, Pe.shape, Pe.dtype)
-            if not kb:    # admission gate and trace share _fit_band
-                raise igg.GridError(_BANDED_REQ)
-            Kf, Bf = kb
-            # Warm-up per-step kernel: the exchange-fresh entry state the
-            # chunk validity argument requires (the trapezoid contract).
-            Pe, phi = fused_hm3d_step(Pe, phi, **kw_it,
-                                      interpret=pallas_interpret)
-            Pe, phi, done = fused_hm3d_banded_steps(
-                Pe, phi, n_inner=n_inner - 1, K=Kf, B=Bf, **kw_it,
-                interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:    # remainder through the per-step kernel
-                Pe, phi = lax.fori_loop(
-                    0, n,
-                    lambda _, S: fused_hm3d_step(
-                        *S, **kw_it, interpret=pallas_interpret),
-                    (Pe, phi))
-            return Pe, phi
-
-        return igg.sharded(banded_steps, donate_argnums=donate_argnums,
-                           check_vma=not pallas_interpret)
-
     from igg.degrade import Tier
     from igg.ops import hm3d_pallas_supported
 
@@ -446,17 +328,13 @@ def make_step(params: Params = Params(), *, donate: bool = True,
                      build=build_trapezoid, admit=admit_trapezoid,
                      required=trapezoid is True,
                      requirement=_TRAPEZOID_REQ)
-    banded_tier = Tier(name="hm3d.banded", rung=0,
-                       build=build_banded, admit=admit_banded,
-                       required=banded is True,
-                       requirement=_BANDED_REQ)
     return auto_dispatch(
         use_pallas=use_pallas, interpret=pallas_interpret,
         supported_fn=hm3d_pallas_supported, requirement=_PALLAS_REQ,
         xla_path=xla_path, build_pallas_steps=build_pallas_steps,
         donate_argnums=donate_argnums,
         family="hm3d", verify=verify,
-        extra_tiers=(trap_tier, banded_tier))
+        extra_tiers=(trap_tier,))
 
 
 def run(nt: int, params: Params = Params(), dtype=np.float32,

@@ -190,17 +190,6 @@ _TRAPEZOID_REQ = (
     "(igg.ops.stokes_trapezoid.stokes_trapezoid_supported); use "
     "trapezoid='auto' or the per-iteration kernel otherwise.")
 
-_BANDED_REQ = (
-    "the streaming banded Stokes chunk tier requires the fused "
-    "per-iteration kernel's prerequisites (TPU devices or "
-    "pallas_interpret=True, overlap-3 grid, f32 fields) plus: "
-    "n_inner >= K+1, banded geometry (band B >= 8, B % 8 == 0, extended "
-    "x span divisible into >= 2 bands), 2K-deep send slabs inside every "
-    "split dimension's block, and a rolling band window set within the "
-    "VMEM budget (igg.ops.stokes_trapezoid.stokes_banded_supported); "
-    "use banded='auto' or the resident tiers otherwise.")
-
-
 def _pallas_applicable(use_pallas, P, interpret: bool = False) -> bool:
     from igg.ops import stokes_pallas_supported
 
@@ -224,8 +213,8 @@ def _pseudo_steps(params: Params):
 def make_iteration(params: Params = Params(), *, donate: bool = True,
                    overlap="auto", n_inner: int = 1,
                    use_pallas="auto", pallas_interpret: bool = False,
-                   trapezoid="auto", K: int = None, banded="auto",
-                   band: int = None, verify=None, tune=None):
+                   trapezoid="auto", K: int = None, verify=None,
+                   tune=None):
     """Compiled `(P, Vx, Vy, Vz, Rho) -> (P, Vx, Vy, Vz)` advancing
     `n_inner` iterations in one SPMD program.  `use_pallas`: "auto"
     (default) uses the fused kernel when it applies — TPU devices,
@@ -255,34 +244,19 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
     truth before it serves traffic.  `tune` consults the autotuner's
     cached winner for this signature ("auto"/True/False, default the
     `IGG_TUNE` knob; `igg.autotune`): a hit supplies the chunk depth `K`
-    (and band depth `band`) and may pin the tier when the caller left
-    the defaults.
-
-    `banded` admits the STREAMING banded chunk tier
-    (`igg.ops.stokes_trapezoid.fused_stokes_banded_iters` — rolling VMEM
-    window, HBM ping-pong; the ladder rung below the resident
-    trapezoid): "auto" (default) engages it only where the resident
-    tier's `fit_stokes_K` refuses (the VMEM K-bound at headline
-    shapes) and the per-iteration kernel cannot keep its full slab
-    height within the VMEM cap (`stokes_pallas.full_slab_fits`; at 256^3
-    the per-iteration kernel serves), True requires it, False pins the
-    resident tiers.  `band`
-    overrides the auto-fitted band depth B (`fit_stokes_band`)."""
+    and may pin the tier when the caller left the defaults."""
     from jax import lax
 
     from igg.overlap import resolve_overlap
 
     from ._dispatch import apply_tuned
 
-    (K, K_from_cache, band, band_from_cache, trapezoid, banded,
-     use_pallas, tuned) = apply_tuned(
+    K, K_from_cache, trapezoid, use_pallas, tuned = apply_tuned(
         "stokes3d", tune, n_inner=n_inner, interpret=pallas_interpret,
-        K=K, chunk_knob=trapezoid, use_pallas=use_pallas, band=band,
-        banded_knob=banded)
+        K=K, chunk_knob=trapezoid, use_pallas=use_pallas,
+        tiers=("trapezoid", "mosaic", "xla"))
     overlap = resolve_overlap(overlap, family="stokes3d", tuned=tuned,
-                              radius=2,
-                              chunk_active=(trapezoid is True
-                                            or banded is True))
+                              radius=2, chunk_active=trapezoid is True)
 
     kw = _pseudo_steps(params)
     dx, dy, dz = kw["dx"], kw["dy"], kw["dz"]
@@ -312,10 +286,8 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
 
     if trapezoid is True and use_pallas is False:
         raise igg.GridError(_TRAPEZOID_REQ)
-    if banded is True and use_pallas is False:
-        raise igg.GridError(_BANDED_REQ)
-    if trapezoid is True or banded is True:
-        use_pallas = True    # the chunk tiers ride the fused kernel
+    if trapezoid is True:
+        use_pallas = True    # the chunk tier rides the fused kernel
 
     donate_argnums = (0, 1, 2, 3) if donate else ()
 
@@ -338,27 +310,6 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
             lambda: fit_stokes_K(grid, tuple(lshape), n_inner - 1, dtype,
                                  interpret=pallas_interpret))
 
-    def _fit_band(grid, lshape, dtype):
-        """The `(K, B)` config the streaming banded tier will run (None
-        when none applies) — shared by the tier's admission gate and its
-        traced body so the two can never disagree."""
-        from igg.ops.stokes_trapezoid import (fit_stokes_band,
-                                              stokes_banded_supported)
-
-        from ._dispatch import resolve_band
-
-        if banded is False or n_inner < 3:
-            return None
-        return resolve_band(
-            K, band, K_from_cache or band_from_cache,
-            lambda k, b: stokes_banded_supported(
-                grid, tuple(lshape), k, n_inner - 1, dtype, B=b,
-                interpret=pallas_interpret),
-            lambda bands: fit_stokes_band(grid, tuple(lshape),
-                                          n_inner - 1, dtype,
-                                          interpret=pallas_interpret,
-                                          bands=bands))
-
     def admit_trapezoid(args):
         from igg.degrade import Admission
         from igg.ops import stokes_pallas_supported
@@ -374,9 +325,6 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
         if trapezoid is False:
             return Admission.no("trapezoid=False pins the per-iteration "
                                 "kernel")
-        if banded is True:
-            return Admission.no("banded=True pins the streaming banded "
-                                "tier")
         # Non-raising base probe ("auto", never the forced form): the
         # chunk tier rides the fused kernel, but a use_pallas=True refusal
         # belongs to the mosaic rung.
@@ -429,82 +377,6 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
         return igg.sharded(trap_it, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
 
-    def admit_banded(args):
-        from igg.degrade import Admission
-        from igg.ops import stokes_pallas_supported
-        from igg.ops.stokes_pallas import full_slab_fits
-
-        from ._dispatch import pallas_applicable
-
-        if use_pallas is False:
-            return Admission.no("use_pallas=False pins the XLA path")
-        if banded is False:
-            return Admission.no("banded=False pins the resident tiers")
-        base = pallas_applicable("auto", args[0],
-                                 supported_fn=stokes_pallas_supported,
-                                 requirement=_PALLAS_REQ,
-                                 interpret=pallas_interpret)
-        if not base:
-            return Admission.no(f"fused per-iteration kernel (the banded "
-                                f"tier's carrier) inadmissible: "
-                                f"{getattr(base, 'reason', '')}")
-        if n_inner < 3:
-            return Admission.no(f"n_inner={n_inner} < 3: no warm-up plus "
-                                f"full chunk fits")
-        grid = igg.get_global_grid()
-        P = args[0]
-        lshape = grid.local_shape_any(P)
-        if banded == "auto":
-            if trapezoid is False:
-                return Admission.no("trapezoid=False pins the "
-                                    "per-iteration kernel (pass "
-                                    "banded=True to require the "
-                                    "streaming tier)")
-            if _fit_K(grid, lshape, P.dtype):
-                return Admission.no(
-                    "the resident chunk tier serves this shape (the "
-                    "banded rung engages where fit_stokes_K refuses)")
-            # Where the per-iteration kernel keeps its full slab height,
-            # the streaming chunk's recomputed 2K margins cost more than
-            # the HBM traffic they save (256^3 periodic on one TPU v5e:
-            # 2.64 ms per iteration banded, 1.38 per-iteration).
-            if full_slab_fits(lshape[1], lshape[2]):
-                return Admission.no(
-                    "the per-iteration kernel fits VMEM at its full slab "
-                    "height here (the banded rung engages where it "
-                    "does not)")
-        if not _fit_band(grid, lshape, P.dtype):
-            return Admission.no(
-                "no banded config (K, B) admissible "
-                "(igg.ops.stokes_trapezoid.stokes_banded_supported)")
-        return Admission.yes()
-
-    def build_banded():
-        from igg.ops import fused_stokes_iteration
-        from igg.ops.stokes_pallas import stokes_iterations
-        from igg.ops.stokes_trapezoid import fused_stokes_banded_iters
-
-        def banded_it(P, Vx, Vy, Vz, Rho):
-            kw_it = dict(dx=dx, dy=dy, dz=dz, mu=mu, dtP=dtP, dtV=dtV)
-            grid = igg.get_global_grid()
-            kb = _fit_band(grid, P.shape, P.dtype)
-            if not kb:    # admission gate and trace share _fit_band
-                raise igg.GridError(_BANDED_REQ)
-            Kf, Bf = kb
-            # Warm-up per-iteration kernel: the exchange-fresh entry
-            # state the chunk validity argument requires.
-            state = fused_stokes_iteration(
-                P, Vx, Vy, Vz, Rho, **kw_it, interpret=pallas_interpret)
-            *state, done = fused_stokes_banded_iters(
-                *state, Rho, n_inner=n_inner - 1, K=Kf, B=Bf, **kw_it,
-                interpret=pallas_interpret)
-            # The remainder through the per-iteration kernel.
-            return stokes_iterations(state, Rho, n_inner - 1 - done,
-                                     **kw_it, interpret=pallas_interpret)
-
-        return igg.sharded(banded_it, donate_argnums=donate_argnums,
-                           check_vma=not pallas_interpret)
-
     def build_pallas_steps():
         from igg.ops.stokes_pallas import stokes_iterations
 
@@ -523,16 +395,13 @@ def make_iteration(params: Params = Params(), *, donate: bool = True,
     trap_tier = Tier(name="stokes3d.trapezoid", rung=0,
                      build=build_trapezoid, admit=admit_trapezoid,
                      required=trapezoid is True, requirement=_TRAPEZOID_REQ)
-    banded_tier = Tier(name="stokes3d.banded", rung=0,
-                       build=build_banded, admit=admit_banded,
-                       required=banded is True, requirement=_BANDED_REQ)
     return auto_dispatch(
         use_pallas=use_pallas, interpret=pallas_interpret,
         supported_fn=stokes_pallas_supported, requirement=_PALLAS_REQ,
         xla_path=xla_path, build_pallas_steps=build_pallas_steps,
         donate_argnums=donate_argnums,
         family="stokes3d", verify=verify,
-        extra_tiers=(trap_tier, banded_tier))
+        extra_tiers=(trap_tier,))
 
 
 def residual_norms(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu):

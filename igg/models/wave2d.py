@@ -112,22 +112,10 @@ _CHUNK_REQ = (
     "VMEM budget (igg.ops.wave2d_pallas.wave2d_chunk_supported); use "
     "chunk='auto' or the per-step tiers otherwise.")
 
-_BANDED_REQ = (
-    "the streaming banded wave2d chunk tier requires the fused per-step "
-    "kernel's prerequisites plus: PERIODIC dims only, n_inner >= K+1, "
-    "banded geometry (band B >= 8, B % 8 == 0, extended x span "
-    "divisible into >= 2 bands), 2K-deep send slabs inside every split "
-    "dimension's block, and a rolling band window set within the VMEM "
-    "budget (igg.ops.wave2d_pallas.wave2d_banded_supported — note the "
-    "compiled Mosaic instantiation is 3-D-only, so this tier serves "
-    "interpret meshes; compiled TPU runs refuse with a structured "
-    "reason); use banded='auto' or the resident tiers otherwise.")
-
-
 def make_step(params: Params = Params(), *, donate: bool = True,
               overlap="auto", n_inner: int = 1, use_pallas="auto",
               pallas_interpret: bool = False, chunk="auto", K: int = None,
-              banded="auto", band: int = None, verify=None, tune=None):
+              verify=None, tune=None):
     """Compiled `(P, Vx, Vy) -> (P, Vx, Vy)` advancing `n_inner` steps in
     one SPMD program, dispatched through the family's degradation ladder
     (`wave2d.chunk` → `wave2d.mosaic` → `wave2d.xla`).
@@ -146,14 +134,7 @@ def make_step(params: Params = Params(), *, donate: bool = True,
     numerically checks each fast tier against the truth before it serves
     traffic.  `tune` consults the autotuner's cached winner for this
     signature ("auto"/True/False; `igg.autotune` — True searches on a
-    cache miss).
-
-    `banded` admits the STREAMING banded chunk tier
-    (`igg.ops.wave2d_pallas.fused_wave2d_banded_steps` — rolling VMEM
-    window; the ladder rung below the resident chunk): "auto" (default)
-    engages it only where the resident tier's `fit_wave2d_K` refuses,
-    True requires it, False pins the resident tiers.  `band` overrides
-    the auto-fitted band depth B (`fit_wave2d_band`)."""
+    cache miss)."""
     from jax import lax
 
     from igg.overlap import resolve_overlap
@@ -166,15 +147,12 @@ def make_step(params: Params = Params(), *, donate: bool = True,
 
     from ._dispatch import apply_tuned
 
-    (K, K_from_cache, band, band_from_cache, chunk, banded,
-     use_pallas, tuned) = apply_tuned(
+    K, K_from_cache, chunk, use_pallas, tuned = apply_tuned(
         "wave2d", tune, n_inner=n_inner, interpret=pallas_interpret, K=K,
-        chunk_knob=chunk, use_pallas=use_pallas, band=band,
-        banded_knob=banded)
+        chunk_knob=chunk, use_pallas=use_pallas,
+        tiers=("chunk", "mosaic", "xla"))
     overlap = resolve_overlap(overlap, family="wave2d", tuned=tuned,
-                              radius=2, ndim=2,
-                              chunk_active=(chunk is True
-                                            or banded is True))
+                              radius=2, ndim=2, chunk_active=chunk is True)
 
     def step_kw():
         return dict(dx=dx, dy=dy, dt=dt, rho=rho, K=bulk)
@@ -190,10 +168,8 @@ def make_step(params: Params = Params(), *, donate: bool = True,
 
     if chunk is True and use_pallas is False:
         raise igg.GridError(_CHUNK_REQ)
-    if banded is True and use_pallas is False:
-        raise igg.GridError(_BANDED_REQ)
-    if chunk is True or banded is True:
-        use_pallas = True    # the chunk tiers ride the fused kernel
+    if chunk is True:
+        use_pallas = True    # the chunk tier rides the fused kernel
 
     def _fit_K(grid, lshape, dtype):
         from igg.ops.wave2d_pallas import (fit_wave2d_K,
@@ -221,9 +197,6 @@ def make_step(params: Params = Params(), *, donate: bool = True,
             return Admission.no("use_pallas=False pins the XLA path")
         if chunk is False:
             return Admission.no("chunk=False pins the per-step tiers")
-        if banded is True:
-            return Admission.no("banded=True pins the streaming banded "
-                                "tier")
         base = pallas_applicable("auto", args[0],
                                  supported_fn=wave2d_pallas_supported,
                                  requirement=_PALLAS_REQ,
@@ -273,96 +246,6 @@ def make_step(params: Params = Params(), *, donate: bool = True,
         return igg.sharded(chunk_steps, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
 
-    def _fit_band(grid, lshape, dtype):
-        """The `(K, B)` config the streaming banded tier will run (None
-        when none applies) — shared by the tier's admission gate and its
-        traced body so the two can never disagree."""
-        from igg.ops.wave2d_pallas import (fit_wave2d_band,
-                                           wave2d_banded_supported)
-
-        from ._dispatch import resolve_band
-
-        if banded is False or n_inner < 3:
-            return None
-        return resolve_band(
-            K, band, K_from_cache or band_from_cache,
-            lambda k, b: wave2d_banded_supported(
-                grid, tuple(lshape), k, n_inner - 1, dtype, B=b,
-                interpret=pallas_interpret),
-            lambda bands: fit_wave2d_band(grid, tuple(lshape),
-                                          n_inner - 1, dtype,
-                                          interpret=pallas_interpret,
-                                          bands=bands))
-
-    def admit_banded(args):
-        from igg.degrade import Admission
-        from igg.ops.wave2d_pallas import wave2d_pallas_supported
-
-        from ._dispatch import pallas_applicable
-
-        if use_pallas is False:
-            return Admission.no("use_pallas=False pins the XLA path")
-        if banded is False:
-            return Admission.no("banded=False pins the resident tiers")
-        base = pallas_applicable("auto", args[0],
-                                 supported_fn=wave2d_pallas_supported,
-                                 requirement=_PALLAS_REQ,
-                                 interpret=pallas_interpret)
-        if not base:
-            return Admission.no(f"fused per-step kernel (the banded "
-                                f"tier's carrier) inadmissible: "
-                                f"{getattr(base, 'reason', '')}")
-        if n_inner < 3:
-            return Admission.no(f"n_inner={n_inner} < 3: no warm-up plus "
-                                f"full chunk fits")
-        grid = igg.get_global_grid()
-        P = args[0]
-        lshape = grid.local_shape_any(P)
-        if banded == "auto":
-            if chunk is False:
-                return Admission.no("chunk=False pins the per-step tiers "
-                                    "(pass banded=True to require the "
-                                    "streaming tier)")
-            if _fit_K(grid, lshape, P.dtype):
-                return Admission.no(
-                    "the resident chunk tier serves this shape (the "
-                    "banded rung engages where fit_wave2d_K refuses)")
-        if not _fit_band(grid, lshape, P.dtype):
-            return Admission.no(
-                "no banded config (K, B) admissible "
-                "(igg.ops.wave2d_pallas.wave2d_banded_supported)")
-        return Admission.yes()
-
-    def build_banded():
-        from igg.ops.wave2d_pallas import (fused_wave2d_banded_steps,
-                                           fused_wave2d_step)
-
-        def banded_steps(P, Vx, Vy):
-            kw = step_kw()
-            grid = igg.get_global_grid()
-            kb = _fit_band(grid, P.shape, P.dtype)
-            if not kb:    # admission gate and trace share _fit_band
-                raise igg.GridError(_BANDED_REQ)
-            Kf, Bf = kb
-            # Warm-up per-step kernel: the exchange-fresh entry state
-            # the chunk validity argument requires.
-            S = fused_wave2d_step(P, Vx, Vy, **kw,
-                                  interpret=pallas_interpret)
-            *S, done = fused_wave2d_banded_steps(
-                *S, n_inner=n_inner - 1, K=Kf, B=Bf, dx=dx, dy=dy, dt=dt,
-                rho=rho, bulk=bulk, interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:    # remainder through the per-step kernel
-                S = lax.fori_loop(
-                    0, n,
-                    lambda _, T: tuple(fused_wave2d_step(
-                        *T, **step_kw(), interpret=pallas_interpret)),
-                    tuple(S))
-            return tuple(S)
-
-        return igg.sharded(banded_steps, donate_argnums=donate_argnums,
-                           check_vma=not pallas_interpret)
-
     def build_pallas_steps():
         from igg.ops.wave2d_pallas import fused_wave2d_steps
 
@@ -381,16 +264,13 @@ def make_step(params: Params = Params(), *, donate: bool = True,
     chunk_tier = Tier(name="wave2d.chunk", rung=0, build=build_chunk,
                       admit=admit_chunk, required=chunk is True,
                       requirement=_CHUNK_REQ)
-    banded_tier = Tier(name="wave2d.banded", rung=0, build=build_banded,
-                       admit=admit_banded, required=banded is True,
-                       requirement=_BANDED_REQ)
     return auto_dispatch(
         use_pallas=use_pallas, interpret=pallas_interpret,
         supported_fn=wave2d_pallas_supported, requirement=_PALLAS_REQ,
         xla_path=xla_path, build_pallas_steps=build_pallas_steps,
         donate_argnums=donate_argnums,
         family="wave2d", verify=verify,
-        extra_tiers=(chunk_tier, banded_tier))
+        extra_tiers=(chunk_tier,))
 
 
 def run(nt: int, params: Params = Params(), dtype=np.float32,

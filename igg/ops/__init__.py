@@ -16,39 +16,21 @@ from .stencil import interior_add
 from .hm3d_pallas import (fused_hm3d_step, fused_hm3d_steps,
                           hm3d_pallas_supported)
 from .stokes_pallas import fused_stokes_iteration, stokes_pallas_supported
-from .stokes_trapezoid import (fit_stokes_K, fit_stokes_band,
-                               fused_stokes_banded_iters,
-                               fused_stokes_trapezoid_iters,
-                               stokes_banded_supported,
+from .stokes_trapezoid import (fit_stokes_K, fused_stokes_trapezoid_iters,
                                stokes_trapezoid_supported)
-from .hm3d_trapezoid import (fit_hm3d_K, fit_hm3d_band,
-                             fused_hm3d_banded_steps,
-                             fused_hm3d_trapezoid_steps,
-                             hm3d_banded_supported,
+from .hm3d_trapezoid import (fit_hm3d_K, fused_hm3d_trapezoid_steps,
                              hm3d_trapezoid_supported)
-from .wave2d_pallas import (fit_wave2d_K, fit_wave2d_band,
-                            fused_wave2d_banded_steps,
-                            fused_wave2d_chunk_steps,
+from .wave2d_pallas import (fit_wave2d_K, fused_wave2d_chunk_steps,
                             fused_wave2d_step, fused_wave2d_steps,
-                            wave2d_banded_supported,
                             wave2d_chunk_supported, wave2d_pallas_supported)
-from .diffusion_trapezoid import (diffusion_banded_supported,
-                                  fit_diffusion_band,
-                                  fused_diffusion_banded_steps)
 
-__all__ = ["diffusion_banded_supported", "diffusion_compute",
-           "fit_diffusion_band", "fit_hm3d_K", "fit_hm3d_band",
-           "fit_stokes_K", "fit_stokes_band", "fit_wave2d_K",
-           "fit_wave2d_band", "fused_diffusion_banded_steps",
-           "fused_diffusion_step", "fused_diffusion_steps",
-           "fused_hm3d_banded_steps", "fused_hm3d_step",
-           "fused_hm3d_steps", "fused_hm3d_trapezoid_steps",
-           "fused_stokes_banded_iters", "fused_stokes_iteration",
-           "fused_stokes_trapezoid_iters", "fused_wave2d_banded_steps",
-           "fused_wave2d_chunk_steps", "fused_wave2d_step",
-           "fused_wave2d_steps", "hm3d_banded_supported",
+__all__ = ["diffusion_compute", "fit_hm3d_K", "fit_stokes_K",
+           "fit_wave2d_K", "fused_diffusion_step", "fused_diffusion_steps",
+           "fused_hm3d_step", "fused_hm3d_steps",
+           "fused_hm3d_trapezoid_steps", "fused_stokes_iteration",
+           "fused_stokes_trapezoid_iters", "fused_wave2d_chunk_steps",
+           "fused_wave2d_step", "fused_wave2d_steps",
            "hm3d_pallas_supported", "hm3d_trapezoid_supported",
-           "interior_add", "pallas_supported", "stokes_banded_supported",
-           "stokes_pallas_supported", "stokes_trapezoid_supported",
-           "wave2d_banded_supported", "wave2d_chunk_supported",
+           "interior_add", "pallas_supported", "stokes_pallas_supported",
+           "stokes_trapezoid_supported", "wave2d_chunk_supported",
            "wave2d_pallas_supported"]

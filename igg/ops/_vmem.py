@@ -101,56 +101,6 @@ def whole_block_vmem(shapes, itemsize: int = 4) -> int:
     return int(2 * 2 * total * itemsize)
 
 
-def banded_vmem(ext_shapes, B: int, extras, n_up: int, *, lo: int = 1,
-                modes=None, freeze_fields=(), itemsize: int = 4) -> int:
-    """Modeled VMEM footprint of the STREAMING banded chunk kernel
-    (`chunk_engine.streaming_chunk_call`) at band depth B: the per-field
-    rolling windows (`lo + B + extras[f]` tile-padded rows — NOT the
-    full extended block, which is the whole point), the double-buffered
-    out slot pairs of the updated fields, the open-dim freeze planes,
-    and the resident models' 2x margin for band temporaries + Mosaic's
-    own scratch.  Compared against :func:`chunk_budget` (override-aware,
-    so `set_cap_override` sweeps reach the banded gates like every
-    other kernel's)."""
-    from .chunk_engine import normalize_freeze, pad8, pad128
-
-    def row(s):
-        return (pad8(s[1]) * pad128(s[2]) if len(s) == 3
-                else pad128(s[1]))
-
-    need = sum((lo + B + e) * row(s)
-               for s, e in zip(ext_shapes, extras))
-    need += sum(2 * B * row(s) for s in ext_shapes[:n_up])
-    if modes is not None:
-        nd = len(ext_shapes[0])
-        freeze = normalize_freeze(freeze_fields, nd)
-        for d in range(nd):
-            if modes[d] in ("oext", "frozen"):
-                for f in freeze[d]:
-                    s = ext_shapes[f]
-                    plane = s[:d] + s[d + 1:]
-                    p = (pad8(plane[0]) * pad128(plane[1])
-                         if len(plane) == 2 else pad128(plane[0]))
-                    need += 2 * p
-    return int(2 * need * itemsize)
-
-
-def fit_banded(admissible, kmax: int, *, bands=(8, 16),
-               min_k: int = 2):
-    """Largest admissible `(K, B)` for a streaming banded tier:
-    K by halving from kmax (deeper chunks amortize more exchange — the
-    window footprint barely depends on K), bands in preference order;
-    None when none applies.  `admissible(K, B)` is the family's full
-    banded admission gate."""
-    K = int(kmax)
-    while K >= min_k:
-        for B in bands:
-            if admissible(K, B):
-                return K, B
-        K //= 2
-    return None
-
-
 def fit_bx(need_fn, bx: int, S0: int, S1: int, S2: int, *,
            min_bx: int, check_vmem: bool = True) -> int:
     """Largest slab height <= bx (halving, >= `min_bx`) that divides S0

@@ -351,7 +351,7 @@ def admit_send_slabs(shapes, ols, E, modes, *, grid=None, min_ol: int = 2):
 
 
 def admit_sublane_extension(E, modes, *, tile: int = 8):
-    """The sublane-tile-extension gate every banded/resident chunk kernel
+    """The sublane-tile-extension gate every resident chunk kernel
     shares: a y-extension that is not a whole number of sublane tiles
     shifts every leading-dim row slice (and the central y window) off the
     Mosaic `(8, 128)` tile grid — configurations Mosaic refuses DEEP in
@@ -364,45 +364,6 @@ def admit_sublane_extension(E, modes, *, tile: int = 8):
     if len(modes) > 1 and modes[1] in ("ext", "oext") and E % tile != 0:
         return Admission.no(f"y-extension E={E} not on sublane tiles "
                             f"(E % {tile} != 0)")
-    return None
-
-
-def admit_banded_geometry(shapes, E, modes, *, B, extras, lo=1,
-                          interpret=False):
-    """Structural gates of the streaming banded realization (shared by
-    every family's `*_banded_supported`): sublane-tiled band depth, a
-    band-divisible extended x span with at least two bands (the
-    ping-pong out-write pipeline drains slot pairs), read margins inside
-    one band, and — compiled mode only — 3-D fields plus the shared
-    sublane-extension geometry (the pure-XLA banded realization has no
-    tile grid, so interpret meshes skip the Mosaic-only gates).  Returns
-    a falsy Admission or None."""
-    from ..degrade import Admission
-
-    nd = len(shapes[0])
-    ext_shapes = [ext_shape(s, E, modes) for s in shapes]
-    base = min(s[0] for s in ext_shapes)
-    if B < 8 or B % 8 != 0:
-        return Admission.no(f"band depth B={B} not on sublane tiles "
-                            f"(needs B % 8 == 0, B >= 8)")
-    if base % B != 0:
-        return Admission.no(f"extended x span {base} not band-divisible "
-                            f"by B={B}")
-    if base // B < 2:
-        return Admission.no(f"extended x span {base} holds fewer than 2 "
-                            f"bands of B={B} (the streaming out-write "
-                            f"pipeline ping-pongs two slots)")
-    if max(extras) + lo > B:
-        return Admission.no(f"read margins lo={lo}/extras={tuple(extras)} "
-                            f"exceed one band of B={B}")
-    if not interpret:
-        if nd != 3:
-            return Admission.no(f"compiled streaming kernel is 3-D only "
-                                f"({nd}-D x-row bands are not "
-                                f"sublane-tileable; interpret mode serves)")
-        sub = admit_sublane_extension(E, modes)
-        if sub is not None:
-            return sub
     return None
 
 
@@ -701,8 +662,7 @@ def band_halo(news, a, bx, flags, frx, fryz, cfg):
     extents).  `cfg` carries modes/ols/ext_shapes/shapes/E and
     `freeze_fields` (which updated fields the open-dim no-write applies
     to — uniform sequence or per-dim dict, :func:`normalize_freeze`).
-    Pure values — shared by the generic Mosaic kernels, the streaming
-    banded kernel, the pure-XLA banded realization, and the
+    Pure values — shared by the generic Mosaic kernels and the
     banded-scheme simulation tests."""
     import jax.numpy as jnp
     from jax import lax
@@ -1012,471 +972,3 @@ def resident_chunk_call(exts, const_exts, *, K, bx, modes, grid, ols,
            for f, F in enumerate(out)]
     return tuple(central(F, f) for f, F in enumerate(out))
 
-
-# ---------------------------------------------------------------------------
-# The STREAMING banded chunk realization (HBM ping-pong, rolling VMEM window)
-# ---------------------------------------------------------------------------
-#
-# The resident kernels above hold the full K-extended block in VMEM, so
-# `fit_chunk_K` gates them off at exactly the headline shapes (the 160^3+
-# refusal in `_vmem`).  The streaming realization below generalizes the
-# diffusion ping-pong scheme (`diffusion_trapezoid._kernel`) to the
-# engine's N-field/freeze-set/margin config: per iteration the x-row
-# bands sweep the 2K-extended block through a rolling VMEM window of
-# `lo + B + extras[f]` rows per field, writing B-row out slabs to an HBM
-# ping-pong pair — the full extended block NEVER materializes in VMEM,
-# so VMEM need is O(B * S1 * S2) instead of O(S0e * S1 * S2) and the
-# tier admits wherever the band window fits.  HBM traffic per chunk is
-# K reads + K writes of the extended fields (vs the per-step path's K
-# reads + K writes of the fields PLUS K exchanges): only the exchange
-# amortizes, which is what `igg.perf.bytes_per_step` models for the
-# `.banded` tiers.
-#
-# Every band's window reads ALL-OLD values (the ping-pong source buffer
-# holds the previous iteration), which is exactly the data the resident
-# kernel's lag-slot scheme feeds its bands — so each family's proven
-# `band_update` core transfers unchanged (lo margin 1), and derived
-# cores come from :func:`band_core_from_window`.
-
-
-def band_core_from_window(core, lo, n_up=None):
-    """Derive a `band_update(*windows, bx=)` from a family's full-window
-    `core(*fields)` (the same callable the window realizations evolve):
-    apply the core to the small band windows (rows `[a-lo, a+bx+extras)`
-    of each field — staggered-consistent shapes, so the shape-driven
-    cores evaluate unchanged) and slice the central `bx` rows.  `lo`
-    must be the per-iteration margin loss (`analysis.margin_after(1)`
-    for spec families) so the central rows are at full validity
-    distance from both window edges; `n_up` truncates cores that return
-    const fields too."""
-    def band_update(*Ws, bx):
-        outs = core(*Ws)
-        if n_up is not None:
-            outs = outs[:n_up]
-        return tuple(o[lo:lo + bx] for o in outs)
-
-    return band_update
-
-
-def banded_window_xla(fields, *, K, B, lo, modes, grid, ols, shapes, E,
-                      band_update, extras, n_up, freeze_fields):
-    """Pure-XLA realization of the streaming banded scheme: K iterations,
-    each sweeping x-row bands over the previous iteration's buffers
-    (ping-pong semantics — every band window reads all-OLD values) with
-    clamped-duplicate margins at the buffer ends and the engine's
-    per-band halo handling (:func:`band_halo`, the same callable the
-    compiled kernels run).  Interpret/CPU meshes prove the banded data
-    flow against the window-realization truth rung with this function;
-    the contaminated shoulder rows the clamped margins produce differ
-    from the window realization's but never reach the central window
-    (the trapezoidal validity argument).  Returns the evolved extended
-    buffers (updated fields first, const fields passed through);
-    central slicing is the caller's."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    entry = tuple(fields)
-    nd = fields[0].ndim
-    n_fields = len(fields)
-    ext_shapes = [tuple(F.shape) for F in fields]
-    base = min(s[0] for s in ext_shapes)
-    nb = base // B
-    freeze = normalize_freeze(freeze_fields, nd)
-    cfg = dict(modes=tuple(modes), ols=tuple(ols), E=E,
-               ext_shapes=tuple(ext_shapes), shapes=tuple(shapes),
-               freeze_fields=freeze_fields)
-
-    any_open = any(modes[d] in ("oext", "frozen") for d in range(nd))
-    flags = ([edge_flags(tuple(modes) + ("wrap",) * (3 - nd), grid)[j]
-              for j in range(6)] if any_open else [0] * 6)
-
-    # Chunk-entry freeze planes (whole planes; y/z ones band-sliced per
-    # band below, the kernel's fr_vmem convention).
-    frx, fryz_full = {}, {}
-    for d in range(nd):
-        if modes[d] not in ("oext", "frozen"):
-            continue
-        fr = E if modes[d] == "oext" else 0
-        for f in freeze[d]:
-            hi = fr + shapes[f][d] - 1
-            for side, idx in ((0, fr), (1, hi)):
-                p = jnp.squeeze(
-                    lax.slice_in_dim(entry[f], idx, idx + 1, axis=d), d)
-                if d == 0:
-                    frx[(f, side)] = p
-                else:
-                    fryz_full[(f, d, side)] = p
-
-    def one_iter(_, S):
-        padded = []
-        for f in range(n_fields):
-            F = S[f]
-            top = extras[f] - (ext_shapes[f][0] - base)
-            parts = []
-            if lo:
-                parts.append(jnp.concatenate(
-                    [lax.slice_in_dim(F, 0, 1, axis=0)] * lo, axis=0))
-            parts.append(F)
-            if top > 0:
-                last = lax.slice_in_dim(F, ext_shapes[f][0] - 1,
-                                        ext_shapes[f][0], axis=0)
-                parts.append(jnp.concatenate([last] * top, axis=0))
-            padded.append(jnp.concatenate(parts, axis=0)
-                          if len(parts) > 1 else parts[0])
-
-        def band(i, D):
-            a = i * B
-            Ws = [lax.dynamic_slice_in_dim(P, a, lo + B + extras[f],
-                                           axis=0)
-                  for f, P in enumerate(padded)]
-            news = band_update(*Ws, bx=B)
-            fryz = {key: lax.dynamic_slice_in_dim(p, a, B, axis=0)
-                    for key, p in fryz_full.items()}
-            news = band_halo(news, a, B, flags, frx, fryz, cfg)
-            return tuple(
-                lax.dynamic_update_slice_in_dim(D[f], news[f], a, axis=0)
-                for f in range(n_up))
-
-        # DST starts from the OLD buffers: rows the band grid never
-        # writes (a staggered field's top face) keep their values,
-        # exactly the compiled kernel's aliasing semantics.
-        new_up = lax.fori_loop(0, nb, band, tuple(S[:n_up]))
-        return (*new_up, *S[n_up:])
-
-    return lax.fori_loop(0, K, one_iter, entry)
-
-
-def _streaming_kernel(*refs, K, B, nb, lo, cfg, nfr, pads, band_update,
-                      extras, stags):
-    """The streaming banded chunk kernel: grid `(K, nb)`, HBM ping-pong
-    (the `diffusion_trapezoid._kernel` scheme generalized).  Per program
-    `(k, i)`: fetch each field's rolling window (rows
-    `[i*B - lo, i*B + B + extras[f])`, clamped-duplicated at the buffer
-    ends) from the iteration-k source — the input extended buffers at
-    k=0, then the ping-pong pair by parity — compute the B-row band with
-    the family's `band_update` + :func:`band_halo`, and write it to the
-    iteration's destination (the other ping-pong buffer; the out buffers
-    at k=K-1) through slot-alternated async DMA (drain both slots at
-    each step boundary, wait the reused slot at i>=2, final drain —
-    the diffusion out-write bookkeeping).  Const fields stream from
-    their single HBM buffer every iteration (never resident: a 256^3
-    coefficient would blow the budget the tier exists to escape)."""
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    ext_shapes = cfg["ext_shapes"]    # logical extended shapes
-    modes = cfg["modes"]
-    n_fields = len(ext_shapes)
-    n_up = cfg["n_up"]
-    freeze = normalize_freeze(cfg.get("freeze_fields", ()), 3)
-
-    it = iter(refs)
-    src_hbm = [next(it) for _ in range(n_fields)]   # padded extended fields
-    flags_ref = next(it) if nfr else None           # SMEM (6,) i32
-    fr_hbm = [next(it) for _ in range(nfr)]         # padded freeze planes
-    outs, b0, b1 = [], [], []
-    for _ in range(n_up):                           # (out, ping, pong) * n_up
-        outs.append(next(it))
-        b0.append(next(it))
-        b1.append(next(it))
-    wv = [next(it) for _ in range(n_fields)]        # rolling window scratch
-    o2 = [next(it) for _ in range(n_up)]            # (2, B, S1p, S2p) slabs
-    fr_v = [next(it) for _ in range(nfr)]
-    wsem = next(it)
-    osem = next(it)
-    fsem = next(it) if nfr else None
-
-    k = pl.program_id(0)
-    i = pl.program_id(1)
-    a = i * B
-    sl = i % 2
-
-    # One-time: freeze planes HBM -> VMEM; the ping buffer's staggered
-    # tail rows seeded from the entry values (the pong buffer IS the
-    # aliased input, so its tail is already correct).
-    if nfr:
-        @pl.when((k == 0) & (i == 0))
-        def _():
-            cs = [pltpu.make_async_copy(fr_hbm[j], fr_v[j], fsem.at[j])
-                  for j in range(nfr)]
-            for c in cs:
-                c.start()
-            for c in cs:
-                c.wait()
-
-    @pl.when((k == 0) & (i == 0))
-    def _():
-        for f in range(n_up):
-            if stags[f]:
-                c = pltpu.make_async_copy(
-                    src_hbm[f].at[pl.ds(nb * B, stags[f])],
-                    b0[f].at[pl.ds(nb * B, stags[f])], wsem.at[f])
-                c.start()
-                c.wait()
-
-    # Out-write bookkeeping (the diffusion ping-pong scheme): drain both
-    # slots at each step boundary, else wait the slot being reused.
-    @pl.when((i == 0) & (k > 0))
-    def _():
-        for f in range(n_up):
-            for s in (0, 1):
-                pltpu.make_async_copy(o2[f].at[s], o2[f].at[s],
-                                      osem.at[f, s]).wait()
-
-    @pl.when(i >= 2)
-    def _():
-        for f in range(n_up):
-            pltpu.make_async_copy(o2[f].at[sl], o2[f].at[sl],
-                                  osem.at[f, sl]).wait()
-
-    # Rolling-window fetches, synchronous (once per band per field; the
-    # out-writes overlap the next band's fetch+compute).  Clamped
-    # duplicate rows at the buffer ends feed only shoulder rows outside
-    # the validity trapezoid.
-    def fetch(f, src):
-        w = wv[f]
-        e = extras[f]
-        stg = stags[f] if f < n_up else ext_shapes[f][0] - nb * B
-        nrows = lo + B + e
-
-        def copy(src_at, w_at):
-            c = pltpu.make_async_copy(src_at, w_at, wsem.at[f])
-            c.start()
-            c.wait()
-
-        @pl.when(i == 0)
-        def _():
-            for r in range(lo):
-                copy(src.at[pl.ds(0, 1)], w.at[pl.ds(r, 1)])
-            copy(src.at[pl.ds(0, B + e)], w.at[pl.ds(lo, B + e)])
-
-        if nb > 2:
-            @pl.when((i > 0) & (i < nb - 1))
-            def _():
-                copy(src.at[pl.ds(a - lo, nrows)], w.at[pl.ds(0, nrows)])
-
-        @pl.when(i == nb - 1)
-        def _():
-            copy(src.at[pl.ds(a - lo, lo + B + stg)],
-                 w.at[pl.ds(0, lo + B + stg)])
-            for r in range(stg, e):
-                copy(src.at[pl.ds(nb * B + stg - 1, 1)],
-                     w.at[pl.ds(lo + B + r, 1)])
-
-    for f in range(n_fields):
-        if f < n_up:
-            @pl.when(k == 0)
-            def _(f=f):
-                fetch(f, src_hbm[f])
-
-            @pl.when((k > 0) & (k % 2 == 1))
-            def _(f=f):
-                fetch(f, b0[f])
-
-            @pl.when((k > 0) & (k % 2 == 0))
-            def _(f=f):
-                fetch(f, b1[f])
-        else:
-            fetch(f, src_hbm[f])
-
-    def logical(W, f):
-        return W[:, :ext_shapes[f][1], :ext_shapes[f][2]]
-
-    Ws = [logical(wv[f][...], f) for f in range(n_fields)]
-    news = band_update(*Ws, bx=B)
-
-    # Per-band halo handling (freeze planes band-sliced to logical
-    # extents; SMEM flags read as scalars) — the resident kernel's exact
-    # assembly.
-    flags = ([flags_ref[j] for j in range(6)] if nfr else [0] * 6)
-    frx, fryz = {}, {}
-    j = 0
-    for d in range(3):
-        if modes[d] not in ("oext", "frozen"):
-            continue
-        for f in freeze[d]:
-            pl_shape = [ext_shapes[f][x] for x in range(3) if x != d]
-            for side in (0, 1):
-                if d == 0:
-                    frx[(f, side)] = fr_v[j][...][:pl_shape[0],
-                                                  :pl_shape[1]]
-                else:
-                    fryz[(f, d, side)] = fr_v[j][pl.ds(a, B)][
-                        :, :pl_shape[1]]
-                j += 1
-    news = band_halo(news, a, B, flags, frx, fryz, cfg)
-
-    # Stage the band in this program's out slot, padded back with the
-    # window's old trailing columns, and launch the async put to the
-    # iteration's destination.
-    for f in range(n_up):
-        new = news[f]
-        pady, padz = pads[f]
-        old = wv[f][pl.ds(lo, B)]
-        if padz:
-            new = jnp.concatenate([new, old[:, :new.shape[1], -padz:]],
-                                  axis=2)
-        if pady:
-            new = jnp.concatenate([new, old[:, -pady:, :]], axis=1)
-        o2[f][pl.ds(sl, 1)] = new[None]
-
-        def put(dst, f=f):
-            pltpu.make_async_copy(o2[f].at[sl], dst.at[pl.ds(a, B)],
-                                  osem.at[f, sl]).start()
-
-        @pl.when(k == K - 1)
-        def _(put=put, f=f):
-            put(outs[f])
-
-        @pl.when((k < K - 1) & (k % 2 == 0))
-        def _(put=put, f=f):
-            put(b0[f])
-
-        @pl.when((k < K - 1) & (k % 2 == 1))
-        def _(put=put, f=f):
-            put(b1[f])
-
-    # Final drain: the last two out DMAs have no successor to wait them.
-    @pl.when((k == K - 1) & (i == nb - 1))
-    def _():
-        for f in range(n_up):
-            pltpu.make_async_copy(o2[f].at[1 - sl], o2[f].at[1 - sl],
-                                  osem.at[f, 1 - sl]).wait()
-            pltpu.make_async_copy(o2[f].at[sl], o2[f].at[sl],
-                                  osem.at[f, sl]).wait()
-
-
-def streaming_chunk_call(exts, const_exts, *, K, B, modes, grid, ols,
-                         shapes, E, band_update, extras, freeze_fields,
-                         lo=1, interpret=False):
-    """Advance K coupled iterations on the extended buffers with the
-    STREAMING banded kernel — the chunk realization that never holds the
-    K-extended block in VMEM; returns the updated fields' central local
-    blocks.  Same contract as :func:`resident_chunk_call` (`exts`
-    updated/aliased, `const_exts` loop-invariant, `extras[f]` the high
-    read margin, `band_update(*windows, bx=)` the family band core) plus
-    `lo`, the low read margin (1 for the hand band cores; the
-    per-iteration margin loss for :func:`band_core_from_window` cores).
-    In interpret mode the chunk runs :func:`banded_window_xla` — the
-    pure-XLA realization of the SAME banded data flow — so CPU meshes
-    prove the scheme itself against the window-realization truth rung,
-    not just the admission gates."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_up = len(exts)
-    fields = list(exts) + list(const_exts)
-    ext_shapes = [tuple(x.shape) for x in fields]
-    nd = exts[0].ndim
-
-    def central(F, f):
-        return central_window(F, shapes[f], E, modes)
-
-    if interpret:
-        out = banded_window_xla(
-            fields, K=K, B=B, lo=lo, modes=modes, grid=grid, ols=ols,
-            shapes=shapes, E=E, band_update=band_update, extras=extras,
-            n_up=n_up, freeze_fields=freeze_fields)
-        return tuple(central(F, f) for f, F in enumerate(out[:n_up]))
-
-    base = min(s[0] for s in ext_shapes)
-    nb = base // B
-    stags = [ext_shapes[f][0] - base for f in range(n_up)]
-    freeze = normalize_freeze(freeze_fields, nd)
-    cfg = dict(modes=tuple(modes), ols=tuple(ols[:n_up]),
-               ext_shapes=tuple(ext_shapes), E=E,
-               shapes=tuple(shapes[:n_up]), n_up=n_up,
-               freeze_fields=freeze_fields)
-
-    def padded(F):
-        s = F.shape
-        py = _pad8(s[1]) - s[1]
-        pz = _pad128(s[2]) - s[2]
-        if py or pz:
-            F = jnp.pad(F, [(0, 0), (0, py), (0, pz)])
-        return F
-
-    fields_all = [padded(F) for F in fields]
-    pads = [(_pad8(s[1]) - s[1], _pad128(s[2]) - s[2])
-            for s in ext_shapes[:n_up]]
-
-    fr_planes = []
-    flag_ops = []
-    any_open = any(m in ("oext", "frozen") for m in modes)
-    if any_open:
-        for d in range(3):
-            if modes[d] not in ("oext", "frozen"):
-                continue
-            fr = E if modes[d] == "oext" else 0
-            for f in freeze[d]:
-                hi = fr + shapes[f][d] - 1
-                for idx in (fr, hi):
-                    p = jnp.squeeze(
-                        lax.slice_in_dim(exts[f], idx, idx + 1, axis=d), d)
-                    ps = p.shape
-                    py = _pad8(ps[0]) - ps[0]
-                    pz = _pad128(ps[1]) - ps[1]
-                    if py or pz:
-                        p = jnp.pad(p, [(0, py), (0, pz)])
-                    fr_planes.append(p)
-        if fr_planes:
-            flag_ops = [edge_flags(modes, grid)]
-    nfr = len(fr_planes)
-
-    kern = partial(_streaming_kernel, K=K, B=B, nb=nb, lo=lo, cfg=cfg,
-                   nfr=nfr, pads=pads, band_update=band_update,
-                   extras=extras, stags=stags)
-
-    operands = [*fields_all, *flag_ops, *fr_planes]
-    vmas = [getattr(getattr(x, "aval", None), "vma", None)
-            for x in operands]
-    vma = frozenset().union(*[v for v in vmas if v])
-
-    def shp(s):
-        return (jax.ShapeDtypeStruct(s, exts[0].dtype, vma=vma) if vma
-                else jax.ShapeDtypeStruct(s, exts[0].dtype))
-
-    # Per updated field: out + ping + pong, all full padded extended
-    # shape; the input buffer aliases the PONG slot (first written at
-    # k=1 — dead after the k=0 reads), so unwritten tail rows keep their
-    # entry values there for the odd-iteration fetches.
-    out_shapes = []
-    aliases = {}
-    for f in range(n_up):
-        out_shapes += [shp(fields_all[f].shape)] * 3
-        aliases[f] = 3 * f + 2
-
-    # Scratch order MUST mirror the kernel's unpack: rolling windows,
-    # out slot pairs, freeze-plane VMEM, window semaphores, out
-    # semaphores, then the freeze-plane semaphore LAST.
-    win_scratch = [
-        pltpu.VMEM((lo + B + extras[f], F.shape[1], F.shape[2]), F.dtype)
-        for f, F in enumerate(fields_all)]
-    out = pl.pallas_call(
-        kern,
-        name="chunk_streaming",
-        grid=(K, nb),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(fields_all)
-        + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(flag_ops)
-        + [pl.BlockSpec(memory_space=pl.ANY)] * nfr,
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (3 * n_up),
-        out_shape=out_shapes,
-        input_output_aliases=aliases,
-        scratch_shapes=win_scratch
-        + [pltpu.VMEM((2, B, F.shape[1], F.shape[2]), F.dtype)
-           for F in fields_all[:n_up]]
-        + [pltpu.VMEM(p.shape, p.dtype) for p in fr_planes]
-        + [pltpu.SemaphoreType.DMA((len(fields_all),)),
-           pltpu.SemaphoreType.DMA((n_up, 2))]
-        + ([pltpu.SemaphoreType.DMA((nfr,))] if nfr else []),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=128 * 1024 * 1024,
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(*operands)
-    evolved = [out[3 * f][:, :ext_shapes[f][1], :ext_shapes[f][2]]
-               for f in range(n_up)]
-    return tuple(central(F, f) for f, F in enumerate(evolved))

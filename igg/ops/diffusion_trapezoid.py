@@ -94,12 +94,10 @@ from __future__ import annotations
 
 from functools import partial
 
-from ._vmem import banded_vmem, chunk_budget, fit_banded
-from .chunk_engine import (admit_banded_geometry, admit_chunk_common,
-                           admit_send_slabs, central_window, dim_modes,
-                           edge_flags, ext_shape, extend_dim_grouped,
-                           extend_fields, field_ols, freeze_open_dim,
-                           run_chunks, streaming_chunk_call, wrap_edges)
+from ._vmem import chunk_budget
+from .chunk_engine import (admit_chunk_common, central_window, dim_modes,
+                           edge_flags, extend_dim_grouped, extend_fields,
+                           freeze_open_dim, run_chunks, wrap_edges)
 from .diffusion_pallas import _u_rows
 
 # Engine aliases (the historical private names, still used by tests and
@@ -584,110 +582,6 @@ def fused_diffusion_trapezoid_steps(T, A, *, n_inner: int, bx: int,
         return (_chunk_call(Text, A_ext, shape, K=K, bx=bx, modes=modes,
                             grid=grid, rdx2=rdx2, rdy2=rdy2, rdz2=rdz2,
                             interpret=interpret),)
-
-    T, done = run_chunks((T,), n_inner=n_inner, K=K, one_chunk=one)
-    return T, done
-
-
-# ---------------------------------------------------------------------------
-# The STREAMING banded tier (diffusion3d.banded): rolling-window
-# realization for the shapes the resident kernels' K-bound refuses
-# ---------------------------------------------------------------------------
-
-def _banded_update(Wt, Wa, *, bx, rdx2, rdy2, rdz2):
-    """New band values (rows [a, a+bx), window row offset 1) from
-    margin-1 windows of T and the coefficient — the per-step kernel's
-    assembly: interior cells take `_u_rows`, y/z edge rows keep their
-    old values (owned by the band-halo wrap/freeze).  Pure values: the
-    engine's streaming kernel and the banded XLA realization share it."""
-    import jax.numpy as jnp
-
-    o = Wt[1:1 + bx]
-    inner = _u_rows(Wt[0:bx], o, Wt[2:2 + bx], Wa[1:1 + bx],
-                    rdx2, rdy2, rdz2)
-    mid = jnp.concatenate([o[:, 1:-1, 0:1], inner, o[:, 1:-1, -1:]],
-                          axis=2)
-    return (jnp.concatenate([o[:, 0:1, :], mid, o[:, -1:, :]], axis=1),)
-
-
-def diffusion_banded_supported(grid, shape, K: int, n_inner: int, dtype,
-                               B: int = 8, interpret: bool = False):
-    """Whether the STREAMING banded diffusion chunk tier applies at
-    depth K / band B: the trapezoid tier's structural gates minus the
-    resident K-bound — the rolling window (T plus the streamed
-    coefficient, margin 1) is O(B), so this rung admits at the 256^3
-    headline shape where `trapezoid_supported`'s resident accounting
-    refuses.  Open dims freeze T's boundary planes (`freeze_fields =
-    (0,)` — the coefficient is never written).  Returns an
-    :class:`igg.degrade.Admission`."""
-    import numpy as np
-
-    from ..degrade import Admission
-
-    common = admit_chunk_common(grid, K, n_inner)
-    if common is not None:
-        return common
-    if tuple(shape) != tuple(grid.nxyz):
-        return Admission.no(f"local shape {tuple(shape)} != grid block "
-                            f"{tuple(grid.nxyz)}")
-    if np.dtype(dtype) != np.float32:
-        return Admission.no(f"dtype {np.dtype(dtype)} is not float32")
-    modes = _dim_modes(grid)
-    E = K
-    shapes = [tuple(shape), tuple(shape)]
-    ols = field_ols(grid, shapes)
-    slabs = admit_send_slabs(shapes, ols, E, modes, grid=grid)
-    if slabs is not None:
-        return slabs
-    geo = admit_banded_geometry(shapes, E, modes, B=B, extras=(1, 1),
-                                interpret=interpret)
-    if geo is not None:
-        return geo
-    exts = [ext_shape(s, E, modes) for s in shapes]
-    need = banded_vmem(exts, B, (1, 1), 1, modes=modes,
-                       freeze_fields=(0,))
-    if need > chunk_budget():
-        return Admission.no(f"banded window set {need} bytes exceeds "
-                            f"the VMEM budget {chunk_budget()}")
-    return Admission.yes()
-
-
-def fit_diffusion_band(grid, shape, n_inner: int, dtype,
-                       interpret: bool = False, kmax: int = 8,
-                       bands=(8, 16)):
-    """Largest admissible `(K, B)` for the banded tier
-    (`_vmem.fit_banded`); None when none applies."""
-    return fit_banded(
-        lambda K, B: diffusion_banded_supported(grid, tuple(shape), K,
-                                                n_inner, dtype, B=B,
-                                                interpret=interpret),
-        kmax, bands=bands)
-
-
-def fused_diffusion_banded_steps(T, A, *, n_inner: int, K: int, B: int,
-                                 grid, rdx2, rdy2, rdz2,
-                                 interpret: bool = False):
-    """Advance `n_inner // K` full K-step chunks through the STREAMING
-    banded realization (`chunk_engine.streaming_chunk_call`: rolling
-    VMEM window of band depth B, HBM ping-pong, the coefficient's
-    extended buffer streamed per band instead of held resident);
-    returns `(T, steps_done)`.  Same entry contract as
-    :func:`fused_diffusion_trapezoid_steps` (the caller runs the warm-up
-    step and the per-K remainder through the per-step path)."""
-    modes = _dim_modes(grid)
-    E = K
-    shapes = [T.shape, T.shape]
-    ols = field_ols(grid, shapes)
-    A_ext = extend_fields([A], [ols[1]], E, grid, modes)[0]  # invariant
-
-    def one(T):
-        Text = extend_fields([T], ols[:1], E, grid, modes)
-        return streaming_chunk_call(
-            list(Text), [A_ext], K=K, B=B, modes=modes, grid=grid,
-            ols=ols, shapes=shapes, E=E,
-            band_update=partial(_banded_update, rdx2=rdx2, rdy2=rdy2,
-                                rdz2=rdz2),
-            extras=(1, 1), freeze_fields=(0,), interpret=interpret)
 
     T, done = run_chunks((T,), n_inner=n_inner, K=K, one_chunk=one)
     return T, done

@@ -55,13 +55,12 @@ from __future__ import annotations
 
 from functools import partial
 
-from ._vmem import banded_vmem, chunk_budget, fit_banded, fit_chunk_K
-from .chunk_engine import (admit_banded_geometry, admit_chunk_common,
-                           admit_send_slabs, admit_sublane_extension,
-                           dim_modes, ext_shape, extend_fields, field_ols,
-                           pad8 as _pad8, pad128 as _pad128,
-                           resident_chunk_call, run_chunks,
-                           streaming_chunk_call, window_chunk_xla)
+from ._vmem import chunk_budget, fit_chunk_K
+from .chunk_engine import (admit_chunk_common, admit_send_slabs,
+                           admit_sublane_extension, dim_modes, ext_shape,
+                           extend_fields, field_ols, pad8 as _pad8,
+                           pad128 as _pad128, resident_chunk_call,
+                           run_chunks, window_chunk_xla)
 
 _BX = 8          # x band height of the resident chunk kernel
 
@@ -250,91 +249,6 @@ def fused_hm3d_trapezoid_steps(Pe, phi, *, n_inner: int, K: int,
         exts = extend_fields([Pe, phi], ols, E, grid, modes)
         return _chunk_call(exts, K=K, modes=modes, grid=grid, kw=kw,
                            ols=ols, shapes=shapes, interpret=interpret)
-
-    *S, done = run_chunks((Pe, phi), n_inner=n_inner, K=K, one_chunk=one)
-    return (*S, done)
-
-
-# ---------------------------------------------------------------------------
-# The STREAMING banded tier (hm3d.banded): rolling-window realization for
-# the shapes the resident kernel's K-bound refuses
-# ---------------------------------------------------------------------------
-
-def hm3d_banded_supported(grid, shape, K: int, n_inner: int, dtype,
-                          B: int = 8, interpret: bool = False):
-    """Whether the STREAMING banded HM3D chunk tier applies at depth K /
-    band B: the resident tier's structural gates minus the K-bound —
-    the rolling window's footprint is O(B), so this is the rung that
-    admits at the headline shapes `fit_hm3d_K` refuses.  Returns an
-    :class:`igg.degrade.Admission`."""
-    import numpy as np
-
-    from ..degrade import Admission
-
-    common = admit_chunk_common(grid, K, n_inner)
-    if common is not None:
-        return common
-    if grid.overlaps != (2, 2, 2):
-        return Admission.no(f"grid overlaps {grid.overlaps} != (2, 2, 2)")
-    if tuple(shape) != tuple(grid.nxyz):
-        return Admission.no(f"local shape {tuple(shape)} != grid block "
-                            f"{tuple(grid.nxyz)}")
-    if np.dtype(dtype) != np.float32:
-        return Admission.no(f"dtype {np.dtype(dtype)} is not float32")
-    modes = dim_modes(grid)
-    E = K
-    shapes = [tuple(shape), tuple(shape)]
-    ols = field_ols(grid, shapes)
-    slabs = admit_send_slabs(shapes, ols, E, modes, grid=grid)
-    if slabs is not None:
-        return slabs
-    geo = admit_banded_geometry(shapes, E, modes, B=B, extras=(1, 1),
-                                interpret=interpret)
-    if geo is not None:
-        return geo
-    exts = [ext_shape(s, E, modes) for s in shapes]
-    need = banded_vmem(exts, B, (1, 1), 2, modes=modes,
-                       freeze_fields=(0, 1))
-    if need > chunk_budget():
-        return Admission.no(f"banded window set {need} bytes exceeds "
-                            f"the VMEM budget {chunk_budget()}")
-    return Admission.yes()
-
-
-def fit_hm3d_band(grid, shape, n_inner: int, dtype,
-                  interpret: bool = False, kmax: int = 8,
-                  bands=(8, 16)):
-    """Largest admissible `(K, B)` for the banded tier
-    (`_vmem.fit_banded`); None when none applies."""
-    return fit_banded(
-        lambda K, B: hm3d_banded_supported(grid, tuple(shape), K, n_inner,
-                                           dtype, B=B, interpret=interpret),
-        kmax, bands=bands)
-
-
-def fused_hm3d_banded_steps(Pe, phi, *, n_inner: int, K: int, B: int,
-                            dx, dy, dz, dt, phi0, npow, eta,
-                            interpret: bool = False):
-    """Advance `n_inner // K` full K-step chunks through the STREAMING
-    banded realization (`chunk_engine.streaming_chunk_call` — same
-    `_band_update` core and margins as the resident tier, rolling VMEM
-    window of band depth B); returns `(Pe, phi, steps_done)`.  Same
-    entry contract as :func:`fused_hm3d_trapezoid_steps`."""
-    from .. import shared
-
-    grid = shared.global_grid()
-    modes = dim_modes(grid)
-    E = K
-    shapes = [Pe.shape, phi.shape]
-    ols = field_ols(grid, shapes)
-    kw = dict(dx=dx, dy=dy, dz=dz, dt=dt, phi0=phi0, npow=npow, eta=eta)
-
-    def one(Pe, phi):
-        exts = extend_fields([Pe, phi], ols, E, grid, modes)
-        return streaming_chunk_call(
-            list(exts), [], K=K, B=B, modes=modes, grid=grid, ols=ols,
-            shapes=shapes, E=E, band_update=partial(_band_update, kw=kw),
-            extras=(1, 1), freeze_fields=(0, 1), interpret=interpret)
 
     *S, done = run_chunks((Pe, phi), n_inner=n_inner, K=K, one_chunk=one)
     return (*S, done)

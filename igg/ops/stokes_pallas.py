@@ -19,10 +19,10 @@ Measured on one TPU v5e at 256^3 f32, periodic (the benchmark's
 check after each, host clock over a 10 s window): 1.38 ms per iteration
 through :func:`stokes_iterations`, of which the kernel takes 1.14; a plain
 `fori_loop` of the kernel ran 2.27, as XLA copied every iteration's four
-fresh outputs back into the loop carry, and the streaming banded chunk
-tier 2.64.  The kernel moves at least 0.61 GB an iteration (5 reads + 4
-writes), 0.74 ms at the HBM peak, so at this size HBM bandwidth is not
-what bounds it.
+fresh outputs back into the loop carry, and a streaming banded chunk
+tier (since removed) 2.64.  The kernel moves at least 0.61 GB an
+iteration (5 reads + 4 writes), 0.74 ms at the HBM peak, so at this size
+HBM bandwidth is not what bounds it.
 `docs/stokes_roofline.md` carries the traffic accounting.
 
 Structure (the radius-2 staggered four-field instance of the
@@ -85,7 +85,7 @@ from .diffusion_pallas import _wrap_dims, _wrap_set
 # grows with `_vmem_need` up to the hard cap (a fixed 32 MB budget failed
 # Mosaic's compile at 256^3).  At 256^3 the kernel runs bx=8 at 1.14 ms
 # per iteration on one TPU v5e (see the module docstring).
-from ._vmem import fit_bx, vmem_cap, vmem_limit
+from ._vmem import fit_bx, vmem_limit
 
 _BX_FULL = 8     # the slab height the kernel starts its fit from
 
@@ -110,14 +110,6 @@ def _fit_bx(bx: int, S0: int, S1: int, S2: int,
             check_vmem: bool = True) -> int:
     return fit_bx(_vmem_need, bx, S0, S1, S2, min_bx=4,
                   check_vmem=check_vmem)
-
-
-def full_slab_fits(S1: int, S2: int) -> bool:
-    """Whether the fused iteration's windows fit the VMEM cap at the full
-    slab height for a local y*z area `S1 x S2` (the same model in compiled
-    and interpret mode, so interpret meshes see the compiled ladder's
-    choice)."""
-    return _vmem_need(_BX_FULL, S1, S2) <= vmem_cap()
 
 
 def stokes_pallas_supported(grid, P, interpret: bool = False):

@@ -83,15 +83,13 @@ from __future__ import annotations
 
 from functools import partial
 
-from ._vmem import banded_vmem, chunk_budget, fit_banded, fit_chunk_K
-from .chunk_engine import (admit_banded_geometry, admit_chunk_common,
-                           admit_send_slabs, admit_sublane_extension,
-                           band_halo,
+from ._vmem import chunk_budget, fit_chunk_K
+from .chunk_engine import (admit_chunk_common, admit_send_slabs,
+                           admit_sublane_extension, band_halo,
                            dim_modes as _dim_modes, ext_shape as _ext_shape_e,
                            extend_dim_grouped, extend_fields, field_ols,
                            pad8 as _pad8, pad128 as _pad128,
-                           resident_chunk_call, run_chunks,
-                           streaming_chunk_call, window_chunk_xla,
+                           resident_chunk_call, run_chunks, window_chunk_xla,
                            wrap_edges as _wrap_edges)
 
 _BX = 8          # x band height of the chunk kernel (rows per program)
@@ -351,100 +349,6 @@ def fused_stokes_trapezoid_iters(P, Vx, Vy, Vz, Rho, *, n_inner: int,
         return _chunk_call(exts, Rho_ext, K=K, modes=modes, grid=grid,
                            scal=scal, ols=ols, shapes=shapes,
                            interpret=interpret)
-
-    *S, done = run_chunks((P, Vx, Vy, Vz), n_inner=n_inner, K=K,
-                          one_chunk=one)
-    return (*S, done)
-
-
-# ---------------------------------------------------------------------------
-# The STREAMING banded tier (stokes3d.banded): rolling-window realization
-# for the shapes the resident kernel's K-bound refuses
-# ---------------------------------------------------------------------------
-
-def stokes_banded_supported(grid, shape, K: int, n_inner: int, dtype,
-                            B: int = 8, interpret: bool = False):
-    """Whether the STREAMING banded Stokes chunk tier applies at depth
-    K / band B: the resident tier's structural gates minus the K-bound
-    — the rolling window (five staggered fields, Vx's high margin 2,
-    const Rho streamed per band) is O(B), so this rung admits at the
-    160^3+/256^3 shapes `fit_stokes_K` refuses.  Returns an
-    :class:`igg.degrade.Admission`."""
-    import numpy as np
-
-    from ..degrade import Admission
-
-    common = admit_chunk_common(grid, K, n_inner)
-    if common is not None:
-        return common
-    if grid.overlaps != (3, 3, 3):
-        return Admission.no(f"grid overlaps {grid.overlaps} != (3, 3, 3)")
-    if tuple(shape) != tuple(grid.nxyz):
-        return Admission.no(f"local shape {tuple(shape)} != grid block "
-                            f"{tuple(grid.nxyz)}")
-    if np.dtype(dtype) != np.float32:
-        return Admission.no(f"dtype {np.dtype(dtype)} is not float32")
-    modes = _dim_modes(grid)
-    E = 2 * K
-    shapes = _field_shapes(shape)
-    ols = _ols(grid, shapes)
-    slabs = admit_send_slabs(shapes, ols, E, modes, grid=grid)
-    if slabs is not None:
-        return slabs
-    geo = admit_banded_geometry(shapes, E, modes, B=B,
-                                extras=(1, 2, 1, 1, 1),
-                                interpret=interpret)
-    if geo is not None:
-        return geo
-    exts = [_ext_shape(s, E, modes) for s in shapes]
-    need = banded_vmem(exts, B, (1, 2, 1, 1, 1), 4, modes=modes,
-                      freeze_fields=(1, 2, 3))
-    if need > chunk_budget():
-        return Admission.no(f"banded window set {need} bytes exceeds "
-                            f"the VMEM budget {chunk_budget()}")
-    return Admission.yes()
-
-
-def fit_stokes_band(grid, shape, n_inner: int, dtype,
-                    interpret: bool = False, kmax: int = 8,
-                    bands=(8, 16)):
-    """Largest admissible `(K, B)` for the banded tier
-    (`_vmem.fit_banded`); None when none applies."""
-    return fit_banded(
-        lambda K, B: stokes_banded_supported(grid, tuple(shape), K,
-                                             n_inner, dtype, B=B,
-                                             interpret=interpret),
-        kmax, bands=bands)
-
-
-def fused_stokes_banded_iters(P, Vx, Vy, Vz, Rho, *, n_inner: int,
-                              K: int, B: int, dx, dy, dz, mu, dtP, dtV,
-                              interpret: bool = False):
-    """Advance `n_inner // K` full K-iteration chunks through the
-    STREAMING banded realization (`chunk_engine.streaming_chunk_call` —
-    same `_band_update` core and margins as the resident tier, rolling
-    VMEM window of band depth B, Rho streamed from its hoisted extended
-    buffer per band); returns `(P, Vx, Vy, Vz, iters_done)`.  Same
-    entry contract as :func:`fused_stokes_trapezoid_iters`."""
-    from .. import shared
-
-    grid = shared.global_grid()
-    modes = _dim_modes(grid)
-    E = 2 * K
-    shapes = _field_shapes(P.shape)
-    ols = _ols(grid, shapes)
-    scal = dict(dx=dx, dy=dy, dz=dz, mu=mu, dtP=dtP, dtV=dtV)
-
-    Rho_ext = extend_fields([Rho], [ols[4]], E, grid, modes)[0]
-
-    def one(P, Vx, Vy, Vz):
-        exts = extend_fields([P, Vx, Vy, Vz], ols[:4], E, grid, modes)
-        return streaming_chunk_call(
-            list(exts), [Rho_ext], K=K, B=B, modes=modes, grid=grid,
-            ols=ols, shapes=shapes, E=E,
-            band_update=partial(_band_update, scal=scal),
-            extras=(1, 2, 1, 1, 1), freeze_fields=(1, 2, 3),
-            interpret=interpret)
 
     *S, done = run_chunks((P, Vx, Vy, Vz), n_inner=n_inner, K=K,
                           one_chunk=one)

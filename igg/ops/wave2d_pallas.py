@@ -43,12 +43,10 @@ from __future__ import annotations
 
 from functools import partial
 
-from ._vmem import banded_vmem, chunk_budget, fit_banded, fit_chunk_K
-from .chunk_engine import (admit_banded_geometry, admit_chunk_common,
-                           admit_send_slabs, band_core_from_window,
-                           dim_modes, extend_fields, field_ols, run_chunks,
-                           streaming_chunk_call, whole_window_chunk_call,
-                           window_chunk_xla)
+from ._vmem import chunk_budget, fit_chunk_K
+from .chunk_engine import (admit_chunk_common, admit_send_slabs, dim_modes,
+                           extend_fields, field_ols, run_chunks,
+                           whole_window_chunk_call, window_chunk_xla)
 
 
 def _field_shapes(shape):
@@ -298,114 +296,6 @@ def fused_wave2d_chunk_steps(P, Vx, Vy, *, n_inner: int, K: int,
         exts = extend_fields([P, Vx, Vy], ols, E, grid, modes)
         return _chunk_call(exts, Kc=K, modes=modes, grid=grid, kw=kw,
                            ols=ols, shapes=shapes, interpret=interpret)
-
-    *S, done = run_chunks((P, Vx, Vy), n_inner=n_inner, K=K, one_chunk=one)
-    return (*S, done)
-
-
-# ---------------------------------------------------------------------------
-# The STREAMING banded tier (wave2d.banded)
-# ---------------------------------------------------------------------------
-
-# The coupled chain loses 2 rows of validity per side per iteration
-# (pressure reads fresh velocities which read the pressure at +-1), so
-# the band core's low margin is 2 and the per-field high margins are
-# `2 + x-stagger`: (P, Vx, Vy) -> (2, 3, 2).
-_BAND_LO = 2
-_BAND_EXTRAS = (2, 3, 2)
-
-
-def wave2d_banded_supported(grid, shape, K: int, n_inner: int, dtype,
-                            B: int = 8, interpret: bool = False):
-    """Whether the STREAMING banded wave2d chunk tier applies at depth
-    K / band B: the chunk tier's structural gates (periodic dims only,
-    2-D decomposition) minus the whole-window VMEM bound, plus the
-    banded geometry.  The compiled streaming kernel is 3-D only, so this
-    rung serves interpret meshes (the CPU contract rows); compiled TPU
-    configurations get the structured `admit_banded_geometry` refusal.
-    Returns an :class:`igg.degrade.Admission`."""
-    import numpy as np
-
-    from ..degrade import Admission
-
-    common = admit_chunk_common(grid, K, n_inner)
-    if common is not None:
-        return common
-    if grid.overlaps != (2, 2, 2):
-        return Admission.no(f"grid overlaps {grid.overlaps} != (2, 2, 2)")
-    if grid.dims[2] != 1 or grid.nxyz[2] != 1:
-        return Admission.no(f"grid is not a 2-D decomposition "
-                            f"(dims={tuple(grid.dims)}, nz={grid.nxyz[2]})")
-    if tuple(shape) != tuple(grid.nxyz[:2]):
-        return Admission.no(f"local shape {tuple(shape)} != grid block "
-                            f"{tuple(grid.nxyz[:2])}")
-    if np.dtype(dtype) != np.float32:
-        return Admission.no(f"dtype {np.dtype(dtype)} is not float32")
-    modes = dim_modes(grid)[:2]
-    if any(m in ("oext", "frozen") for m in modes):
-        return Admission.no(
-            f"open (non-periodic) dimensions {modes}: the wave2d chunk "
-            f"tiers serve periodic meshes only (the per-step tiers carry "
-            f"open boundaries)")
-    E = 2 * K
-    shapes = _field_shapes(shape)
-    ols = field_ols(grid, shapes)
-    slabs = admit_send_slabs(shapes, ols, E, modes, grid=grid)
-    if slabs is not None:
-        return slabs
-    geo = admit_banded_geometry(shapes, E, modes, B=B,
-                                extras=_BAND_EXTRAS, lo=_BAND_LO,
-                                interpret=interpret)
-    if geo is not None:
-        return geo
-    exts = [tuple(s[d] + (2 * E if modes[d] == "ext" else 0)
-                  for d in range(2)) for s in shapes]
-    need = banded_vmem(exts, B, _BAND_EXTRAS, 3, lo=_BAND_LO,
-                       modes=modes, freeze_fields=())
-    if need > chunk_budget():
-        return Admission.no(f"banded window set {need} bytes exceeds "
-                            f"the VMEM budget {chunk_budget()}")
-    return Admission.yes()
-
-
-def fit_wave2d_band(grid, shape, n_inner: int, dtype,
-                    interpret: bool = False, kmax: int = 8,
-                    bands=(8, 16)):
-    """Largest admissible `(K, B)` for the banded tier
-    (`_vmem.fit_banded`); None when none applies."""
-    return fit_banded(
-        lambda K, B: wave2d_banded_supported(grid, tuple(shape), K,
-                                             n_inner, dtype, B=B,
-                                             interpret=interpret),
-        kmax, bands=bands)
-
-
-def fused_wave2d_banded_steps(P, Vx, Vy, *, n_inner: int, K: int, B: int,
-                              dx, dy, dt, rho, bulk,
-                              interpret: bool = False):
-    """Advance `n_inner // K` full K-step chunks through the STREAMING
-    banded realization: the band core is derived from the coupled
-    full-window update by :func:`chunk_engine.band_core_from_window`
-    (margin loss 2 per iteration), swept over x-row bands with the
-    engine's rolling window.  Same entry contract as
-    :func:`fused_wave2d_chunk_steps`."""
-    from .. import shared
-
-    grid = shared.global_grid()
-    modes = dim_modes(grid)[:2]
-    E = 2 * K
-    shapes = _field_shapes(P.shape)
-    ols = field_ols(grid, shapes)
-    kw = dict(dx=dx, dy=dy, dt=dt, rho=rho, bulk=bulk)
-    band_update = band_core_from_window(_window_core(kw), _BAND_LO)
-
-    def one(P, Vx, Vy):
-        exts = extend_fields([P, Vx, Vy], ols, E, grid, modes)
-        return streaming_chunk_call(
-            list(exts), [], K=K, B=B, modes=modes, grid=grid, ols=ols,
-            shapes=shapes, E=E, band_update=band_update,
-            extras=_BAND_EXTRAS, freeze_fields=(), lo=_BAND_LO,
-            interpret=interpret)
 
     *S, done = run_chunks((P, Vx, Vy), n_inner=n_inner, K=K, one_chunk=one)
     return (*S, done)

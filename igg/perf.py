@@ -201,11 +201,7 @@ def bytes_per_step(family: str, tier: Optional[str], local_shape,
         return None
     if tier and ("trapezoid" in tier or tier.endswith(".chunk")):
         # Resident K-step chunk tiers read/write HBM once per K steps,
-        # so the per-step model does not apply.  The STREAMING `.banded`
-        # tier is deliberately NOT excluded: its rolling window
-        # re-streams every field once per iteration of the chunk (HBM
-        # ping-pong), so its amortized per-step traffic matches the
-        # ideal-fusion accesses model (docs/stokes_roofline.md).
+        # so the per-step model does not apply.
         return None
     try:
         itemsize = np.dtype(dtype).itemsize
@@ -1106,7 +1102,7 @@ def _main(argv: Sequence[str]) -> int:
                   f"{'' if len(entries) == 1 else 's'})"
                   + (f" vs prior {lpath}" if led else " (no ledger prior)"))
             header = (f"{'family':<12} {'local_shape':<14} {'tier':<22} "
-                      f"{'K':>3} {'bx':>3} {'band':>4} {'vmem':>5} "
+                      f"{'K':>3} {'bx':>3} {'vmem':>5} "
                       f"{'ms':>9}  prior (ledger best)")
             print(header)
             for e in sorted(entries, key=lambda e: (e["family"],
@@ -1122,7 +1118,6 @@ def _main(argv: Sequence[str]) -> int:
                 print(f"{e['family']:<12} {shape:<14} "
                       f"{e.get('tier') or '-':<22} "
                       f"{e.get('K') or '-':>3} {e.get('bx') or '-':>3} "
-                      f"{e.get('band') or '-':>4} "
                       f"{str(e.get('vmem_mb') or '-'):>5} "
                       f"{(e.get('ms') or 0):>9.4f}  {ptxt}")
             return 0

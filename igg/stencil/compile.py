@@ -43,16 +43,7 @@ def _requirements(name):
         f"dimension's block, and an extended working set within the VMEM "
         f"budget (igg.stencil.lower.chunk_supported_fn); use chunk='auto' "
         f"or the per-step tiers otherwise.")
-    banded_req = (
-        f"the streaming banded {name} spec chunk tier requires the fused "
-        f"per-step kernel's prerequisites plus: n_inner >= K+1, analyzer-"
-        f"admitted boundary conditions, banded geometry (band B >= 8, "
-        f"B % 8 == 0, extended x span divisible into >= 2 bands), E-deep "
-        f"send slabs inside every split dimension's block, and a rolling "
-        f"band window set within the VMEM budget "
-        f"(igg.stencil.lower.banded_supported_fn); use banded='auto' or "
-        f"the resident tiers otherwise.")
-    return pallas_req, chunk_req, banded_req
+    return pallas_req, chunk_req
 
 
 def _register_family(spec: StencilSpec, analysis, cf: Dict) -> None:
@@ -107,22 +98,11 @@ def _register_family(spec: StencilSpec, analysis, cf: Dict) -> None:
                        interpret=interpret):
                     out.append({"tier": f"{spec.name}.chunk", "K": K,
                                 "bx": None, "vmem_mb": None})
-            from .lower import banded_supported_fn
-
-            bsup = banded_supported_fn(spec, analysis)
-            for K in (4, 8):
-                for B in (8, 16):
-                    if bsup(grid, shape, K, n_inner - 1, np.float32, B=B,
-                            interpret=interpret):
-                        out.append({"tier": f"{spec.name}.banded", "K": K,
-                                    "bx": None, "vmem_mb": None,
-                                    "band": B})
             return out
 
         def build(cand, *, n_inner, params, interpret):
             tier = cand["tier"]
             fast = not tier.endswith(".xla")
-            is_banded = tier == f"{spec.name}.banded"
             fields = spec.init(cf, np.float32)
             step = compile(
                 spec, coeffs=cf, donate=False, n_inner=n_inner,
@@ -130,8 +110,7 @@ def _register_family(spec: StencilSpec, analysis, cf: Dict) -> None:
                 overlap=bool(cand.get("overlap")),
                 pallas_interpret=interpret,
                 chunk=(tier == f"{spec.name}.chunk"), K=cand.get("K"),
-                banded=(True if is_banded else False),
-                band=cand.get("band"), tune=False)
+                tune=False)
             return (lambda *fs: step(*fs)), tuple(fields)
 
         autotune.register_family(spec.name, candidates=candidates,
@@ -141,19 +120,14 @@ def _register_family(spec: StencilSpec, analysis, cf: Dict) -> None:
 def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
             donate: bool = True, n_inner: int = 1, use_pallas="auto",
             overlap="auto", pallas_interpret: bool = False, chunk="auto",
-            K: Optional[int] = None, banded="auto",
-            band: Optional[int] = None, verify=None, tune=None):
+            K: Optional[int] = None, verify=None, tune=None):
     """Compiled `(*fields) -> (*fields)` advancing `n_inner` steps in one
     SPMD program, dispatched through the spec's degradation ladder
-    (`{name}.chunk` → `{name}.banded` → `{name}.mosaic` → `{name}.xla`).
+    (`{name}.chunk` → `{name}.mosaic` → `{name}.xla`).
 
     `coeffs` binds the spec's scalar Params (declared defaults fill the
     rest); the remaining knobs carry the model-factory contract verbatim
     — `use_pallas` "auto"/True/False, `chunk`/`K` for the K-step tier,
-    `banded`/`band` for the STREAMING banded chunk tier
-    (`igg.stencil.lower.spec_banded_steps` — rolling VMEM window of
-    band depth B, HBM ping-pong; "auto" engages it only where the
-    resident chunk tier's `fit_spec_K` refuses),
     `overlap` "auto"/True/False to restructure the generated XLA
     composition with `igg.hide_communication` (the analyzer's read-set
     radius drives the admission for free: a spec whose
@@ -178,20 +152,18 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
         raise GridError(f"igg.stencil.compile({spec.name!r}): {adm.reason}")
     analysis = analyze(spec)
     cf = spec.coeffs(coeffs)
-    pallas_req, chunk_req, banded_req = _requirements(spec.name)
+    pallas_req, chunk_req = _requirements(spec.name)
 
     _register_family(spec, analysis, cf)
 
-    (K, K_from_cache, band, band_from_cache, chunk, banded, use_pallas,
-     tuned) = apply_tuned(
+    K, K_from_cache, chunk, use_pallas, tuned = apply_tuned(
         spec.name, tune, n_inner=n_inner, interpret=pallas_interpret, K=K,
-        chunk_knob=chunk, use_pallas=use_pallas, band=band,
-        banded_knob=banded)
+        chunk_knob=chunk, use_pallas=use_pallas,
+        tiers=("chunk", "mosaic", "xla"))
     radius = max(analysis.halo_radius) if analysis.halo_radius else 1
     overlap = resolve_overlap(overlap, family=spec.name, tuned=tuned,
                               radius=radius, ndim=spec.ndim,
-                              chunk_active=(chunk is True
-                                            or banded is True))
+                              chunk_active=chunk is True)
 
     local_step = lower.local_step_fn(spec, cf)
 
@@ -215,14 +187,11 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
 
     if chunk is True and use_pallas is False:
         raise GridError(chunk_req)
-    if banded is True and use_pallas is False:
-        raise GridError(banded_req)
-    if chunk is True or banded is True:
-        use_pallas = True      # the chunk tiers ride the fused kernel
+    if chunk is True:
+        use_pallas = True      # the chunk tier rides the fused kernel
 
     mosaic_supported = lower.mosaic_supported_fn(spec)
     chunk_supported = lower.chunk_supported_fn(spec, analysis)
-    banded_supported = lower.banded_supported_fn(spec, analysis)
 
     def _base_shape(lshape):
         return tuple(lshape[d] - spec.fields[0].stagger[d]
@@ -240,22 +209,6 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
                                      n_inner - 1, dtype,
                                      interpret=pallas_interpret))
 
-    def _fit_band(grid, lshape, dtype):
-        from ..models._dispatch import resolve_band
-
-        base = _base_shape(lshape)
-        if banded is False or n_inner < 3:
-            return None
-        return resolve_band(
-            K, band, K_from_cache or band_from_cache,
-            lambda k, b: banded_supported(grid, base, k, n_inner - 1,
-                                          dtype, B=b,
-                                          interpret=pallas_interpret),
-            lambda bands: lower.fit_spec_band(spec, analysis, grid, base,
-                                              n_inner - 1, dtype,
-                                              interpret=pallas_interpret,
-                                              bands=bands))
-
     def admit_chunk(args):
         from ..degrade import Admission
 
@@ -263,9 +216,6 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
             return Admission.no("use_pallas=False pins the XLA path")
         if chunk is False:
             return Admission.no("chunk=False pins the per-step tiers")
-        if banded is True:
-            return Admission.no("banded=True pins the streaming banded "
-                                "tier")
         base = pallas_applicable("auto", args[0],
                                  supported_fn=mosaic_supported,
                                  requirement=pallas_req,
@@ -311,68 +261,6 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
         return igg.sharded(chunk_steps, donate_argnums=donate_argnums,
                            check_vma=not pallas_interpret)
 
-    def admit_banded(args):
-        from ..degrade import Admission
-
-        if use_pallas is False:
-            return Admission.no("use_pallas=False pins the XLA path")
-        if banded is False:
-            return Admission.no("banded=False pins the resident tiers")
-        base = pallas_applicable("auto", args[0],
-                                 supported_fn=mosaic_supported,
-                                 requirement=pallas_req,
-                                 interpret=pallas_interpret)
-        if not base:
-            return Admission.no(f"fused per-step kernel (the banded "
-                                f"tier's carrier) inadmissible: "
-                                f"{getattr(base, 'reason', '')}")
-        if n_inner < 3:
-            return Admission.no(f"n_inner={n_inner} < 3: no warm-up plus "
-                                f"full chunk fits")
-        grid = igg.get_global_grid()
-        A = args[0]
-        lshape = grid.local_shape_any(A)
-        if banded == "auto":
-            if chunk is False:
-                return Admission.no("chunk=False pins the per-step tiers "
-                                    "(pass banded=True to require the "
-                                    "streaming tier)")
-            if _fit_K(grid, lshape, A.dtype):
-                return Admission.no(
-                    "the resident chunk tier serves this shape (the "
-                    "banded rung engages where fit_spec_K refuses)")
-        if not _fit_band(grid, lshape, A.dtype):
-            return Admission.no(
-                "no banded config (K, B) admissible "
-                "(igg.stencil.lower.banded_supported_fn)")
-        return Admission.yes()
-
-    def build_banded():
-        def banded_steps(*fields):
-            grid = igg.get_global_grid()
-            kb = _fit_band(grid, fields[0].shape, fields[0].dtype)
-            if not kb:     # admission gate and trace share _fit_band
-                raise GridError(banded_req)
-            Kf, Bf = kb
-            # Warm-up per-step kernel: the exchange-fresh entry state
-            # the chunk validity argument requires (the chunk contract).
-            S = lower.fused_spec_step(spec, cf, fields,
-                                      interpret=pallas_interpret)
-            *S, done = lower.spec_banded_steps(
-                spec, analysis, cf, S, n_inner=n_inner - 1, K=Kf, B=Bf,
-                interpret=pallas_interpret)
-            n = n_inner - 1 - done
-            if n:          # remainder through the per-step kernel
-                S = lax.fori_loop(
-                    0, n,
-                    lambda _, T: tuple(lower.fused_spec_step(
-                        spec, cf, T, interpret=pallas_interpret)),
-                    tuple(S))
-            return tuple(S)
-
-        return igg.sharded(banded_steps, donate_argnums=donate_argnums,
-                           check_vma=not pallas_interpret)
-
     def build_pallas_steps():
         def pallas_steps(*fields):
             return lower.fused_spec_steps(spec, cf, fields,
@@ -386,13 +274,10 @@ def compile(spec: StencilSpec, *, coeffs: Optional[Dict] = None,
     chunk_tier = Tier(name=f"{spec.name}.chunk", rung=0, build=build_chunk,
                       admit=admit_chunk, required=chunk is True,
                       requirement=chunk_req)
-    banded_tier = Tier(name=f"{spec.name}.banded", rung=0,
-                       build=build_banded, admit=admit_banded,
-                       required=banded is True, requirement=banded_req)
     return auto_dispatch(
         use_pallas=use_pallas, interpret=pallas_interpret,
         supported_fn=mosaic_supported, requirement=pallas_req,
         xla_path=xla_path, build_pallas_steps=build_pallas_steps,
         donate_argnums=donate_argnums,
         family=spec.name, verify=verify,
-        extra_tiers=(chunk_tier, banded_tier))
+        extra_tiers=(chunk_tier,))

@@ -42,8 +42,7 @@ from .spec import (BinOp, Const, Expr, ParamRef, Read, StencilSpec, UnOp,
 
 __all__ = ["apply_updates", "local_step_fn", "fused_spec_step",
            "spec_chunk_steps", "mosaic_supported_fn", "chunk_supported_fn",
-           "fit_spec_K", "whole_block_vmem", "banded_supported_fn",
-           "fit_spec_band", "spec_banded_steps"]
+           "fit_spec_K", "whole_block_vmem"]
 
 _OPS = {
     "add": lambda a, b: a + b,
@@ -362,142 +361,6 @@ def spec_chunk_steps(spec: StencilSpec, analysis: Analysis, coeffs, fields,
                 tuple(exts), K=K, E=E, modes=modes, grid=grid, ols=ols,
                 shapes=shapes, freeze_fields=freeze, core=core),
             interpret=interpret)
-
-    *S, done = run_chunks(tuple(fields), n_inner=n_inner, K=K,
-                          one_chunk=one)
-    return (*S, done)
-
-
-# ---------------------------------------------------------------------------
-# STREAMING banded chunk tier (the generated `<spec>.banded` rung)
-# ---------------------------------------------------------------------------
-
-def _band_margins(spec: StencilSpec, analysis: Analysis):
-    """The banded scheme's read margins for a spec: the low margin is
-    the analyzer's one-iteration validity loss (so
-    `band_core_from_window` slices rows at full validity distance from
-    both window edges), the per-field high margins add the x-stagger."""
-    lo = analysis.margin_after(1)
-    extras = tuple(lo + f.stagger[0] for f in spec.fields)
-    return lo, extras
-
-
-def banded_supported_fn(spec: StencilSpec, analysis: Analysis):
-    """`supported(grid, shape, K, n_inner, dtype, B=8, interpret=False)`
-    for the generated STREAMING banded chunk tier: the chunk tier's
-    structural gates minus the whole-window VMEM bound (the rolling
-    window is O(B) — this rung admits where :func:`fit_spec_K`'s
-    resident accounting refuses), plus the engine's banded geometry
-    (`chunk_engine.admit_banded_geometry`) at the analyzer-computed
-    margins."""
-    import numpy as np
-
-    from ..degrade import Admission
-    from ..ops._vmem import banded_vmem, chunk_budget
-    from ..ops.chunk_engine import (admit_banded_geometry,
-                                    admit_chunk_common, admit_send_slabs,
-                                    dim_modes, field_ols)
-
-    def supported(grid, shape, K, n_inner, dtype, B: int = 8,
-                  interpret: bool = False):
-        nd = spec.ndim
-        common = admit_chunk_common(grid, K, n_inner)
-        if common is not None:
-            return common
-        if grid.overlaps[:nd] != (2,) * nd:
-            return Admission.no(f"grid overlaps {grid.overlaps} != 2 on "
-                                f"the spec's {nd} dims")
-        if nd == 2 and (grid.dims[2] != 1 or grid.nxyz[2] != 1):
-            return Admission.no(
-                f"grid is not a 2-D decomposition "
-                f"(dims={tuple(grid.dims)}, nz={grid.nxyz[2]})")
-        if tuple(shape) != tuple(grid.nxyz[:nd]):
-            return Admission.no(f"local shape {tuple(shape)} != grid "
-                                f"block {tuple(grid.nxyz[:nd])}")
-        if np.dtype(dtype) != np.float32:
-            return Admission.no(f"dtype {np.dtype(dtype)} is not float32")
-        modes = dim_modes(grid)[:nd]
-        if any(m in ("oext", "frozen") for m in modes) \
-                and not analysis.open_chunk_ok(K):
-            return Admission.no(
-                f"open (non-periodic) dimensions {modes}: the analyzer's "
-                f"boundary-validity recurrence refuses the plane-freeze "
-                f"chunk evolution for spec {spec.name!r} (a "
-                f"boundary-adjacent read would land on shoulder garbage); "
-                f"the per-step tiers carry open boundaries")
-        E = analysis.margin_after(K)
-        shapes = _field_shapes(spec, tuple(shape))
-        ols = field_ols(grid, shapes)
-        slabs = admit_send_slabs(shapes, ols, E, modes, grid=grid)
-        if slabs is not None:
-            return slabs
-        lo, extras = _band_margins(spec, analysis)
-        geo = admit_banded_geometry(shapes, E, modes, B=B, extras=extras,
-                                    lo=lo, interpret=interpret)
-        if geo is not None:
-            return geo
-        freeze = {d: analysis.freeze[d] for d in range(nd)}
-        exts = [tuple(s[d] + (2 * E if modes[d] in ("ext", "oext") else 0)
-                      for d in range(nd)) for s in shapes]
-        need = banded_vmem(exts, B, extras, len(shapes), lo=lo,
-                           modes=modes, freeze_fields=freeze)
-        if need > chunk_budget():
-            return Admission.no(f"banded window set {need} bytes exceeds "
-                                f"the VMEM budget {chunk_budget()}")
-        return Admission.yes()
-
-    return supported
-
-
-def fit_spec_band(spec, analysis, grid, shape, n_inner, dtype,
-                  interpret: bool = False, kmax: int = 8, bands=(8, 16)):
-    """Largest admissible `(K, B)` for the banded tier
-    (`_vmem.fit_banded`); None when none applies."""
-    from ..ops._vmem import fit_banded
-
-    sup = banded_supported_fn(spec, analysis)
-    return fit_banded(
-        lambda K, B: sup(grid, tuple(shape), K, n_inner, dtype, B=B,
-                         interpret=interpret), kmax, bands=bands)
-
-
-def spec_banded_steps(spec: StencilSpec, analysis: Analysis, coeffs,
-                      fields, *, n_inner: int, K: int, B: int,
-                      interpret: bool = False):
-    """Advance `n_inner // K` full K-step chunks through the STREAMING
-    banded realization (`chunk_engine.streaming_chunk_call`): the band
-    core is derived from the spec's update-chain evaluator by
-    :func:`chunk_engine.band_core_from_window` at the analyzer's
-    one-iteration margin, swept over x-row bands with a rolling VMEM
-    window instead of the whole extended block.  Same entry contract as
-    :func:`spec_chunk_steps`."""
-    from .. import shared
-    from ..ops.chunk_engine import (band_core_from_window, dim_modes,
-                                    extend_fields, field_ols, run_chunks,
-                                    streaming_chunk_call)
-
-    grid = shared.global_grid()
-    nd = spec.ndim
-    modes = dim_modes(grid)[:nd]
-    E = analysis.margin_after(K)
-    shapes = _field_shapes(spec, tuple(fields[0].shape[d] -
-                                       spec.fields[0].stagger[d]
-                                       for d in range(nd)))
-    ols = field_ols(grid, shapes)
-    freeze = {d: analysis.freeze[d] for d in range(nd)}
-    lo, extras = _band_margins(spec, analysis)
-
-    def core(*windows):
-        return apply_updates(spec, windows, coeffs)
-
-    band_update = band_core_from_window(core, lo)
-
-    def one(*S):
-        exts = extend_fields(list(S), ols, E, grid, modes)
-        return streaming_chunk_call(
-            list(exts), [], K=K, B=B, modes=modes, grid=grid, ols=ols,
-            shapes=shapes, E=E, band_update=band_update, extras=extras,
-            freeze_fields=freeze, lo=lo, interpret=interpret)
 
     *S, done = run_chunks(tuple(fields), n_inner=n_inner, K=K,
                           one_chunk=one)

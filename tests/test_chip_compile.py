@@ -228,9 +228,8 @@ def stokes_trapezoid_4x1(topo):
 def stokes_mosaic_256_periodic(topo):
     """The rung `stokes3d.make_iteration`'s ladder serves for the Stokes
     cell's 256^3 periodic one-chip block: the per-iteration fused kernel
-    (the resident chunk tier's K-bound refuses the shape, and the banded
-    rung stays off while this kernel keeps its full slab height).  The
-    local program is the ladder's own."""
+    (the resident chunk tier's K-bound refuses the shape).  The local
+    program is the ladder's own."""
     import jax
 
     from igg.models import stokes3d
@@ -242,7 +241,6 @@ def stokes_mosaic_256_periodic(topo):
     P = (jax.ShapeDtypeStruct(g, np.float32),)
     tiers = {t.name: t for t in ladder.tiers}
     assert not tiers["stokes3d.trapezoid"].admit(P)
-    assert not tiers["stokes3d.banded"].admit(P)
     assert tiers["stokes3d.mosaic"].admit(P)
     local = tiers["stokes3d.mosaic"].build().__wrapped__
     stag = [tuple(n + (d == i) for d in range(3)) for i in range(3)]

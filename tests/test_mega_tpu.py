@@ -738,88 +738,6 @@ def test_wave2d_mosaic_compiled_matches_xla(periods):
     igg.finalize_global_grid()
 
 
-def test_hm3d_banded_compiled_at_256_where_resident_refuses():
-    """Round 18, the tentpole's headline claim ON HARDWARE: at 256^3 f32
-    single-device the resident chunk window's working set exceeds the
-    VMEM budget (`fit_hm3d_K` == 0), and the STREAMING banded rung —
-    x-row band sweeps through a rolling VMEM window with HBM ping-pong —
-    serves the chunk tier there anyway, matching the XLA composition."""
-    import jax.numpy as jnp
-
-    from igg.models import hm3d
-    from igg.ops.hm3d_trapezoid import fit_hm3d_K
-
-    igg.init_global_grid(256, 256, 256, dimx=1, dimy=1, dimz=1,
-                         periodx=1, periody=1, periodz=1, quiet=True)
-    grid = igg.get_global_grid()
-    assert fit_hm3d_K(grid, (256, 256, 256), 8, np.float32) == 0
-    params = hm3d.Params()
-    Pe, phi = hm3d.init_fields(params, dtype=np.float32)
-    ref = hm3d.make_step(params, donate=False, n_inner=5,
-                         use_pallas=False)
-    band = hm3d.make_step(params, donate=False, n_inner=5, banded=True,
-                          K=4, band=8)
-    r = ref(Pe, phi)
-    o = band(Pe, phi)
-    assert igg.degrade.active().get("hm3d") == "hm3d.banded"
-    for name, a, b in zip(("Pe", "phi"), r, o):
-        scale = float(jnp.max(jnp.abs(a))) + 1e-30
-        rel = float(jnp.max(jnp.abs(a - b))) / scale
-        assert rel < 1e-4, (name, rel)
-    igg.finalize_global_grid()
-
-
-def test_stokes_banded_compiled_at_256_where_resident_refuses():
-    """Same headline claim for the staggered family: 256^3 f32 Stokes,
-    where the resident window refuses (`fit_stokes_K` == 0), through the
-    compiled banded rung vs the per-iteration fused kernel."""
-    import jax.numpy as jnp
-
-    from igg.models import stokes3d
-    from igg.ops.stokes_trapezoid import fit_stokes_K
-
-    igg.init_global_grid(256, 256, 256, dimx=1, dimy=1, dimz=1,
-                         periodx=1, periody=1, periodz=1,
-                         overlapx=3, overlapy=3, overlapz=3, quiet=True)
-    grid = igg.get_global_grid()
-    assert fit_stokes_K(grid, (256, 256, 256), 8, np.float32) == 0
-    params = stokes3d.Params()
-    P, Vx, Vy, Vz, Rho = stokes3d.init_fields(params, dtype=np.float32)
-    pre = stokes3d.make_iteration(params, donate=False, n_inner=3,
-                                  trapezoid=False)
-    P, Vx, Vy, Vz = pre(P, Vx, Vy, Vz, Rho)
-    ref = stokes3d.make_iteration(params, donate=False, n_inner=5,
-                                  use_pallas=False)
-    band = stokes3d.make_iteration(params, donate=False, n_inner=5,
-                                   banded=True, K=4, band=8)
-    r = ref(P, Vx, Vy, Vz, Rho)
-    o = band(P, Vx, Vy, Vz, Rho)
-    assert igg.degrade.active().get("stokes3d") == "stokes3d.banded"
-    for name, a, b in zip(("P", "Vx", "Vy", "Vz"), r, o):
-        scale = float(jnp.max(jnp.abs(a))) + 1e-30
-        rel = float(jnp.max(jnp.abs(a - b))) / scale
-        # f32 reassociation through the PT chain (see
-        # tests/test_chunk_engine.py::test_stokes_banded_matches_xla_staggered).
-        assert rel < 5e-4, (name, rel)
-    igg.finalize_global_grid()
-
-
-def test_wave2d_banded_refused_when_compiled():
-    """The streaming banded kernel is 3-D only, so the 2-D banded rung
-    serves interpret meshes alone: required on the chip, it refuses with
-    its structured reason (`wave2d_banded_supported`) and nothing runs."""
-    from igg.models import wave2d
-
-    igg.init_global_grid(512, 512, 1, periodx=1, periody=1, quiet=True)
-    params = wave2d.Params()
-    fields = wave2d.init_fields(params, dtype=np.float32)
-    band = wave2d.make_step(params, donate=False, n_inner=5, banded=True,
-                            K=4, band=8)
-    with pytest.raises(igg.GridError, match="3-D-only"):
-        band(*fields)
-    igg.finalize_global_grid()
-
-
 def test_wave2d_chunk_compiled_matches_per_step():
     """The K-step wave2d chunk kernel (compiled whole-window resident
     program, `igg.ops.wave2d_pallas._chunk_kernel`) against the per-step

@@ -246,7 +246,7 @@ def test_pingpong_matches_sequential_bitwise(grid, n, entry):
     if entry == "make_iteration":
         it = stokes3d.make_iteration(params, n_inner=n, donate=True,
                                      use_pallas=True, pallas_interpret=True,
-                                     trapezoid=False, banded=False)
+                                     trapezoid=False)
         got = it(*S, Rho)
         assert it.ladder.tiers[0].name == "stokes3d.trapezoid"
         assert igg.degrade.active()["stokes3d"] == "stokes3d.mosaic"
@@ -267,7 +267,7 @@ def test_pingpong_counter_per_dispatch(selfwrap_grid):
     n_inner = 5
     it = stokes3d.make_iteration(n_inner=n_inner, donate=False,
                                  use_pallas=True, pallas_interpret=True,
-                                 trapezoid=False, banded=False)
+                                 trapezoid=False)
     labels = dict(family="stokes3d", tier="stokes3d.mosaic")
     pingpong = telemetry.counter("igg_pingpong_iterations_total", **labels)
     dispatches = telemetry.counter("igg_tier_dispatch_total", **labels)
