@@ -64,7 +64,8 @@ periodicity — periodic dims self-wrapped or extended, open dims extended
 with per-device edge freezing (the reference-default boundary condition)
 — 0.29 ms/step on the `(N,1,1)` pod decomposition, 0.40 on `(N,M,1)`
 with both dims extended; one K-deep slab ppermute pair per exchanged dim
-per K steps) > the per-step kernel above (any mesh, 0.52 ms/step;
+per K steps; where no mesh edge is open, each chunk is one wavefront
+sweep of its extended window) > the per-step kernel above (any mesh, 0.52 ms/step;
 `benchmarks/results/pallas_sweep.jsonl`).
 """
 

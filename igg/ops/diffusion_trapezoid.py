@@ -24,21 +24,32 @@ torus, the v5p BASELINE topology:
      `/root/reference/src/update_halo.jl:36,130`).  z slabs ride the wire
      TRANSPOSED (z on the sublane axis) so nothing lane-padded
      materializes.
-  2. ONE `pallas_call` advances K steps on the extended window (same
-     VMEM-resident coefficient, HBM ping-pong, and hand double-buffered DMA
-     as the mega-kernel; wrap dims keep their in-VMEM self-wrap aliases).
+  2. ONE `pallas_call` advances K steps on the extended window.  Where no
+     dim is "oext" and `diffusion_mega.wavefront_plan` admits the window,
+     it is the mega kernel's wavefront (`diffusion3d_trapezoid_wavefront`):
+     one HBM sweep advances D steps (D = K = 8 on the 2x2 mesh's 272 x 272
+     x 256 window), in place in the window's buffer.  An extended dim's
+     end rows are shoulders whose values never reach the central window,
+     so the wavefront holds them fixed (its "frozen" mode); wrap and
+     frozen dims keep their modes.  Otherwise it is the one-step-per-sweep
+     kernel below (`diffusion3d_trapezoid_chunk`: VMEM-resident
+     coefficient, HBM ping-pong, hand double-buffered DMA, in-VMEM
+     self-wrap aliases, per-device freeze planes for open mesh edges).
      Each step the outermost rows of every extended dimension lose
      validity — after K steps exactly the device's own block (interior AND
      halo rows) carries the values the per-step path would produce,
      bit-for-bit, because every row is updated by the identical stencil
-     arithmetic the neighbor would apply.
-  3. The final step's programs write only that central window to the
-     output; the garbage shoulders are never materialized outside the
-     ping-pong scratch.
+     arithmetic the neighbor would apply; the two kernels differ only in
+     the order of the rows.
+  3. The central window is sliced out of the evolved window (the wavefront)
+     or written alone by the last step's programs (the one-step kernel,
+     whose garbage shoulders stay in its ping-pong scratch).
 
-Per-chunk overhead vs the ideal: the concat (one extended-buffer write) and
-the redundant shoulder compute (`2K/S` per extended dim) — both amortized
-by K.
+Per-chunk overhead vs the ideal: the concat (one extended-buffer write), the
+central-window slice, and the redundant shoulder compute (`2K/S` per
+extended dim) — all amortized by K.  The wavefront reads and writes the
+window once a chunk (the one-step kernel once a step: 170 MB a step at
+272 x 272 x 256 f32).
 
 Validity needs fresh data beyond each extended end.  Periodic rings
 provide it by neighbor slabs (`periods[d]`, any `dims[d] >= 1` — on one
@@ -96,8 +107,10 @@ from functools import partial
 
 from ._vmem import chunk_budget
 from .chunk_engine import (admit_chunk_common, central_window, dim_modes,
-                           edge_flags, extend_dim_grouped, extend_fields,
-                           freeze_open_dim, run_chunks, wrap_edges)
+                           edge_flags, ext_shape, extend_dim_grouped,
+                           extend_fields, freeze_open_dim, pad128,
+                           run_chunks, wrap_edges)
+from .diffusion_mega import _wavefront_steps, wavefront_plan
 from .diffusion_pallas import _u_rows
 
 # Engine aliases (the historical private names, still used by tests and
@@ -460,10 +473,40 @@ def _window_steps_xla(Text, A_ext, *, K, modes, grid, rdx2, rdy2, rdz2):
     return lax.fori_loop(0, K, step, Text)
 
 
+def _window_shape(shape, K, modes):
+    """The chunk's extended window as its kernels see it: +2K along every
+    extended dim, the lane extent of an extended z padded to 128."""
+    S = list(ext_shape(shape, K, modes))
+    if modes[2] in ("ext", "oext"):
+        S[2] = pad128(S[2])
+    return tuple(S)
+
+
+def _wavefront_modes(modes):
+    """The wavefront kernel's halo modes for a chunk's window: an
+    extended dim's end rows are shoulders, held fixed ("frozen") as they
+    never reach the central window; wrap and frozen dims keep theirs.
+    None where a dim is "oext": open mesh edges re-freeze per device
+    (`axis_index` flags), which the wavefront kernel does not do."""
+    if "oext" in modes:
+        return None
+    return tuple("frozen" if m == "ext" else m for m in modes)
+
+
+def _wavefront_plan(window_shape, K, modes, dtype):
+    """`(D, bx)` where the chunk's K steps run as wavefront sweeps of its
+    window (`diffusion_mega.wavefront_plan`), else 0."""
+    wmodes = _wavefront_modes(modes)
+    return wmodes and wavefront_plan(window_shape, K, wmodes, dtype)
+
+
 def _chunk_call(Text, A_ext, out_shape3, *, K, bx, modes, grid,
-                rdx2, rdy2, rdz2, interpret=False):
+                rdx2, rdy2, rdz2, interpret=False, _per_step_sweep=False):
     """Advance K steps on the extended buffer; returns the central
-    `out_shape3` window."""
+    `out_shape3` window.  The compiled chunk runs as wavefront sweeps
+    where `_wavefront_plan` admits its window, else (or with
+    `_per_step_sweep`, for the equivalence tests) as the one-step-per-
+    sweep kernel."""
     import jax
     from jax import lax
     from jax.experimental import pallas as pl
@@ -489,6 +532,22 @@ def _chunk_call(Text, A_ext, out_shape3, *, K, bx, modes, grid,
         Text = jnp.pad(Text, pad)
         A_ext = jnp.pad(A_ext, pad)
         S2e = S2p
+    plan = (not _per_step_sweep) and _wavefront_plan(Text.shape, K, modes,
+                                                     Text.dtype)
+    if plan:
+        # D steps per HBM sweep, in place in the window's buffer; the
+        # shoulders' end rows pass through, which keeps the validity front
+        # at one row a step, so the central window equals the one-step
+        # kernel's below.
+        D, wbx = plan
+        run = partial(_wavefront_steps, bx=wbx, rdx2=rdx2, rdy2=rdy2,
+                      rdz2=rdz2, modes=_wavefront_modes(modes),
+                      force_streamed=False,
+                      name="diffusion3d_trapezoid_wavefront")
+        out = run(Text, A_ext, sweeps=K // D, D=D)
+        if K % D:
+            out = run(out, A_ext, sweeps=1, D=K % D)
+        return central_window(out, out_shape3, K, modes)
     assert K == bx, "chunk depth is pinned to the block row count"
     nbe = S0e // bx
     nbo = S0 // bx
@@ -566,12 +625,18 @@ def _chunk_call(Text, A_ext, out_shape3, *, K, bx, modes, grid,
 def fused_diffusion_trapezoid_steps(T, A, *, n_inner: int, bx: int,
                                     grid, rdx2, rdy2, rdz2,
                                     force_y_ext=None, force_z_ext=None,
-                                    interpret=False):
+                                    interpret=False, _per_step_sweep=False):
     """Advance `n_inner` steps in chunks of K=bx trapezoidal kernel calls
     (plus a per-step remainder handled by the caller; this function runs
     only the `n_inner // bx` full chunks and returns `(T, steps_done)`).
     `force_y_ext`/`force_z_ext` override the mesh-derived modes
-    (benchmarking the `(N,M,K)` program shapes on a 1-device self-torus)."""
+    (benchmarking the `(N,M,K)` program shapes on a 1-device self-torus).
+    Each dispatch of the program being traced counts the steps its
+    chunks ran as wavefront sweeps in `igg_chunk_wavefront_steps_total`
+    (`igg.parallel.count_per_dispatch`); `_per_step_sweep` pins the
+    one-step-per-sweep kernel, for the equivalence tests."""
+    from ..parallel import count_per_dispatch
+
     K = bx
     shape = T.shape
     modes = _dim_modes(grid, force_y_ext, force_z_ext)
@@ -581,7 +646,11 @@ def fused_diffusion_trapezoid_steps(T, A, *, n_inner: int, bx: int,
         Text = _extend(T, K, grid, shape, modes)
         return (_chunk_call(Text, A_ext, shape, K=K, bx=bx, modes=modes,
                             grid=grid, rdx2=rdx2, rdy2=rdy2, rdz2=rdz2,
-                            interpret=interpret),)
+                            interpret=interpret,
+                            _per_step_sweep=_per_step_sweep),)
 
     T, done = run_chunks((T,), n_inner=n_inner, K=K, one_chunk=one)
+    if not (interpret or _per_step_sweep) and _wavefront_plan(
+            _window_shape(shape, K, modes), K, modes, T.dtype):
+        count_per_dispatch("igg_chunk_wavefront_steps_total", done)
     return T, done

@@ -388,6 +388,11 @@ _BUILTIN_HELP: Dict[str, str] = {
                                      "partner state set (an HBM "
                                      "ping-pong) instead of fresh "
                                      "buffers.",
+    "igg_chunk_wavefront_steps_total": "Steps of the ladder's dispatches, "
+                                       "per (family, tier), that K-step "
+                                       "chunks ran as wavefront sweeps "
+                                       "(D steps per HBM sweep of the "
+                                       "extended window).",
     "igg_tier_quarantined_total": "Kernel tiers quarantined by the "
                                   "degradation ladder.",
     "igg_dispatch_host_seconds": "Host seconds of a ladder dispatch that "

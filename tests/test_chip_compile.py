@@ -186,13 +186,28 @@ def perstep_with_halo_writer(topo):
 
 
 def diffusion_trapezoid_2x2(topo):
-    """`--chips 4`: the K-step trapezoid chunks with slab ppermutes."""
+    """`--chips 4`: the K-step trapezoid chunks with slab ppermutes, each
+    chunk one wavefront sweep of its extended window, beside the warm-up
+    and remainder steps' per-step kernel.  The trace counts the steps the
+    chunks ran (`count_per_dispatch`): 12 chunks of 8 of the 99 after the
+    warm-up step."""
+    from igg import parallel
     from igg.ops.diffusion_trapezoid import trapezoid_supported
 
     g = _grid(topo, (256,) * 3, (2, 2, 1), periodic=True)
     assert trapezoid_supported(igg.get_global_grid(), (256,) * 3, 8,
                                N_INNER - 1, np.float32, allow_open=True)
-    return _compile(_diffusion_steps(N_INNER), [g, g], igg.spec_for(3))
+    counts = {}
+    parallel._tracing.append(counts)
+    try:
+        compiled = _compile(_diffusion_steps(N_INNER), [g, g],
+                            igg.spec_for(3))
+    finally:
+        parallel._tracing.pop()
+    assert counts == {"igg_chunk_wavefront_steps_total": 96}
+    assert _kernels(compiled) == {"diffusion3d_trapezoid_wavefront",
+                                  "diffusion3d_step"}
+    return compiled
 
 
 def stokes_trapezoid_4x1(topo):

@@ -57,10 +57,14 @@ def test_mega_matches_per_step_kernel():
     assert float(jnp.max(jnp.abs(out - ref))) <= 4e-7 * scale
 
 
-def test_trapezoid_matches_per_step_kernel():
+@pytest.mark.parametrize("per_step_sweep", [False, True],
+                         ids=["wavefront", "one_step_sweep"])
+def test_trapezoid_matches_per_step_kernel(per_step_sweep):
     """The K-step trapezoidal chunk kernel (x-exchanged ring; here the
     1-device self-ring) must match K applications of the per-step fused
-    kernel on the same block."""
+    kernel on the same block, as wavefront sweeps of the extended window
+    and as one HBM sweep a step; the two agree bit for bit (identical
+    arithmetic per cell, only the order of the rows changes)."""
     import jax
     import jax.numpy as jnp
 
@@ -84,10 +88,16 @@ def test_trapezoid_matches_per_step_kernel():
     bx = 8
     assert trapezoid_supported(grid, T.shape, bx, 2 * bx, T.dtype)
 
-    out, done = jax.jit(
-        lambda T, A: fused_diffusion_trapezoid_steps(
-            T, A, n_inner=2 * bx, bx=bx, grid=grid, **scal))(T, A)
+    def chunks(pin):
+        return jax.jit(
+            lambda T, A: fused_diffusion_trapezoid_steps(
+                T, A, n_inner=2 * bx, bx=bx, grid=grid, **scal,
+                _per_step_sweep=pin))(T, A)
+
+    out, done = chunks(per_step_sweep)
     assert done == 2 * bx
+    twin, _ = chunks(not per_step_sweep)
+    assert np.array_equal(np.asarray(out), np.asarray(twin))
 
     from igg.ops import fused_diffusion_step
     dt = params.timestep()
@@ -98,6 +108,37 @@ def test_trapezoid_matches_per_step_kernel():
         ref = step(ref)
     scale = float(jnp.max(jnp.abs(ref)))
     assert float(jnp.max(jnp.abs(out - ref))) <= 4e-7 * scale
+
+
+def test_trapezoid_wavefront_counter_per_dispatch(monkeypatch):
+    """One dispatch of `make_multi_step(100)` through the exchanged-mesh
+    path (the single-device mega kernel turned off, so the one chip takes
+    the mesh cell's route: a warm-up step, 12 chunks of K 8, 3 per-step
+    remainder steps) adds 96 to `igg_chunk_wavefront_steps_total`, beside
+    `igg_tier_dispatch_total`'s one."""
+    from igg import telemetry
+    from igg.models import diffusion3d as d3
+    from igg.ops import diffusion_pallas
+
+    monkeypatch.setattr(diffusion_pallas, "_single_device_modes",
+                        lambda grid: None)
+    igg.init_global_grid(64, 64, 128, dimx=1, dimy=1, dimz=1,
+                         periodx=1, periody=1, periodz=1, quiet=True)
+    try:
+        params = d3.Params()
+        T, Cp = d3.init_fields(params, dtype=np.float32)
+        step = d3.make_multi_step(100, params, donate=False,
+                                  use_pallas=True, bx=8)
+        labels = dict(family="diffusion3d", tier="diffusion3d.mosaic")
+        chunked = telemetry.counter("igg_chunk_wavefront_steps_total",
+                                    **labels)
+        dispatches = telemetry.counter("igg_tier_dispatch_total", **labels)
+        c0, d0 = chunked.value, dispatches.value
+        np.asarray(step(T, Cp))
+        assert dispatches.value - d0 == 1
+        assert chunked.value - c0 == 96
+    finally:
+        igg.finalize_global_grid()
 
 
 def test_trapezoid_2d_kernel_matches_window():

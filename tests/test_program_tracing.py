@@ -44,7 +44,7 @@ def _compiled(topo, case):
 
 @pytest.mark.parametrize("case, kernels", [
     (mega_512_open, {"diffusion3d_mega_wavefront"}),
-    (diffusion_trapezoid_2x2, {"diffusion3d_trapezoid_chunk",
+    (diffusion_trapezoid_2x2, {"diffusion3d_trapezoid_wavefront",
                                "diffusion3d_step"})],
     ids=["mega_512_open", "diffusion_trapezoid_2x2"])
 def test_cell_programs_name_every_op(topo, case, kernels):
@@ -77,14 +77,13 @@ def test_cell_programs_name_every_op(topo, case, kernels):
         if last(i) == "pad":
             assert i.scope == profiling.STENCIL, i
 
-    # the central-window slices read the chunk kernel's output
-    outs = {i.name for i in ins if i.opcode == "get-tuple-element"
-            and any(by_name[o].kernel == "diffusion3d_trapezoid_chunk"
-                    for o in profiling._OPERAND.findall(i.key)
-                    if o in by_name)}
+    # the central-window slice reads the chunk kernel's output: one slice
+    # of x and y together
+    outs = {i.name for i in ins
+            if i.kernel == "diffusion3d_trapezoid_wavefront"}
     central = [i for i in ins if i.opcode == "slice"
                and set(profiling._OPERAND.findall(i.key)) & outs]
-    assert bool(central) == ("trapezoid" in case.__name__)
+    assert len(central) == ("trapezoid" in case.__name__)
     assert all(i.scope == profiling.STENCIL for i in central)
 
     for i in ins:
