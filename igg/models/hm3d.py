@@ -1,9 +1,10 @@
-"""3-D hydro-mechanical porous flow (nonlinear two-field compaction).
+"""3-D nonlinear two-field compaction (a stand-in for porous flow).
 
-BASELINE config 4 ("3-D hydro-mechanical porous flow (ParallelStencil HM3D),
-weak scaling").  A compact HM3D-class miniapp: effective pressure `Pe`
-diffusing through a porosity field `phi` with porosity-dependent (cubic)
-permeability, coupled back through compaction — the porosity-wave problem.
+A two-field compaction model: effective pressure `Pe` diffusing through a
+porosity field `phi` with porosity-dependent (cubic) permeability, coupled
+back through compaction.  It has no solid velocities and no total
+pressure; the published two-phase model of BASELINE config 4
+(ParallelStencil's HydroMech3D miniapp) is `igg.models.hydromech3d`.
 Two mutually-coupled fields exchanged in one grouped halo update per step;
 the nonlinear face permeabilities make the stencil state-dependent, unlike
 the constant-coefficient diffusion flagship.

@@ -79,7 +79,21 @@ def iteration_core(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
     `buoy_axis=1`."""
     divV = divergence(Vx, Vy, Vz, dx=dx, dy=dy, dz=dz)
     P = P - dtP * divV
+    rx, ry, rz = momentum_residuals(P, Vx, Vy, Vz, Rho, divV, dx=dx, dy=dy,
+                                    dz=dz, mu=mu, buoy_axis=buoy_axis)
+    return P, dtV * rx, dtV * ry, dtV * rz
 
+
+def momentum_residuals(P, Vx, Vy, Vz, B, divV, *, dx, dy, dz, mu,
+                       buoy_axis: int = 2, buoy_sign: int = 1):
+    """The momentum chain both pseudo-transient families share: the
+    deviatoric stresses of the velocities (`divV` their divergence at
+    cell centres) and the momentum residuals on the interior faces, the
+    pressure `P` read as given, plus `buoy_sign` times the buoyancy `B`
+    averaged onto the faces of the `buoy_axis` velocity.  Returns
+    `(rx, ry, rz)`.  Stokes passes its updated P and Rho; HydroMech3D
+    (`igg.models.hydromech3d`) its updated total pressure and, with
+    `buoy_sign=-1`, its density Rog."""
     # Deviatoric normal stresses at centers
     txx = 2.0 * mu * ((Vx[1:, :, :] - Vx[:-1, :, :]) / dx - divV / 3.0)
     tyy = 2.0 * mu * ((Vy[:, 1:, :] - Vy[:, :-1, :]) / dy - divV / 3.0)
@@ -108,10 +122,12 @@ def iteration_core(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,
           + (tyz[1:-1, 1:, :] - tyz[1:-1, :-1, :]) / dy
           - (P[1:-1, 1:-1, 1:] - P[1:-1, 1:-1, :-1]) / dz)
     if buoy_axis == 2:                                   # buoyancy drives Vz
-        rz = rz + 0.5 * (Rho[1:-1, 1:-1, 1:] + Rho[1:-1, 1:-1, :-1])
+        bz = 0.5 * (B[1:-1, 1:-1, 1:] + B[1:-1, 1:-1, :-1])
+        rz = rz + bz if buoy_sign > 0 else rz - bz
     else:                  # transposed windows: physical z sits on axis 1
-        ry = ry + 0.5 * (Rho[1:-1, 1:, 1:-1] + Rho[1:-1, :-1, 1:-1])
-    return P, dtV * rx, dtV * ry, dtV * rz
+        by = 0.5 * (B[1:-1, 1:, 1:-1] + B[1:-1, :-1, 1:-1])
+        ry = ry + by if buoy_sign > 0 else ry - by
+    return rx, ry, rz
 
 
 def compute_iteration(P, Vx, Vy, Vz, Rho, *, dx, dy, dz, mu, dtP, dtV,

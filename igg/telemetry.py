@@ -382,6 +382,11 @@ _BUILTIN_HELP: Dict[str, str] = {
                                            "schedules traced.",
     "igg_tier_dispatch_total": "Dispatches served per (family, tier) by "
                                "the degradation ladder.",
+    "igg_residual_checks_total": "Convergence checks of a pseudo-"
+                                 "transient solver, per family "
+                                 "(stokes3d: four residual norms; "
+                                 "hydromech3d: five), each one "
+                                 "igg.sharded program read to the host.",
     "igg_pingpong_iterations_total": "Iterations of the ladder's "
                                      "dispatches, per (family, tier), "
                                      "whose outputs went into the dead "

@@ -265,11 +265,41 @@ def stokes_mosaic_256_periodic(topo):
     return compiled
 
 
+def hydromech3d_mosaic_256_periodic(topo):
+    """The rung `hydromech3d.make_iteration`'s ladder serves for the
+    HydroMech3D cell's 256^3 periodic one-chip block: the fused
+    per-iteration kernel, at the slab height its VMEM model fits (bx 4:
+    bx 8 needs 137 MB of the chip's 128).  The local program is the
+    ladder's own, 100 iterations; the ping-pong moves no field in its
+    loop (the x halo rows are written in place)."""
+    import jax
+
+    from igg.models import hydromech3d
+    from igg.ops.hydromech_pallas import _BX_FULL, _fit_bx
+
+    n = 256
+    g = _grid(topo, (n,) * 3, (1, 1, 1), periodic=True, overlapx=3,
+              overlapy=3, overlapz=3)
+    assert _fit_bx(_BX_FULL, n, n, n) == 4
+    ladder = hydromech3d.make_iteration(n_inner=N_INNER).ladder
+    tiers = {t.name: t for t in ladder.tiers}
+    assert list(tiers) == ["hydromech3d.mosaic", "hydromech3d.xla"]
+    assert tiers["hydromech3d.mosaic"].admit(
+        (jax.ShapeDtypeStruct(g, np.float32),))
+    local = tiers["hydromech3d.mosaic"].build().__wrapped__
+    stag = [tuple(n + (d == i) for d in range(3)) for i in range(3)]
+    compiled = _compile(local, [g] * 5 + stag + stag + [g],
+                        (igg.spec_for(3),) * 11)
+    assert _kernels(compiled) == {"hydromech3d_iteration"}
+    assert _loop_field_copies(compiled, [g, *stag]) == []
+    return compiled
+
+
 @pytest.mark.parametrize("case", [
     mega_512_open, mega_512_periodic, mega_256_periodic,
     perstep_with_halo_writer,
     diffusion_trapezoid_2x2, stokes_trapezoid_4x1,
-    stokes_mosaic_256_periodic],
+    stokes_mosaic_256_periodic, hydromech3d_mosaic_256_periodic],
     ids=lambda f: f.__name__)
 def test_compiles_for_v5e(topo, case):
     import jax
