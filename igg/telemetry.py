@@ -393,6 +393,16 @@ _BUILTIN_HELP: Dict[str, str] = {
                                      "partner state set (an HBM "
                                      "ping-pong) instead of fresh "
                                      "buffers.",
+    "igg_margin_rows_carried_total": "Margin rows of the ladder's "
+                                     "dispatches, per (family, tier), "
+                                     "that a fused kernel served from "
+                                     "its VMEM ring instead of reading "
+                                     "them from HBM again.",
+    "igg_margin_rows_fetched_total": "Margin rows of the ladder's "
+                                     "dispatches, per (family, tier), "
+                                     "that a fused kernel read from HBM "
+                                     "(the edge rows of each slab "
+                                     "sequence).",
     "igg_chunk_wavefront_steps_total": "Steps of the ladder's dispatches, "
                                        "per (family, tier), that K-step "
                                        "chunks ran as wavefront sweeps "

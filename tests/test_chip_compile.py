@@ -268,19 +268,24 @@ def stokes_mosaic_256_periodic(topo):
 def hydromech3d_mosaic_256_periodic(topo):
     """The rung `hydromech3d.make_iteration`'s ladder serves for the
     HydroMech3D cell's 256^3 periodic one-chip block: the fused
-    per-iteration kernel, at the slab height its VMEM model fits (bx 4:
-    bx 8 needs 137 MB of the chip's 128).  The local program is the
-    ladder's own, 100 iterations; the ping-pong moves no field in its
-    loop (the x halo rows are written in place)."""
+    per-iteration kernel with its margin rows carried in VMEM rings, at
+    the slab height its VMEM model fits (bx 4, 95.8 MiB; bx 8 models 183
+    MiB, over the 110 MiB cap).  The kernel compiles with the model's
+    footprint as its limit, so the model is not below what the compile
+    allocates (88.1 MiB at bx 4).  The local program is the ladder's own, 100
+    iterations; the ping-pong moves no field in its loop (the x halo rows
+    are written in place)."""
     import jax
 
     from igg.models import hydromech3d
-    from igg.ops.hydromech_pallas import _BX_FULL, _fit_bx
+    from igg.ops._vmem import vmem_limit
+    from igg.ops.hydromech_pallas import _BX_FULL, _fit_bx, _vmem_need
 
     n = 256
     g = _grid(topo, (n,) * 3, (1, 1, 1), periodic=True, overlapx=3,
               overlapy=3, overlapz=3)
     assert _fit_bx(_BX_FULL, n, n, n) == 4
+    assert vmem_limit(_vmem_need(4, n, n)) == _vmem_need(4, n, n)
     ladder = hydromech3d.make_iteration(n_inner=N_INNER).ladder
     tiers = {t.name: t for t in ladder.tiers}
     assert list(tiers) == ["hydromech3d.mosaic", "hydromech3d.xla"]

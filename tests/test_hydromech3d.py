@@ -11,7 +11,12 @@ HydroMech3D on igg's normal path.
 - The ladder falls to `hydromech3d.xla` where admission refuses.
 - Stokes's `iteration_core`, now built on the shared momentum chain,
   gives the chain's former outputs bit for bit from the same ops.
-- The ping-pong and residual counters.
+- The kernel's margin rows carried in VMEM, at bx 4 over 2, 3 and 4
+  slabs, periodic and open x, one device and a mesh split along x,
+  under both interpreters: against the same slab arithmetic fed by
+  one-row margin blocks (the data movement the carry replaced) and
+  against the XLA rung.
+- The ping-pong, margin-row and residual counters.
 """
 
 import importlib.util
@@ -349,3 +354,143 @@ def test_pingpong_counter_per_dispatch():
         state = it(*state, Phi)
         assert dispatches.value - d0 == k
         assert pingpong.value - p0 == k * 4
+
+
+def _margin_fetch_iteration(*args, c, bx, interpret):
+    """The same slab arithmetic (`hydromech_pallas._slab`) and exchange as
+    the fused iteration, with the data movement the VMEM carry replaced:
+    independent programs, each reading its slab and its margin rows -1,
+    bx (and bx + 1 of Vx) as one-row blocks through modular index maps."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from igg.ops import hydromech_pallas as hp
+    from igg.ops.diffusion_pallas import _wrap_dims, _wrap_set
+
+    *state, Phi = args
+    grid = igg.get_global_grid()
+    nb = state[0].shape[0] // bx
+    f = dict(zip(hm.STATE, state), Phi=Phi)
+    operands, in_specs, counts = [], [], []
+    for name, after in hp._MARGINS:
+        F = f[name]
+        rows = (-1, "c", *range(bx, bx + after))
+        counts.append(len(rows))
+        for r in rows:
+            operands.append(F)
+            if r == "c":
+                in_specs.append(pl.BlockSpec((bx, *F.shape[1:]),
+                                             lambda i: (i, 0, 0)))
+            else:
+                in_specs.append(pl.BlockSpec(
+                    (1, *F.shape[1:]),
+                    lambda i, r=r, s=F.shape[0]: ((i * bx + r) % s, 0, 0)))
+    for name in hp._SLAB_ONLY:
+        operands.append(f[name])
+        in_specs.append(pl.BlockSpec((bx, *f[name].shape[1:]),
+                                     lambda i: (i, 0, 0)))
+    wy, wz = _wrap_dims(grid)
+    ols = [tuple(grid.ol_of_local(d, f[n].shape) for d in (1, 2))
+           for n in hm.EXCHANGED]
+
+    def kernel(*refs):
+        wins, k = [], 0
+        for n in counts:
+            wins.append(jnp.concatenate([r[:] for r in refs[k:k + n]],
+                                        axis=0))
+            k += n
+        cRPf, *cdV = refs[k:k + len(hp._SLAB_ONLY)]
+        hp._slab(*wins, cRPf, cdV, refs[k + len(hp._SLAB_ONLY):], bx=bx, c=c,
+                 ols=ols, wrap_y=wy, wrap_z=wz)
+
+    new = list(pl.pallas_call(
+        kernel, grid=(nb,), in_specs=in_specs,
+        out_specs=[pl.BlockSpec((bx, *F.shape[1:]), lambda i: (i, 0, 0))
+                   for F in state],
+        out_shape=[jax.ShapeDtypeStruct(F.shape, F.dtype) for F in state],
+        interpret=interpret)(*operands))
+    idx = [hm.STATE.index(n) for n in hm.EXCHANGED]
+    done = hp._exchange([new[i] for i in idx], [state[i] for i in idx],
+                        grid, _wrap_set((wy, wz)))
+    for i, F in zip(idx, done):
+        new[i] = F
+    return tuple(new)
+
+
+@pytest.mark.parametrize("interpreter", ["generic", "tpu"])
+@pytest.mark.parametrize("nx,dims,periodic", [
+    (8, (1, 1, 1), True),      # 2 slabs: two sequences of one
+    (16, (1, 1, 1), True),     # 4 slabs: two sequences of two
+    (12, (1, 1, 1), True),     # 3 slabs: one sequence
+    (16, (1, 1, 1), False),    # open x
+    (12, (2, 1, 1), True),     # a mesh split along x, 3 slabs a device
+    (16, (2, 1, 1), False),    # ... open, 4 slabs a device
+], ids=["nb2", "nb4", "nb3", "nb4-open", "x2-nb3", "x2-nb4-open"])
+def test_carry_matches_margin_fetch(nx, dims, periodic, interpreter):
+    import contextlib
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from igg.ops.hydromech_pallas import fused_hydromech_iteration
+
+    _grid(local=(nx, 8, 128), dims=dims, periodic=periodic)
+    grid = igg.get_global_grid()
+    rng = np.random.default_rng(nx + 10 * dims[0] + periodic)
+    sh = igg.sharding_for(3)
+    n = [d * s for d, s in zip(grid.dims, grid.nxyz)]
+    shapes = [tuple(n)] * 5 + [tuple(n[d] + grid.dims[d] * (d == i)
+                                     for d in range(3)) for i in range(3)] * 2
+    scale = [2e-2, 2e-2, 1e-1, 1.0, 1.0, 1e-2, 1e-2, 1e-2, 1e-5, 1e-5, 1e-5]
+    state = [jax.device_put((s * rng.uniform(-1, 1, sh_)).astype(np.float32)
+                            + (1.0 if i in (3, 4) else 0.0), sh)
+             for i, (s, sh_) in enumerate(zip(scale, shapes))]
+    Phi = jax.device_put(rng.uniform(0.006, 0.014, n).astype(np.float32), sh)
+    c = hm.pseudo_steps(hm.Params())
+    generic = interpreter == "generic"
+    ctx = (contextlib.nullcontext if generic
+           else pltpu.force_tpu_interpret_mode)
+
+    def run(fn):
+        with jax.enable_x64(False), ctx():
+            out = igg.sharded(fn, check_vma=False)(*state, Phi)
+            return [np.asarray(a) for a in out]
+
+    got = run(lambda *A: fused_hydromech_iteration(*A, c=c, bx=4,
+                                                   interpret=generic))
+    ref = run(lambda *A: _margin_fetch_iteration(*A, c=c, bx=4,
+                                                 interpret=generic))
+    xla = run(lambda *A: hm.local_iteration(*A[:-1], Phi=A[-1], c=c))
+    # The same f32 formulas on the same values: bitwise on the chip
+    # (PERF.md §6).  XLA:CPU fuses the two interpreted programs apart and
+    # contracts different products into FMAs, so here a cell may differ
+    # by an ulp or two of its value; a margin row from the wrong place
+    # reads O(1).
+    for f, a, b, x in zip(hm.STATE, got, ref, xla):
+        top = np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * top, err_msg=f)
+        np.testing.assert_allclose(a, x, rtol=0, atol=2e-5 * top, err_msg=f)
+
+
+def test_margin_row_counters_per_dispatch():
+    from igg import telemetry
+    from igg.ops.hydromech_pallas import margin_rows
+
+    _grid(local=(24, 16, 128))     # bx 8, 3 slabs: one sequence
+    state, Phi = hm.init_fields()
+    labels = dict(family="hydromech3d", tier="hydromech3d.mosaic")
+    carried = telemetry.counter("igg_margin_rows_carried_total", **labels)
+    fetched = telemetry.counter("igg_margin_rows_fetched_total", **labels)
+    c0, f0 = carried.value, fetched.value
+    it = hm.make_iteration(n_inner=5, pallas_interpret=True)
+    for k in (1, 2):
+        state = it(*state, Phi)
+        # 17 margin rows a slab: two slabs' from the ring, the
+        # sequence's edge rows from HBM, five iterations a dispatch
+        assert carried.value - c0 == k * 5 * 34
+        assert fetched.value - f0 == k * 5 * 17
+    assert margin_rows(3) == (34, 17)
+    # 256^3 at bx 4: 64 slabs in two sequences
+    c, f = margin_rows(64)
+    assert (c, f) == (1054, 34) and round(c / (c + f), 3) == 0.969
